@@ -1,0 +1,64 @@
+"""Operation, byte and peak accounting against hand-worked counts."""
+import pytest
+
+from bench import costs
+
+# A small configuration: I = C = 2 channels, H = 3, K = 4, 8-sample frames.
+F32 = {"numerics": "float32", "input_dim": 2, "hidden": 3, "classes": 4,
+       "fex": {"frame_shift": 8},
+       "dtypes": {"audio": "float32", "fex_state": "float32",
+                  "features": "float32", "weights": "float32",
+                  "hidden": "float32", "accumulator": "float32"}}
+I8 = dict(F32, numerics="int8",
+          dtypes={"audio": "int16", "fex_state": "int16", "features": "int16",
+                  "weights": "int8", "hidden": "int16",
+                  "accumulator": "int32"})
+
+
+def test_fex_counts_by_hand():
+    # 5 slots x 16 samples (2 frames) x 2 channels:
+    #   16 ops a sample-channel: 5*16*2*16 = 2560; 10 a frame-channel:
+    #   5*2*2*10 = 200.
+    # bytes: audio 5*16*4 = 320, state 2*5*5*2*4 = 400, features
+    #   5*2*2*4 = 80, coefficients 6*2*4 = 48.
+    w = costs.fex_call(F32, slots=5, samples=16)
+    assert w.ops == 2560 + 200
+    assert w.bytes == 320 + 400 + 80 + 48
+    w8 = costs.fex_call(I8, slots=5, samples=16)
+    assert w8.ops == w.ops
+    assert w8.bytes == 160 + 200 + 40 + 48
+
+
+def test_gru_counts_by_hand():
+    # per frame: 2*(2+3)*9 = 90 contraction, 6*3 = 18 accumulate,
+    # 11*3 = 33 gates -> 141.  5 slots x 2 frames -> 1410.
+    assert costs.gru_frame_ops(F32) == 141
+    w = costs.gru_call(F32, slots=5, frames=2)
+    assert w.ops == 1410
+    # features in 5*2*2*4 = 80, hidden out 5*2*3*4 = 120; state per slot
+    # (h, h_hat 6*4) + (x_hat 2*4) + (M 18*4) = 104, in and out for 5
+    # slots = 1040; weights 5*9*4 = 180.
+    assert w.bytes == 80 + 120 + 1040 + 180
+    # int8: 40 + 60 + 2*5*(12 + 4 + 72) + 45
+    assert costs.gru_call(I8, slots=5, frames=2).bytes == 40 + 60 + 880 + 45
+
+
+def test_model_ops_per_frame_by_hand():
+    # FEx 8*2*16 + 2*10 + ADC 8*7 = 332; GRU 141; FC 2*3*4 + 4 = 28.
+    assert costs.model_ops_per_frame(F32) == 332 + 141 + 28
+
+
+def test_peaks_and_roofline():
+    p = costs.peaks_for("TPU v5 lite")
+    assert p["bf16_ops_per_s"] == 197e12 and p["hbm_bytes_per_s"] == 819e9
+    assert costs.ops_peak(I8, p) == 393e12
+    assert costs.ops_peak(F32, p) == 197e12
+    w = costs.Work(ops=197e12, bytes=819e9 / 2)
+    assert costs.least_time(w, 197e12, p) == pytest.approx(1.0)
+    assert costs.least_time(w, None, p) == pytest.approx(0.5)
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "cpu", "TPU v5"])
+def test_unknown_device_raises(kind):
+    with pytest.raises(costs.UnknownDevice):
+        costs.peaks_for(kind)
