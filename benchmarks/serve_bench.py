@@ -71,6 +71,7 @@ def _make_engine(params, cfg, fex, mesh, slots, args, depth=1):
     host-blocked-phase telemetry for ``_stats``.
     """
     import numpy as np
+    from repro.launch import spans
     from repro.launch.engine import PipelinedEngine
     from repro.launch.streaming import SlotScheduler, StreamingKwsSession
 
@@ -104,9 +105,10 @@ def _make_engine(params, cfg, fex, mesh, slots, args, depth=1):
     def step():
         t0 = time.perf_counter()
         eng.begin()
-        block = np.zeros((slots, chunk), np.float32)
-        for slot in sched.live:
-            block[slot] = pool[slot, progress[slot]]
+        with spans.span(spans.ASSEMBLE):
+            block = np.zeros((slots, chunk), np.float32)
+            for slot in sched.live:
+                block[slot] = pool[slot, progress[slot]]
         piece_frames, _drained = eng.submit([block])
         for slot in list(sched.live):
             progress[slot] += 1
@@ -408,6 +410,7 @@ def soak_main(args) -> int:
             params, cfg, threshold=args.threshold, batch=slots, fex=fex,
             supervisor=SupervisorConfig(), input_policy="trust")
 
+    from repro.launch import spans
     from repro.launch.engine import PipelinedEngine
 
     sess = make_session()
@@ -445,22 +448,25 @@ def soak_main(args) -> int:
             admit()
             t0 = time.perf_counter()
             eng.begin()
-            block = np.zeros((slots, chunk), np.float32)
-            for slot in sched.live:
-                block[slot] = pool[slot, progress[slot] % chunks_per_utt]
-            pieces, actions = ([block], []) if not faulty \
-                else injector.inject(block)
-            stalled = False
-            for act in actions:
-                fault_counts[act.kind] = fault_counts.get(act.kind, 0) + 1
-                if act.kind == "stall":
-                    stalled = True
-                    time.sleep(act.detail)
-                elif act.kind == "churn_storm":
-                    storm = [s for s in act.slots if s in sched.live]
-                    sess.reset_streams(storm)
-                    for s in storm:
-                        progress[s] = 0
+            with spans.span(spans.ASSEMBLE):
+                block = np.zeros((slots, chunk), np.float32)
+                for slot in sched.live:
+                    block[slot] = pool[slot,
+                                       progress[slot] % chunks_per_utt]
+                pieces, actions = ([block], []) if not faulty \
+                    else injector.inject(block)
+                stalled = False
+                for act in actions:
+                    fault_counts[act.kind] = \
+                        fault_counts.get(act.kind, 0) + 1
+                    if act.kind == "stall":
+                        stalled = True
+                        time.sleep(act.detail)
+                    elif act.kind == "churn_storm":
+                        storm = [s for s in act.slots if s in sched.live]
+                        sess.reset_streams(storm)
+                        for s in storm:
+                            progress[s] = 0
             # Frame counts come from dispatch-time SHAPES (no fetch):
             # the decision count stays exact even while the pipeline is
             # depth-deep in flight.

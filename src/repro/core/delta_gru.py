@@ -230,6 +230,7 @@ def _delta_gru_scan_blocked(params: DeltaGRUParams, xs: Array,
     return hs, final_state, stats
 
 
+@jax.named_scope("kws.gru")
 def delta_gru_scan(params: DeltaGRUParams, xs: Array, threshold: float = 0.0,
                    state: DeltaState | None = None, *,
                    backend: str = "xla", interpret: bool | None = None,

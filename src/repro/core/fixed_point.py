@@ -367,6 +367,7 @@ def gru_frame_step(fmt: GruFormats | None, x, h, x_hat, h_hat, m,
 _INT_SEQ_KERNEL_VMEM_BUDGET_BYTES = 8 * 2 ** 20
 
 
+@jax.named_scope("kws.gru")
 def int_gru_scan(w: IntGruWeights, fmt: GruFormats, xs_codes,
                  threshold: float, state=None, *, backend: str = "xla",
                  block_b: int | None = None, block_t: int | None = None,
@@ -636,6 +637,7 @@ def fex_state_from_codes(codes, fmt: FexFormats):
     return jnp.concatenate([filt, env], axis=1)
 
 
+@jax.named_scope("kws.fex")
 def int_fex_scan(audio_codes, coef_codes, state_codes, fmt: FexFormats, *,
                  frame_shift: int = 128, backend: str = "xla",
                  block_b: int | None = None, unroll: int | None = None,

@@ -137,6 +137,7 @@ def _fex_scan_xla(audio: Array, coef: Array, state_buf: Array,
     return jnp.moveaxis(feats, 0, 1), state_buf      # (B, F, C)
 
 
+@jax.named_scope("kws.fex")
 def fex_scan(audio: Array, coef: Array, state: FExState | None = None, *,
              frame_shift: int = FRAME_SHIFT, env_alpha: float = 0.0606,
              log_eps: float = 2.0 ** -11, compress: bool = True,

@@ -31,12 +31,18 @@ tests/test_engine.py proves ``depth>=2`` equal to ``depth=1`` decision
 for decision and counter for counter, in float and int8, under churn
 storms, fault plans and mesh>1.
 
-SLO telemetry: the engine tracks per-phase host-blocked time
-(assemble / dispatch / fetch), p50/p99/p99.9 step and end-to-end
-decision latency (assemble start → results host-visible), and the
-scheduler's shard-occupancy imbalance, all against an injectable
-``clock`` so the math is testable with a fake clock.  ``report()``
-feeds ``StreamSummary.slo`` and ``BENCH_serve.json``.
+SLO telemetry: the engine tracks p50/p99/p99.9 step and end-to-end
+decision latency (assemble start → results host-visible), the
+scheduler's shard-occupancy imbalance, and where a step's host time
+goes.  Every step is a ``kws.step`` span; the phases inside it are the
+program's host spans (``launch/spans.py`` lists them and where each is
+entered), timed into the engine's span recorder.  Everything runs on an
+injectable ``clock`` so the math is testable with a fake clock.
+``report()["host_ms_per_step"]`` gives ms per step for every span plus
+``untraced`` (the steady-state step less the phases);
+``host_blocked_ms_per_step`` keeps its historical keys as sums of spans
+(assemble; dispatch = police + frame + h2d + launch; fetch; total).
+``report()`` feeds ``StreamSummary.slo`` and ``BENCH_serve.json``.
 """
 from __future__ import annotations
 
@@ -44,6 +50,8 @@ import time
 from typing import Any, Callable, Sequence
 
 import numpy as np
+
+from repro.launch import spans
 
 __all__ = ["PipelinedEngine", "FetchedStep", "percentiles_ms",
            "warm_session", "run_audio_requests", "run_continuous_detect"]
@@ -96,11 +104,13 @@ class PipelinedEngine:
 
         eng = PipelinedEngine(sess, depth=2, field="votes", scheduler=sched)
         while serving:
-            eng.begin()                     # assemble phase starts
-            block = ...                     # host work (slicing, faults)
+            eng.begin()                     # the kws.step span opens
+            with spans.span(spans.ASSEMBLE):
+                block = ...                 # host work (slicing, faults)
             _, drained = eng.submit([block], meta=...)   # dispatch + drain
-            ...                             # dispatch-time bookkeeping
-            for f in drained: integrate(f)  # results from ~depth steps ago
+            with spans.span(spans.SLOTS):
+                ...                         # dispatch-time bookkeeping
+                for f in drained: integrate(f)   # from ~depth steps ago
             eng.end()                       # step wall-clock sample
         for f in eng.flush(): integrate(f)
         sess.attach_slo(eng.report())
@@ -111,7 +121,8 @@ class PipelinedEngine:
     ``field`` names the result attribute fetched per piece ("votes" for
     the utterance loop, "events" for detect/cascade).  ``scheduler``
     (optional) is sampled at every ``end()`` for shard-occupancy
-    imbalance.  ``clock`` is injectable for fake-clock telemetry tests.
+    imbalance.  ``clock`` is injectable for fake-clock telemetry tests;
+    the span recorder (``spans``) times on it too.
     """
 
     def __init__(self, session, *, depth: int = 2, field: str = "votes",
@@ -129,9 +140,8 @@ class PipelinedEngine:
         self._t_begin: float | None = None
         self._t_first: float | None = None
         self._t_last_end: float | None = None
-        self.assemble_s = 0.0               # host-blocked phase seconds
-        self.dispatch_s = 0.0
-        self.fetch_s = 0.0
+        self.spans = spans.Recorder(clock)  # host seconds per span
+        self._step_span: spans.Step | None = None
         self._step_s: list[float] = []
         self._e2e_s: list[float] = []
         self._imbalance: list[int] = []
@@ -140,10 +150,14 @@ class PipelinedEngine:
     # ------------------------------------------------------- phases --
 
     def begin(self):
-        """Mark the start of a step's host-assemble phase."""
+        """Open a step (the ``kws.step`` span): the phases entered until
+        ``end()`` are counted as the step's."""
+        if self._step_span is not None:     # begun again without end()
+            self._step_span.close(None)
         self._t_begin = self._clock()
         if self._t_first is None:
             self._t_first = self._t_begin
+        self._step_span = spans.Step(self.spans, self._index)
 
     def submit(self, pieces, meta: Any = None
                ) -> tuple[list[int], list[FetchedStep]]:
@@ -158,11 +172,7 @@ class PipelinedEngine:
         step's ``FetchedStep``.
         """
         t_begin = self._t_begin if self._t_begin is not None else self._clock()
-        t0 = self._clock()
-        self.assemble_s += t0 - t_begin
         outs = [self.session.process_audio(p) for p in pieces]
-        t1 = self._clock()
-        self.dispatch_s += t1 - t0
         piece_frames = [int(getattr(o, self.field).shape[0]) for o in outs]
         self.decisions += sum(piece_frames) * self.session.batch
         self._queue.append(_InFlight(self._index, tuple(outs), piece_frames,
@@ -179,6 +189,8 @@ class PipelinedEngine:
             return
         now = self._clock()
         self._step_s.append(now - self._t_begin)
+        self._step_span.close(now - self._t_begin)
+        self._step_span = None
         self._t_last_end = now
         self._t_begin = None
         if self._sched is not None:
@@ -194,11 +206,10 @@ class PipelinedEngine:
 
     def _fetch_oldest(self) -> FetchedStep:
         inf = self._queue.pop(0)
-        t0 = self._clock()
-        arrays = tuple(np.asarray(getattr(o, self.field)) for o in inf.outs)
-        t1 = self._clock()
-        self.fetch_s += t1 - t0
-        self._e2e_s.append(t1 - inf.t_begin)
+        with spans.span(spans.FETCH):
+            arrays = tuple(np.asarray(getattr(o, self.field))
+                           for o in inf.outs)
+        self._e2e_s.append(self._clock() - inf.t_begin)
         return FetchedStep(inf.index, arrays, inf.piece_frames, inf.meta)
 
     # ---------------------------------------------------- telemetry --
@@ -211,7 +222,10 @@ class PipelinedEngine:
         """Zero the SLO accumulators (keeps the in-flight queue): the
         benchmarks call this after their warmup steps so compile noise
         never reaches the reported percentiles."""
-        self.assemble_s = self.dispatch_s = self.fetch_s = 0.0
+        if self._step_span is not None:     # an open step counts nothing
+            self._step_span.close(None)
+            self._step_span = None
+        self.spans.reset()
         self._step_s = []
         self._e2e_s = []
         self._imbalance = []
@@ -233,6 +247,11 @@ class PipelinedEngine:
                     if self._t_last_end is not None
                     and self._t_first is not None else sum(self._step_s))
         imb = np.asarray(self._imbalance or [0], np.float64)
+        host = {name: sec * 1e3 / n
+                for name, sec in self.spans.seconds.items()}
+        host[spans.UNTRACED] = (steady_s * 1e3 / n
+                                - sum(host[p] for p in spans.PHASES))
+        dispatch = sum(host[p] for p in spans.DISPATCH)
         return {
             "depth": self.depth,
             "steps": steps,
@@ -243,12 +262,12 @@ class PipelinedEngine:
             "step_ms": percentiles_ms(self._step_s),
             "e2e_ms": percentiles_ms(self._e2e_s),
             "host_blocked_ms_per_step": {
-                "assemble": self.assemble_s * 1e3 / n,
-                "dispatch": self.dispatch_s * 1e3 / n,
-                "fetch": self.fetch_s * 1e3 / n,
-                "total": (self.assemble_s + self.dispatch_s + self.fetch_s)
-                * 1e3 / n,
+                "assemble": host[spans.ASSEMBLE],
+                "dispatch": dispatch,
+                "fetch": host[spans.FETCH],
+                "total": host[spans.ASSEMBLE] + dispatch + host[spans.FETCH],
             },
+            "host_ms_per_step": host,
             "shard_imbalance": {"mean": float(imb.mean()),
                                 "max": int(imb.max())},
         }
@@ -328,48 +347,52 @@ def run_audio_requests(sess, sched, ctl, *, audio_q, chunk: int,
                                       minlength=sess.n_classes)
 
     def admit():
-        for slot, req in sched.admit():
-            incarnations[slot] = _Incarnation(req, sess.n_classes,
-                                              real_frames)
+        admitted = sched.admit()
+        with spans.span(spans.SLOTS):
+            for slot, req in admitted:
+                incarnations[slot] = _Incarnation(req, sess.n_classes,
+                                                  real_frames)
 
     admit()
     while not sched.idle:
         eng.begin()
-        block = np.zeros((sess.batch, chunk), np.float32)
-        for slot, req in sched.live.items():
-            c0 = incarnations[slot].progress[0]
-            seg = audio_q[req, c0 * chunk:(c0 + 1) * chunk]
-            block[slot, :len(seg)] = seg    # zero-pad a short final chunk
-        pieces, actions = ([block], []) if injector is None \
-            else injector.inject(block)
+        with spans.span(spans.ASSEMBLE):
+            block = np.zeros((sess.batch, chunk), np.float32)
+            for slot, req in sched.live.items():
+                c0 = incarnations[slot].progress[0]
+                seg = audio_q[req, c0 * chunk:(c0 + 1) * chunk]
+                block[slot, :len(seg)] = seg    # zero-pad a short chunk
+            pieces, actions = ([block], []) if injector is None \
+                else injector.inject(block)
         contribs: list[tuple] = []          # filled below, post-submit
         piece_frames, drained = eng.submit(pieces, meta=contribs)
         n_f = sum(piece_frames)
-        for act in actions:                 # driver directives
-            if act.kind == "stall":
-                time.sleep(act.detail)
-            elif act.kind == "churn_storm":
-                storm = [s for s in act.slots if s in sched.live]
-                sess.reset_streams(storm)   # poof — streams restart
-                for s in storm:             # same request, new life
-                    incarnations[s] = _Incarnation(sched.live[s],
-                                                   sess.n_classes,
-                                                   real_frames)
-        pad_frames += n_f * (sess.batch - len(sched.live))   # idle slots
-        for slot in list(sched.live):
-            inc = incarnations[slot]
-            st = inc.progress
-            n_real = min(n_f, st[1])
-            contribs.append((inc, slot, n_real))
-            st[1] -= n_real
-            frames_served += n_real
-            pad_frames += n_f - n_real
-            st[0] += 1
-            if st[0] >= chunks_per_utt:
-                sched.evict(slot)
-                order.append(inc)
-        for f in drained:
-            integrate(f)
+        with spans.span(spans.SLOTS):
+            for act in actions:             # driver directives
+                if act.kind == "stall":
+                    time.sleep(act.detail)
+                elif act.kind == "churn_storm":
+                    storm = [s for s in act.slots if s in sched.live]
+                    sess.reset_streams(storm)   # poof — streams restart
+                    for s in storm:             # same request, new life
+                        incarnations[s] = _Incarnation(sched.live[s],
+                                                       sess.n_classes,
+                                                       real_frames)
+            pad_frames += n_f * (sess.batch - len(sched.live))  # idle
+            for slot in list(sched.live):
+                inc = incarnations[slot]
+                st = inc.progress
+                n_real = min(n_f, st[1])
+                contribs.append((inc, slot, n_real))
+                st[1] -= n_real
+                frames_served += n_real
+                pad_frames += n_f - n_real
+                st[0] += 1
+                if st[0] >= chunks_per_utt:
+                    sched.evict(slot)
+                    order.append(inc)
+            for f in drained:
+                integrate(f)
         admit()
         steps += 1
         eng.end()
@@ -420,23 +443,25 @@ def run_continuous_detect(sess, streams_audio, *, chunk: int,
 
     for off in range(0, n_samples, chunk):
         eng.begin()
-        block = np.stack([s[off:off + chunk] for s in streams_audio])
-        pieces, actions = ([block], []) if injector is None \
-            else injector.inject(block)
-        for act in actions:
-            if act.kind == "stall":
-                time.sleep(act.detail)
-            elif act.kind == "churn_storm":
-                sess.reset_streams(list(act.slots))
+        with spans.span(spans.ASSEMBLE):
+            block = np.stack([s[off:off + chunk] for s in streams_audio])
+            pieces, actions = ([block], []) if injector is None \
+                else injector.inject(block)
+            for act in actions:
+                if act.kind == "stall":
+                    time.sleep(act.detail)
+                elif act.kind == "churn_storm":
+                    sess.reset_streams(list(act.slots))
         bases: list[int] = []
         piece_frames, drained = eng.submit(pieces, meta=bases)
-        for pf in piece_frames:             # offsets fixed at dispatch
-            bases.append(frame_base)
-            frame_base += pf
+        with spans.span(spans.SLOTS):
+            for pf in piece_frames:         # offsets fixed at dispatch
+                bases.append(frame_base)
+                frame_base += pf
+            for f in drained:
+                integrate(f)
         steps += 1
-        eng.end()
-        for f in drained:
-            integrate(f)
+        eng.end()                           # the step covers the drain
     for f in eng.flush():
         integrate(f)
 

@@ -101,6 +101,7 @@ from repro.frontend.vad import (VADConfig, VADState, VAD_OFF, frame_energy,
                                 init_vad_state, vad_gate, vad_state_flags)
 from repro.kernels.gru_math import f32_dot
 from repro.kernels.platform import resolve_interpret, shard_map_kernels
+from repro.launch import spans
 from repro.models import kws
 from repro.models import detector as det_mod
 from repro.models.detector import (DetectorConfig, DetectorState,
@@ -418,8 +419,8 @@ class StreamSummary:
     recovery_reasons: dict = dataclasses.field(default_factory=dict)
     sat_events: int = 0                    # HEALTH_SAT slot-checks observed
     # Serve-loop SLO telemetry attached by the pipelined engine
-    # (launch.engine): step/e2e latency percentiles, per-phase
-    # host-blocked time, shard imbalance.  Empty unless a serve driver
+    # (launch.engine): step/e2e latency percentiles, host time per span
+    # (launch.spans), shard imbalance.  Empty unless a serve driver
     # called ``attach_slo`` — plain session runs are unaffected.
     slo: dict = dataclasses.field(default_factory=dict)
 
@@ -428,6 +429,7 @@ def _zero_accum(n_shards: int = 1) -> _Accum:
     return _Accum(*[_count_zero(n_shards) for _ in _Accum._fields])
 
 
+@jax.named_scope("kws.head")
 def _classify(w_fc, b_fc, hs, stats):
     logits = f32_dot(hs, w_fc) + b_fc             # (F, B, 12)
     votes = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -484,6 +486,7 @@ def _process_chunk(gru: dg.DeltaGRUParams, w_fc, b_fc, state: dg.DeltaState,
             out, health)
 
 
+@jax.named_scope("kws.head")
 def _classify_int(w_fc, b_fc, hs_codes, stats, logit_frac: int):
     """FC + argmax on integer codes — the decision is the argmax over
     int32 logit codes (bit-true); the dequantized logits are returned
@@ -1120,28 +1123,38 @@ class StreamingKwsSession:
         return step, audio_step_fn
 
     def _build_audio_step(self, audio_step_fn):
-        """Jit + shard the fused audio step once the FEx kwargs are known."""
+        """Jit + shard the fused audio step once the FEx kwargs are known.
+
+        Every variant is jitted under one stable name, so the device
+        trace shows the step as the module ``jit_kws_audio_step``."""
         fn = functools.partial(audio_step_fn, **self._fex_kw)
         if self._cascade is not None:
             # _process_audio_chunk_cascade[_int](gru, w_fc, b_fc, gru0,
             # w_fc0, b_fc0, coef, fex_state, state, cas_state, vad_state,
             # det_state, acc, audio): five state trees + acc + audio are
             # slot-major; both stages' weights are replicated.
-            return jax.jit(self._shard(
+            sharded = self._shard(
                 fn, n_args=14, slot_major=(7, 8, 9, 10, 11, 12, 13),
-                time_major=(), n_state_out=6))
-        if self._detector is not None:
+                time_major=(), n_state_out=6)
+        elif self._detector is not None:
             # _process_audio_chunk_detect[_int](gru, w_fc, b_fc, coef,
             # fex_state, state, vad_state, det_state, acc, audio):
             # the four state trees + acc + audio are slot-major.
-            return jax.jit(self._shard(
+            sharded = self._shard(
                 fn, n_args=10, slot_major=(4, 5, 6, 7, 8, 9),
-                time_major=(), n_state_out=5))
-        # _process_audio_chunk[_int](gru, w_fc, b_fc, coef, fex_state,
-        # state, acc, audio): fex_state/state/acc/audio are slot-major.
-        return jax.jit(self._shard(
-            fn, n_args=8, slot_major=(4, 5, 6, 7), time_major=(),
-            n_state_out=3))
+                time_major=(), n_state_out=5)
+        else:
+            # _process_audio_chunk[_int](gru, w_fc, b_fc, coef,
+            # fex_state, state, acc, audio): fex_state/state/acc/audio
+            # are slot-major.
+            sharded = self._shard(
+                fn, n_args=8, slot_major=(4, 5, 6, 7), time_major=(),
+                n_state_out=3)
+
+        def kws_audio_step(*args):
+            return sharded(*args)
+
+        return jax.jit(kws_audio_step)
 
     def kernel_tuning_report(self) -> dict:
         """Which autotuned kernel configs THIS session's steps resolve.
@@ -1369,16 +1382,19 @@ class StreamingKwsSession:
         chunk length.
         """
         fex = self._require_fex()
-        audio = self._coerce_audio(audio)
+        with spans.span(spans.POLICE):
+            audio = self._coerce_audio(audio)
         if audio.ndim == 1:
             audio = audio[None]
         if audio.shape[0] != self.batch:
             raise ValueError(f"audio carries {audio.shape[0]} streams, "
                              f"session was created with batch={self.batch}")
-        audio = np.concatenate([self._audio_rem, audio], axis=1)
-        shift = fex.cfg.frame_shift
-        n_frames = audio.shape[1] // shift
-        self._audio_rem = audio[:, n_frames * shift:]
+        with spans.span(spans.FRAME):
+            audio = np.concatenate([self._audio_rem, audio], axis=1)
+            shift = fex.cfg.frame_shift
+            n_frames = audio.shape[1] // shift
+            self._audio_rem = audio[:, n_frames * shift:]
+            block = audio[:, :n_frames * shift]
         if n_frames == 0:
             z = jnp.zeros((0, self.batch), jnp.int32)
             logits = jnp.zeros((0, self.batch, self.n_classes))
@@ -1390,18 +1406,21 @@ class StreamingKwsSession:
                 return DetectResult(logits=logits, votes=z, nz=z, events=z,
                                     gate=jnp.zeros((0, self.batch), bool))
             return ChunkResult(logits=logits, votes=z, nz=z)
-        block = jnp.asarray(audio[:, :n_frames * shift])
-        outs = self._audio_step(*self._audio_step_args(block))
-        if self._cascade is not None:
-            (self._fex_state, self._state, self._cas_state, self._vad_state,
-             self._det_state, self._acc, out, health) = outs
-        elif self._detector is not None:
-            (self._fex_state, self._state, self._vad_state, self._det_state,
-             self._acc, out, health) = outs
-        else:
-            self._fex_state, self._state, self._acc, out, health = outs
-        self._last_health = health
-        self._chunks += 1
+        with spans.span(spans.H2D):
+            block = jnp.asarray(block)
+        with spans.span(spans.LAUNCH):
+            outs = self._audio_step(*self._audio_step_args(block))
+            if self._cascade is not None:
+                (self._fex_state, self._state, self._cas_state,
+                 self._vad_state, self._det_state, self._acc, out,
+                 health) = outs
+            elif self._detector is not None:
+                (self._fex_state, self._state, self._vad_state,
+                 self._det_state, self._acc, out, health) = outs
+            else:
+                self._fex_state, self._state, self._acc, out, health = outs
+            self._last_health = health
+            self._chunks += 1
         self._maybe_heal()
         return out
 
@@ -1588,16 +1607,17 @@ class StreamingKwsSession:
             return
         if self._chunks % sup.check_every:
             return
-        flags = np.asarray(jax.device_get(self._last_health))
-        self._sat_events += int(np.count_nonzero(flags & HEALTH_SAT))
-        healed = self._quarantine(flags, sup.quarantine_mask)
-        # Host-side cache of who is STILL flagged (below the strike bar,
-        # not yet quarantined) — the scheduler consults this at admit()
-        # without adding a device fetch to the hot path.
-        bad = (flags & sup.quarantine_mask) != 0
-        if healed:
-            bad[healed] = False           # quarantined slots restart clean
-        self._flagged = frozenset(int(s) for s in np.flatnonzero(bad))
+        with spans.span(spans.HEAL):
+            flags = np.asarray(jax.device_get(self._last_health))
+            self._sat_events += int(np.count_nonzero(flags & HEALTH_SAT))
+            healed = self._quarantine(flags, sup.quarantine_mask)
+            # Host-side cache of who is STILL flagged (below the strike
+            # bar, not yet quarantined) — the scheduler consults this at
+            # admit() without adding a device fetch to the hot path.
+            bad = (flags & sup.quarantine_mask) != 0
+            if healed:
+                bad[healed] = False       # quarantined slots restart clean
+            self._flagged = frozenset(int(s) for s in np.flatnonzero(bad))
 
     def heal(self, mask: int | None = None) -> list[int]:
         """Force one supervisor pass NOW, ignoring ``check_every`` and
@@ -1612,16 +1632,17 @@ class StreamingKwsSession:
         if mask is None:
             mask = (self.supervisor.quarantine_mask
                     if self.supervisor is not None else QUARANTINE_DEFAULT)
-        flags = np.asarray(jax.device_get(self._last_health))
-        victims = [int(s) for s in np.flatnonzero((flags & mask) != 0)]
-        if victims:
-            for s in victims:
-                for bit, reason in HEALTH_REASONS.items():
-                    if flags[s] & bit & mask:
-                        self._recovery_reasons[reason] = \
-                            self._recovery_reasons.get(reason, 0) + 1
-            self._recoveries += len(victims)
-            self.reset_streams(victims)
+        with spans.span(spans.HEAL):
+            flags = np.asarray(jax.device_get(self._last_health))
+            victims = [int(s) for s in np.flatnonzero((flags & mask) != 0)]
+            if victims:
+                for s in victims:
+                    for bit, reason in HEALTH_REASONS.items():
+                        if flags[s] & bit & mask:
+                            self._recovery_reasons[reason] = \
+                                self._recovery_reasons.get(reason, 0) + 1
+                self._recoveries += len(victims)
+                self.reset_streams(victims)
         return victims
 
     def unhealthy_slots(self) -> dict[int, int]:
@@ -1786,23 +1807,24 @@ class SlotScheduler:
         slot-local reset (see ``reset_streams``).  Returns the
         (slot, payload) admissions.
         """
-        flagged = self._sess.flagged_slots()
-        admitted = []
-        while self._queue:
-            usable = [s for s in range(self.n_shards)
-                      if any(sl not in flagged for sl in self._free[s])]
-            if not usable:
-                break                     # full, or only unhealthy slots
-            shard = min(usable, key=self._shard_load)
-            free = self._free[shard]      # pop highest-priority healthy
-            idx = next(i for i in range(len(free) - 1, -1, -1)
-                       if free[i] not in flagged)
-            slot = free.pop(idx)
-            payload = self._queue.popleft()
-            self.live[slot] = payload
-            admitted.append((slot, payload))
-        if admitted:
-            self._sess.reset_streams([slot for slot, _ in admitted])
+        with spans.span(spans.ADMIT):
+            flagged = self._sess.flagged_slots()
+            admitted = []
+            while self._queue:
+                usable = [s for s in range(self.n_shards)
+                          if any(sl not in flagged for sl in self._free[s])]
+                if not usable:
+                    break                 # full, or only unhealthy slots
+                shard = min(usable, key=self._shard_load)
+                free = self._free[shard]  # pop highest-priority healthy
+                idx = next(i for i in range(len(free) - 1, -1, -1)
+                           if free[i] not in flagged)
+                slot = free.pop(idx)
+                payload = self._queue.popleft()
+                self.live[slot] = payload
+                admitted.append((slot, payload))
+            if admitted:
+                self._sess.reset_streams([slot for slot, _ in admitted])
         return admitted
 
     def _shard_load(self, shard: int) -> int:

@@ -159,6 +159,7 @@ def detector_step(cfg: DetectorConfig, state: DetectorState, post: Array
     return DetectorState(smooth=smooth, active=active, refract=refract), event
 
 
+@jax.named_scope("kws.head")
 def detector_scan(cfg: DetectorConfig, state: DetectorState, posts: Array
                   ) -> tuple[DetectorState, Array]:
     """Run the decision head over a chunk of frames.

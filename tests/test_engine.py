@@ -2,7 +2,7 @@
 
 Three families:
 
-* Fake-clock SLO telemetry — the engine's percentile / host-blocked /
+* Fake-clock SLO telemetry — the engine's percentile / host-span /
   throughput math checked against a scripted clock and a stub session
   (the regression tests for the serve-loop timing-skew bugfix: wall
   timing must come from the injectable monotonic clock, warmup must
@@ -72,14 +72,31 @@ def test_engine_rejects_bad_depth():
         PipelinedEngine(StubSession(), depth=0)
 
 
+class SpanStubSession(StubSession):
+    """A StubSession whose ``process_audio`` enters the dispatch phases
+    (police, frame, h2d, launch) the real session enters."""
+
+    def process_audio(self, piece):
+        from repro.launch import spans
+        for name in spans.DISPATCH:
+            with spans.span(name):
+                pass
+        return super().process_audio(piece)
+
+
 def test_fake_clock_phase_attribution():
-    # Scripted clock: begin +0, submit reads t0 (+1ms assemble), after
-    # dispatch (+2ms), fetch t0 (+0), fetch t1 (+3ms), end (+1ms).
+    # Scripted clock, one tick per read: begin +0; the assemble span
+    # (+0, +1ms); police, frame, h2d, launch (+0, +0.5ms each: the 2ms
+    # dispatch); fetch (+0, +3ms); the e2e read +0; end (+1ms).
     # depth=1 → the fetch happens inside submit.
+    from repro.launch import spans
     from repro.launch.engine import PipelinedEngine
-    clk = FakeClock([0.0, 0.001, 0.002, 0.0, 0.003, 0.001])
-    eng = PipelinedEngine(StubSession(batch=2), depth=1, clock=clk)
+    clk = FakeClock([0.0, 0.0, 0.001] + [0.0, 0.0005] * 4
+                    + [0.0, 0.003, 0.0, 0.001])
+    eng = PipelinedEngine(SpanStubSession(batch=2), depth=1, clock=clk)
     eng.begin()
+    with spans.span(spans.ASSEMBLE):
+        pass
     piece_frames, drained = eng.submit([None])
     eng.end()
     assert piece_frames == [4] and len(drained) == 1
@@ -90,8 +107,15 @@ def test_fake_clock_phase_attribution():
     assert hb["dispatch"] == pytest.approx(2.0)
     assert hb["fetch"] == pytest.approx(3.0)
     assert hb["total"] == pytest.approx(6.0)
+    # The same phases under their span names; the 1 ms between the
+    # fetch and end() is in the step but in no phase.
+    host = rep["host_ms_per_step"]
+    assert host["kws.police"] == pytest.approx(0.5)
+    assert host["kws.fetch"] == pytest.approx(3.0)
+    assert host["untraced"] == pytest.approx(1.0)
     # Step wall time = everything from begin to end = 7 ms.
     assert rep["step_ms"]["p50"] == pytest.approx(7.0)
+    assert host["kws.step"] == pytest.approx(7.0)
     # e2e decision latency = begin → fetch done = 6 ms.
     assert rep["e2e_ms"]["p50"] == pytest.approx(6.0)
     assert rep["decisions"] == 4 * 2
@@ -255,7 +279,8 @@ def test_summary_carries_slo_block():
     assert done                             # everything served
     slo = stats["slo"]
     for key in ("step_ms", "e2e_ms", "host_blocked_ms_per_step",
-                "shard_imbalance", "decisions_per_s_steady"):
+                "host_ms_per_step", "shard_imbalance",
+                "decisions_per_s_steady"):
         assert key in slo
     assert slo["step_ms"].keys() == {"p50", "p99", "p999"}
 
