@@ -43,6 +43,17 @@ injectable ``clock`` so the math is testable with a fake clock.
 ``host_blocked_ms_per_step`` keeps its historical keys as sums of spans
 (assemble; dispatch = police + frame + h2d + launch; fetch; total).
 ``report()`` feeds ``StreamSummary.slo`` and ``BENCH_serve.json``.
+
+Staging ring: the loop drivers assemble each step's block into
+``PipelinedEngine.staging`` — ``depth`` host buffers, allocated on first
+use and again only when the block's shape or dtype changes, so a step
+writes its audio once into memory that is already mapped.  A buffer is
+handed out again only after the step that read it has been fetched (a
+fetched result means the executable, and so its input, is done).
+``report()`` counts the ring's ``staging_allocated`` and
+``staging_reused`` blocks, beside the session's ``frame_joins`` (steps
+that had to join a carried sample remainder; 0 for frame-aligned
+chunks, where the block reaches the device with no further copy).
 """
 from __future__ import annotations
 
@@ -66,6 +77,17 @@ def percentiles_ms(samples_s: Sequence[float]) -> dict:
     return {"p50": float(np.percentile(ms, 50)),
             "p99": float(np.percentile(ms, 99)),
             "p999": float(np.percentile(ms, 99.9))}
+
+
+def _aligned_empty(shape, dtype, align: int = 64) -> np.ndarray:
+    """``np.empty`` starting on an ``align``-byte boundary.  On CPU,
+    ``jnp.asarray`` shares such a buffer instead of copying it, so the
+    device reads the staging block itself — and a block rewritten too
+    early would show in the CPU tests."""
+    nbytes = int(np.prod(shape)) * dtype.itemsize
+    raw = np.empty(nbytes + align, np.uint8)
+    off = -raw.ctypes.data % align
+    return raw[off:off + nbytes].view(dtype).reshape(shape)
 
 
 class FetchedStep:
@@ -106,7 +128,7 @@ class PipelinedEngine:
         while serving:
             eng.begin()                     # the kws.step span opens
             with spans.span(spans.ASSEMBLE):
-                block = ...                 # host work (slicing, faults)
+                block = eng.staging(shape)  # fill every row (host work)
             _, drained = eng.submit([block], meta=...)   # dispatch + drain
             with spans.span(spans.SLOTS):
                 ...                         # dispatch-time bookkeeping
@@ -146,8 +168,33 @@ class PipelinedEngine:
         self._e2e_s: list[float] = []
         self._imbalance: list[int] = []
         self.decisions = 0
+        self._ring: list[np.ndarray | None] = [None] * depth
+        self.staging_allocated = 0          # ring blocks allocated
+        self.staging_reused = 0             # ring blocks handed out again
 
     # ------------------------------------------------------- phases --
+
+    def staging(self, shape, dtype=np.float32) -> np.ndarray:
+        """The host block for the step about to be submitted: take one
+        per step, before ``submit``, and write every element of it (it
+        holds what an earlier step left).
+
+        Ring slot ``step % depth``: after the previous ``submit`` at
+        most ``depth - 1`` steps are unfetched, so the step that last
+        read this slot (``depth`` steps ago) has been fetched.  A new
+        shape or dtype drops the whole ring (buffers still in flight
+        stay alive with the steps that hold them)."""
+        shape, dtype = tuple(shape), np.dtype(dtype)
+        i = self._index % self.depth
+        buf = self._ring[i]
+        if buf is not None and buf.shape == shape and buf.dtype == dtype:
+            self.staging_reused += 1
+            return buf
+        if buf is not None:
+            self._ring = [None] * self.depth
+        self._ring[i] = buf = _aligned_empty(shape, dtype)
+        self.staging_allocated += 1
+        return buf
 
     def begin(self):
         """Open a step (the ``kws.step`` span): the phases entered until
@@ -270,6 +317,10 @@ class PipelinedEngine:
             "host_ms_per_step": host,
             "shard_imbalance": {"mean": float(imb.mean()),
                                 "max": int(imb.max())},
+            "staging_allocated": self.staging_allocated,
+            "staging_reused": self.staging_reused,
+            # the session's count since its last reset
+            "frame_joins": getattr(self.session, "frame_joins", 0),
         }
 
 
@@ -357,11 +408,17 @@ def run_audio_requests(sess, sched, ctl, *, audio_q, chunk: int,
     while not sched.idle:
         eng.begin()
         with spans.span(spans.ASSEMBLE):
-            block = np.zeros((sess.batch, chunk), np.float32)
+            block = eng.staging((sess.batch, chunk))
+            idle = np.ones(sess.batch, bool)
             for slot, req in sched.live.items():
                 c0 = incarnations[slot].progress[0]
                 seg = audio_q[req, c0 * chunk:(c0 + 1) * chunk]
-                block[slot, :len(seg)] = seg    # zero-pad a short chunk
+                n = len(seg)
+                block[slot, :n] = seg
+                if n < chunk:
+                    block[slot, n:] = 0.0       # zero-pad a short chunk
+                idle[slot] = False
+            block[idle] = 0.0                   # idle slots hear silence
             pieces, actions = ([block], []) if injector is None \
                 else injector.inject(block)
         contribs: list[tuple] = []          # filled below, post-submit
@@ -423,6 +480,10 @@ def run_continuous_detect(sess, streams_audio, *, chunk: int,
     shapes, so the fire positions are exact even though the event
     values land later.
 
+    ``streams_audio`` holds one 1-D sample array per slot; each step's
+    block is their ``[off:off + chunk]`` rows, copied into the engine's
+    staging ring in the arrays' common dtype (``np.stack``'s).
+
     Returns ``(fires, frame_base, stats)``: per-slot fire lists (for
     ``det_point``), the total frame count, and the loop stats + SLO
     report (also attached to the session summary).
@@ -435,6 +496,7 @@ def run_continuous_detect(sess, streams_audio, *, chunk: int,
     fires: list[list] = [[] for _ in range(slots)]
     frame_base = 0
     steps = 0
+    dtype = np.result_type(*streams_audio)
 
     def integrate(f: FetchedStep):
         for ev, base in zip(f.arrays, f.meta):
@@ -444,7 +506,15 @@ def run_continuous_detect(sess, streams_audio, *, chunk: int,
     for off in range(0, n_samples, chunk):
         eng.begin()
         with spans.span(spans.ASSEMBLE):
-            block = np.stack([s[off:off + chunk] for s in streams_audio])
+            segs = [s[off:off + chunk] for s in streams_audio]
+            width = len(segs[0])
+            block = eng.staging((len(segs), width), dtype)
+            for row, seg in enumerate(segs):
+                if len(seg) != width:
+                    raise ValueError(f"streams end at different samples: "
+                                     f"the chunk at {off} is {width} wide "
+                                     f"in stream 0, {len(seg)} in {row}")
+                block[row] = seg
             pieces, actions = ([block], []) if injector is None \
                 else injector.inject(block)
             for act in actions:

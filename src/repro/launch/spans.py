@@ -15,11 +15,12 @@ The spans, where they are entered, and the per-layer benchmark metric
   kws.step      ``PipelinedEngine.begin()`` … ``end()``: the whole step,
                 a ``StepTraceAnnotation`` numbered by step index; the
                 only span that encloses others            (``step_ms``)
-  kws.assemble  loop drivers: the step's block — allocation, per-slot
-                copy or ``np.stack``, fault injection     assemble_ms_per_step
+  kws.assemble  loop drivers: the step's block — per-slot row copies
+                into the engine's staging ring, fault injection
+                                                          assemble_ms_per_step
   kws.police    ``StreamingKwsSession._coerce_audio``: decode, dtype
-                copy, non-finite scan                     police_ms_per_step
-  kws.frame     ``process_audio``: remainder concatenation and frame
+                cast, non-finite check                    police_ms_per_step
+  kws.frame     ``process_audio``: join of a carried remainder, frame
                 slicing                                   frame_ms_per_step
   kws.h2d       ``process_audio``: ``jnp.asarray`` of the block, the
                 host→device put                           h2d_ms_per_step

@@ -418,6 +418,10 @@ class StreamSummary:
     recoveries: int = 0                    # slots auto-reset by supervisor
     recovery_reasons: dict = dataclasses.field(default_factory=dict)
     sat_events: int = 0                    # HEALTH_SAT slot-checks observed
+    # Chunks joined to a carried sample remainder: how the host framed
+    # the audio, not what the streams decided, so — unlike ``chunks`` —
+    # it takes no part in comparing two summaries.
+    frame_joins: int = dataclasses.field(default=0, compare=False)
     # Serve-loop SLO telemetry attached by the pipelined engine
     # (launch.engine): step/e2e latency percentiles, host time per span
     # (launch.spans), shard imbalance.  Empty unless a serve driver
@@ -1044,6 +1048,7 @@ class StreamingKwsSession:
         self._vad_state: VADState | None = None
         self._det_state: DetectorState | None = None
         self._audio_rem: np.ndarray | None = None   # carried tail samples
+        self.frame_joins = 0                # steps that joined a tail
         self._acc = shp.put_slot_sharded(_zero_accum(self.n_shards), mesh)
         self._chunks = 0
         self._input_dim = input_dim
@@ -1335,7 +1340,8 @@ class StreamingKwsSession:
         not audio — always rejected).  Float arrays are policed for
         non-finite samples according to the policy; anything else (text,
         objects, complex, bools) cannot be audio and raises
-        ``StreamInputError`` outright.
+        ``StreamInputError`` outright.  Clean float32 input comes back
+        as the caller's own array, uncopied.
         """
         arr = np.asarray(audio)
         if arr.dtype.kind in "iu":
@@ -1350,8 +1356,16 @@ class StreamingKwsSession:
             raise StreamInputError(
                 f"audio dtype {arr.dtype} is not decodable: pass float "
                 f"samples in [-1, 1) or int16-range integer codes")
-        arr = arr.astype(np.float32)
+        arr = arr.astype(np.float32, copy=False)
         if self.input_policy == "trust":
+            return arr
+        # A finite sum proves every sample finite (NaN and ±inf
+        # propagate through a sum) without a temporary; only a sum that
+        # is not finite — a bad sample, or huge finite ones overflowing
+        # — pays for the exact count.
+        with np.errstate(over="ignore", invalid="ignore"):
+            total = np.add.reduce(arr, axis=None)
+        if np.isfinite(total):
             return arr
         n_bad = int(np.count_nonzero(~np.isfinite(arr)))
         if n_bad:
@@ -1380,6 +1394,14 @@ class StreamingKwsSession:
         zero rows when the chunk is shorter than the carried remainder's
         complement).  Like ``process_chunk``, the step is compiled per
         chunk length.
+
+        Ownership: clean float32 audio is neither copied nor joined when
+        no remainder is carried (``frame_joins`` counts the steps that
+        joined one), so the dispatched step reads the caller's array —
+        on CPU ``jnp.asarray`` may alias it.  The session keeps no view
+        of that array past the call (the carried remainder is a copy);
+        a caller that reuses the array must first fetch the step's
+        result, as ``PipelinedEngine``'s staging ring does.
         """
         fex = self._require_fex()
         with spans.span(spans.POLICE):
@@ -1390,11 +1412,14 @@ class StreamingKwsSession:
             raise ValueError(f"audio carries {audio.shape[0]} streams, "
                              f"session was created with batch={self.batch}")
         with spans.span(spans.FRAME):
-            audio = np.concatenate([self._audio_rem, audio], axis=1)
+            if self._audio_rem.shape[1]:    # join the carried tail
+                audio = np.concatenate([self._audio_rem, audio], axis=1)
+                self.frame_joins += 1
             shift = fex.cfg.frame_shift
             n_frames = audio.shape[1] // shift
-            self._audio_rem = audio[:, n_frames * shift:]
-            block = audio[:, :n_frames * shift]
+            cut = n_frames * shift
+            self._audio_rem = audio[:, cut:].copy()   # never a view
+            block = audio[:, :cut]
         if n_frames == 0:
             z = jnp.zeros((0, self.batch), jnp.int32)
             logits = jnp.zeros((0, self.batch, self.n_classes))
@@ -1520,6 +1545,7 @@ class StreamingKwsSession:
         self._acc = shp.put_slot_sharded(_zero_accum(self.n_shards),
                                          self.mesh)
         self._chunks = 0
+        self.frame_joins = 0
         self._last_health = None
         self._strikes = np.zeros((self.batch,), np.int64)
         self._recoveries = 0
@@ -1689,7 +1715,8 @@ class StreamingKwsSession:
             overflow = overflow or sat
         robust = dict(overflowed=overflow, recoveries=self._recoveries,
                       recovery_reasons=dict(self._recovery_reasons),
-                      sat_events=self._sat_events, slo=dict(self._slo))
+                      sat_events=self._sat_events,
+                      frame_joins=self.frame_joins, slo=dict(self._slo))
         if totals["frames"] == 0:
             # Nothing processed yet: report an identifiable empty state,
             # not a spurious 100%-sparsity / 0-energy datapoint.

@@ -194,19 +194,29 @@ def _session_bits():
 
 
 def _audio_run(depth, *, numerics="float32", faults=None, chunk=1000,
-               requests=5, slots=2):
-    """One full kws-audio serve through the loop driver at ``depth``."""
+               requests=5, slots=2, utt=4000, record=None):
+    """One full kws-audio serve through the loop driver at ``depth``
+    (0.5 s utterances).  ``record``, a list, receives per dispatched
+    piece a copy of the block as handed to the session and its (device)
+    vote array."""
     from repro.launch.engine import run_audio_requests
     from repro.launch.faults import FaultInjector, FaultPlan, \
         parse_fault_specs
     from repro.launch.streaming import SlotScheduler, StreamingKwsSession
     cfg, fex, params = _session_bits()
-    utt = 4000                              # 0.5 s utterances
     rng = np.random.default_rng(11)
     audio_q = rng.uniform(-0.5, 0.5, (requests, utt)).astype(np.float32)
     sess = StreamingKwsSession(params, cfg, threshold=0.1, batch=slots,
                                fex=fex, numerics=numerics,
                                input_policy="trust")
+    if record is not None:
+        serve = sess.process_audio
+
+        def process_audio(block):
+            out = serve(block)
+            record.append((np.array(block), out.votes))
+            return out
+        sess.process_audio = process_audio
     sched = SlotScheduler(sess)
     for req in range(requests):
         sched.submit(req)
@@ -224,18 +234,52 @@ def _audio_run(depth, *, numerics="float32", faults=None, chunk=1000,
     return done, stats, summ
 
 
+@pytest.fixture(scope="module")
+def fresh_block_run():
+    """The synchronous loop with a fresh zeroed block every step — the
+    loop as it was before the staging ring — run once per numerics."""
+    from repro.launch.engine import PipelinedEngine
+    runs: dict = {}
+
+    def run(numerics):
+        if numerics not in runs:
+            record: list = []
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(PipelinedEngine, "staging",
+                           lambda self, shape, dtype=np.float32:
+                           np.zeros(shape, dtype))
+                done, stats, summ = _audio_run(1, numerics=numerics,
+                                               record=record)
+            runs[numerics] = done, stats, summ, record
+        return runs[numerics]
+    return run
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
 @pytest.mark.parametrize("numerics", ["float32", "int8"])
-def test_audio_conformance_async_equals_sync(numerics):
+def test_audio_conformance_async_equals_sync(fresh_block_run, numerics,
+                                             depth):
     # chunk=1000 is NOT frame-aligned (frame shift 128): every step
     # carries a sample remainder across the chunk boundary, the hardest
-    # alignment case for late integration.
-    done1, stats1, summ1 = _audio_run(1, numerics=numerics)
-    done2, stats2, summ2 = _audio_run(2, numerics=numerics)
+    # alignment case for late integration.  The reference is the sync
+    # loop on fresh blocks; every depth runs on the engine's staging
+    # ring, whose blocks the CPU device reads in place.
+    done1, stats1, summ1, rec1 = fresh_block_run(numerics)
+    rec: list = []
+    done2, stats2, summ2 = _audio_run(depth, numerics=numerics, record=rec)
     assert done2 == done1                   # same requests, same classes
     assert summ2 == summ1                   # every telemetry counter
     assert stats2["frames_served"] == stats1["frames_served"]
     assert stats2["pad_frames"] == stats1["pad_frames"]
     assert stats2["steps"] == stats1["steps"]
+    assert len(rec) == len(rec1)
+    for (block2, votes2), (block1, votes1) in zip(rec, rec1):
+        np.testing.assert_array_equal(block2, block1)
+        np.testing.assert_array_equal(np.asarray(votes2),
+                                      np.asarray(votes1))
+    # No chunk but the first starts on a frame boundary (1000·k mod 128
+    # is 0 only at k = 16), so every later step joins a remainder.
+    assert summ2.frame_joins == stats2["steps"] - 1
 
 
 def test_audio_conformance_under_fault_storms():
@@ -283,6 +327,128 @@ def test_summary_carries_slo_block():
                 "decisions_per_s_steady"):
         assert key in slo
     assert slo["step_ms"].keys() == {"p50", "p99", "p999"}
+
+
+# ---------------------------------------------------------------------------
+# The staging ring and block ownership
+
+def test_staging_ring_reuses_a_block_only_after_its_fetch():
+    from repro.launch.engine import PipelinedEngine
+    depth = 3
+    eng = PipelinedEngine(StubSession(), depth=depth,
+                          clock=FakeClock([0.0] * 999))
+    blocks = []
+    for k in range(8):
+        eng.begin()
+        buf = eng.staging((2, 8))
+        assert buf.shape == (2, 8) and buf.dtype == np.float32
+        assert buf.ctypes.data % 64 == 0    # CPU asarray shares it
+        # Never a block an unfetched step holds; the one of the step
+        # ``depth`` back, which the last submit fetched.
+        unfetched = blocks[len(blocks) - eng.in_flight:]
+        assert all(buf is not b for b in unfetched)
+        if k >= depth:
+            assert buf is blocks[k - depth]
+        blocks.append(buf)
+        eng.submit([buf])
+        eng.end()
+    rep = eng.report()
+    assert rep["staging_allocated"] == depth
+    assert rep["staging_reused"] == 8 - depth
+    eng.begin()
+    wide = eng.staging((2, 16))             # a new shape: a new ring
+    assert all(wide is not b for b in blocks)
+    assert eng.staging_allocated == depth + 1
+    assert eng.staging((2, 16), np.float64).dtype == np.float64
+    assert eng.staging_allocated == depth + 2
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_staging_idle_rows_and_short_tails_are_zero(depth):
+    # 3 slots, 4 requests of 4000 samples in 1024-sample chunks: steps
+    # 0-3 serve requests 0-2, the last chunk 928 samples wide; steps
+    # 4-7 serve request 3 alone, so two slots that held audio go idle.
+    # The ring hands back blocks that held those rows: stale samples
+    # would show.
+    utt, chunk = 4000, 1024
+    rec: list = []
+    _audio_run(depth, chunk=chunk, requests=4, slots=3, utt=utt,
+               record=rec)
+    audio_q = np.random.default_rng(11).uniform(
+        -0.5, 0.5, (4, utt)).astype(np.float32)
+    assert len(rec) == 8
+    for k, (block, _) in enumerate(rec):
+        c = k % 4
+        want = []
+        for req in (range(3) if k < 4 else [3]):
+            row = np.zeros(chunk, np.float32)
+            seg = audio_q[req, c * chunk:(c + 1) * chunk]
+            row[:len(seg)] = seg
+            want.append(row)
+        live = [row for row in block if np.any(row != 0)]
+        key = lambda r: r[0]                # audio samples are never 0
+        assert len(live) == len(want)
+        for got, exp in zip(sorted(live, key=key), sorted(want, key=key)):
+            np.testing.assert_array_equal(got, exp)
+        zero = [row for row in block if not np.any(row != 0)]
+        assert len(zero) == 3 - len(want)
+        assert all(not np.signbit(row).any() for row in zero)
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_staging_counters_on_aligned_chunks(depth):
+    from repro.launch.engine import run_continuous_detect
+    from repro.launch.streaming import StreamingKwsSession
+    from repro.models.detector import DetectorConfig
+    # 1024 = 8 frames: no step carries a remainder.
+    _, stats, summ = _audio_run(depth, chunk=1024)
+    slo = stats["slo"]
+    assert slo["staging_allocated"] == depth
+    assert slo["staging_reused"] == stats["steps"] - depth
+    assert slo["frame_joins"] == 0 and summ.frame_joins == 0
+    cfg, fex, params = _session_bits()
+    audio = np.random.default_rng(12).uniform(
+        -0.5, 0.5, (2, 6144)).astype(np.float32)
+    sess = StreamingKwsSession(params, cfg, threshold=0.1, batch=2,
+                               fex=fex, detector=DetectorConfig())
+    _, _, stats = run_continuous_detect(sess, list(audio), chunk=1024,
+                                        n_samples=6144, depth=depth,
+                                        warm=False)
+    slo = stats["slo"]
+    assert slo["staging_allocated"] == depth
+    assert slo["staging_reused"] == 6 - depth
+    assert slo["frame_joins"] == 0 and sess.summary().frame_joins == 0
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_frame_path_keeps_no_view_of_the_callers_array(batch):
+    # Non-aligned 1000-sample chunks fed through ONE 64-byte aligned
+    # buffer (CPU asarray reads it in place): after each step's result
+    # is fetched the buffer is poisoned with NaN, then refilled.  A
+    # carried remainder that viewed the buffer would carry the NaN into
+    # the next step; the run must equal one fed fresh arrays.
+    from repro.launch.engine import _aligned_empty
+    from repro.launch.streaming import StreamingKwsSession
+    cfg, fex, params = _session_bits()
+    audio = np.random.default_rng(13).uniform(
+        -0.5, 0.5, (batch, 6000)).astype(np.float32)
+
+    def session():
+        return StreamingKwsSession(params, cfg, threshold=0.1, batch=batch,
+                                   fex=fex, input_policy="trust")
+
+    reused, fresh = session(), session()
+    buf = _aligned_empty((batch, 1000), np.dtype(np.float32))
+    for lo in range(0, 6000, 1000):
+        buf[:] = audio[:, lo:lo + 1000]
+        got = reused.process_audio(buf)
+        got = [np.asarray(got.logits), np.asarray(got.votes)]
+        buf[:] = np.nan
+        want = fresh.process_audio(audio[:, lo:lo + 1000].copy())
+        np.testing.assert_array_equal(got[0], np.asarray(want.logits))
+        np.testing.assert_array_equal(got[1], np.asarray(want.votes))
+    assert reused.summary() == fresh.summary()
+    assert reused.frame_joins == fresh.frame_joins == 5
 
 
 ENGINE_MESH_CHILD = r"""

@@ -255,6 +255,75 @@ def test_integer_codes_decode_and_out_of_range_rejects(kws_bits):
         _session(kws_bits, input_policy="yolo")
 
 
+def _coerce_as_before(arr, policy):
+    """The float policing as it was before the sum-based check: a
+    float32 copy, then an exact count of the non-finite samples."""
+    arr = np.asarray(arr).astype(np.float32)
+    if policy == "trust":
+        return arr
+    n_bad = int(np.count_nonzero(~np.isfinite(arr)))
+    if n_bad:
+        if policy == "reject":
+            raise StreamInputError(
+                f"{n_bad} non-finite samples in audio chunk "
+                f"(input_policy='reject'; use 'sanitize' to squash "
+                f"them instead)")
+        arr = np.nan_to_num(arr, nan=0.0, posinf=1.0 - 2.0 ** -11,
+                            neginf=-1.0)
+    return arr
+
+
+def _hostile(kind):
+    a = _audio(2, seed=21)
+    if kind == "nan":
+        a[0, 5] = np.nan
+    elif kind == "posinf":
+        a[1, 0] = np.inf
+    elif kind == "neginf":
+        a[0, -1] = -np.inf
+    elif kind == "both_inf":            # their sum is NaN, not ±inf
+        a[0, 3], a[1, 9] = np.inf, -np.inf
+    elif kind == "overflow":            # finite, but the float32 sum is inf
+        a[:, :64] = 3e38
+    elif kind == "overflow_f64":        # finite float64, decoded to float32
+        a = a.astype(np.float64)
+        a[0, :64] = -3e38
+    return a
+
+
+@pytest.mark.parametrize("policy", ["reject", "sanitize", "trust"])
+@pytest.mark.parametrize("kind", ["clean", "nan", "posinf", "neginf",
+                                  "both_inf", "overflow", "overflow_f64"])
+def test_finite_check_matches_the_exact_count(kws_bits, kind, policy):
+    sess = _session(kws_bits, input_policy=policy)
+    arr = _hostile(kind)
+    try:
+        want = _coerce_as_before(arr.copy(), policy)
+    except StreamInputError as e:
+        with pytest.raises(StreamInputError) as got:
+            sess._coerce_audio(arr)
+        assert str(got.value) == str(e)
+        return
+    out = sess._coerce_audio(arr)
+    assert out.dtype == np.float32
+    np.testing.assert_array_equal(out, want)    # NaN == NaN here
+    if kind.startswith("overflow"):             # finite: never rejected
+        assert np.isfinite(out).all()
+
+
+@pytest.mark.parametrize("policy", ["reject", "sanitize", "trust"])
+def test_clean_float32_is_not_copied_and_codes_still_decode(kws_bits,
+                                                            policy):
+    sess = _session(kws_bits, input_policy=policy)
+    clean = _audio(2, seed=22)
+    assert sess._coerce_audio(clean) is clean
+    codes = np.round(clean * 32767.0).astype(np.int16)
+    out = sess._coerce_audio(codes)
+    assert out.dtype == np.float32
+    np.testing.assert_array_equal(out, codes.astype(np.float32) / 32768.0)
+    assert not np.shares_memory(out, codes)
+
+
 # ------------------------------------------------------- exact telemetry
 def test_split_counters_exact_past_float32_wedge():
     """The counters keep ±1 exactness past 2²⁴ — exactly where a float32
