@@ -9,9 +9,12 @@ read the same work whatever implements it.
     envelope (|y|, two multiplies, an add): 16 operations; per frame and
     channel, the log2 compression onto the 12-bit grid: 10 operations.
     Bytes: audio in, filter state in and out, features out, coefficients.
-  ΔGRU, per frame and slot, dense as the kernel runs it today:
-    2·(I+H)·3H for the two contractions, 6H to accumulate M, 11H for the
-    gates.  Bytes: features in, hidden out, state in and out, weights.
+  ΔGRU, per frame and slot, dense as the kernel runs it today, summed
+    over the configuration's layers (I = input_dim for the first, H for
+    each layer above, which reads the hidden state below): 2·(I+H)·3H
+    for the two contractions, 6H to accumulate M, 11H for the gates.
+    Bytes, each layer: its input in (features, or the hidden state
+    below), hidden out, state in and out, weights.
   FC, per frame and slot: 2·H·K + K.
   ADC quantizer, per sample: 7 (clip, scale, round, clip).
 
@@ -20,6 +23,8 @@ Peaks are per chip, keyed by the device kind JAX reports.
 from __future__ import annotations
 
 import dataclasses
+
+from bench.model import layers
 
 # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8,
 # 16 GB HBM at 819 GB/s per chip.  No float32 or vector-unit rate is
@@ -81,20 +86,32 @@ def fex_call(config: dict, slots: int, samples: int) -> Work:
     return Work(ops, nbytes)
 
 
-def gru_frame_ops(config: dict) -> int:
+def _layer_inputs(config: dict) -> list[tuple[int, int]]:
+    """(input width, bytes per input element) of each ΔGRU layer, the
+    first first: the features, then the hidden state of the layer below."""
     I, H = int(config["input_dim"]), int(config["hidden"])
-    return 2 * (I + H) * 3 * H + 6 * H + 11 * H
+    dt = config["dtypes"]
+    return ([(I, BYTES[dt["features"]])]
+            + [(H, BYTES[dt["hidden"]])] * (layers(config) - 1))
+
+
+def gru_frame_ops(config: dict) -> int:
+    H = int(config["hidden"])
+    return sum(2 * (I + H) * 3 * H + 6 * H + 11 * H
+               for I, _ in _layer_inputs(config))
 
 
 def gru_call(config: dict, slots: int, frames: int) -> Work:
-    """One ΔGRU call over ``slots`` × ``frames`` (dense)."""
-    I, H = int(config["input_dim"]), int(config["hidden"])
+    """The ΔGRU stack over ``slots`` × ``frames`` (dense): one call of
+    the kernel per layer."""
+    H = int(config["hidden"])
     dt = config["dtypes"]
-    bf, bh, ba = (BYTES[dt["features"]], BYTES[dt["hidden"]],
-                  BYTES[dt["accumulator"]])
-    state = (2 * H) * bh + I * bf + 6 * H * ba          # h, ĥ, x̂, M
-    nbytes = (slots * frames * (I * bf + H * bh) + 2 * slots * state
-              + (I + H) * 3 * H * BYTES[dt["weights"]])
+    bh, ba = BYTES[dt["hidden"]], BYTES[dt["accumulator"]]
+    nbytes = 0
+    for I, bi in _layer_inputs(config):
+        state = (2 * H) * bh + I * bi + 6 * H * ba      # h, ĥ, x̂, M
+        nbytes += (slots * frames * (I * bi + H * bh) + 2 * slots * state
+                   + (I + H) * 3 * H * BYTES[dt["weights"]])
     return Work(slots * frames * gru_frame_ops(config), nbytes)
 
 

@@ -10,7 +10,8 @@ its own offset, so few distinct streams feed many slots.
 The loop takes its length up front, so the window is sized from the warm
 step time: ``seconds`` over the step time, rounded up to whole reference
 blocks.  The check compares the events the loop fired and every slot's
-final hidden state with the plain reference run over the same audio.
+final hidden state, every layer's, with the plain reference run over the
+same audio.
 """
 from __future__ import annotations
 
@@ -21,7 +22,7 @@ import time
 import numpy as np
 
 from bench import generator
-from bench.probes import Probe
+from bench.probes import Probe, hidden_values
 
 
 @dataclasses.dataclass
@@ -105,8 +106,10 @@ def serve(prep: Prepared, run) -> dict:
 
 
 def final_state(prep: Prepared) -> np.ndarray:
-    h = np.asarray(prep.session.state.h)
-    return h.astype(np.float64) * (2.0 ** -15 if h.dtype.kind == "i" else 1)
+    """The slots' hidden state after the window, as values, (B, L·H):
+    every layer's ``h``, the first layer's first (``probes.hidden_values``:
+    the session's ``state`` is one ΔGRU state, or a sequence of L)."""
+    return hidden_values(prep.session)
 
 
 def verify(prep: Prepared, served: dict, h_final: np.ndarray, ref,

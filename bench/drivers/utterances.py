@@ -10,9 +10,9 @@ loop drains what is live.  Request ``r`` plays pool clip ``r % pool``.
 
 The check compares, for every request the window served, its per-frame
 votes (the arrays the loop fetched) and its decision with the plain
-reference run on the same clip, and the slots' final hidden state with
-the reference run over each slot's last request and the zero chunks an
-idle slot processes after it.
+reference run on the same clip, and the slots' final hidden state, every
+layer's, with the reference run over each slot's last request and the
+zero chunks an idle slot processes after it.
 """
 from __future__ import annotations
 
@@ -22,7 +22,7 @@ import time
 import numpy as np
 
 from bench import generator
-from bench.probes import SPAN_ADMIT, Probe, annotation
+from bench.probes import SPAN_ADMIT, Probe, annotation, hidden_values
 
 
 def _scheduler_class():
@@ -151,9 +151,10 @@ def serve(prep: Prepared, run) -> dict:
 
 
 def final_state(prep: Prepared) -> np.ndarray:
-    """The slots' hidden state after the window, as values."""
-    h = np.asarray(prep.session.state.h)
-    return h.astype(np.float64) * (2.0 ** -15 if h.dtype.kind == "i" else 1)
+    """The slots' hidden state after the window, as values, (B, L·H):
+    every layer's ``h``, the first layer's first (``probes.hidden_values``:
+    the session's ``state`` is one ΔGRU state, or a sequence of L)."""
+    return hidden_values(prep.session)
 
 
 def verify(prep: Prepared, served: dict, h_final: np.ndarray, ref,

@@ -61,6 +61,7 @@ def _check_program_config(cfg: dict, arch, fcfg):
     """The program must serve the sizes this configuration file states."""
     f = cfg["fex"]
     pairs = {
+        "layers": (arch.num_layers, model.layers(cfg)),
         "hidden": (arch.d_model, cfg["hidden"]),
         "classes": (arch.vocab_size, cfg["classes"]),
         "sample_rate_hz": (fcfg.fs, cfg["sample_rate_hz"]),
@@ -117,12 +118,14 @@ class Reference:
         return out
 
     def _hidden(self, state) -> np.ndarray:
-        h = np.asarray(state.h).astype(np.float64)
+        """Every layer's hidden state, (B, L·H), the first layer's first."""
+        h = np.concatenate([np.asarray(h) for h in state.h],
+                           axis=-1).astype(np.float64)
         return h * 2.0 ** -15 if self.static.numerics == "int8" else h
 
     def detect(self, views: list, n_samples: int):
         """Always-on mode over per-slot audio: (events (F, B), final
-        hidden values (B, H))."""
+        hidden values (B, L·H))."""
         B = len(views)
         block = self.block_frames * self.static.frame_shift
         state = self._mod.init_state(self.model, self.static, B)

@@ -1,4 +1,13 @@
-"""Weights of a configuration, made from the seed on the device."""
+"""Weights of a configuration, made from the seed on the device.
+
+A configuration states a stack of ``layers`` ΔGRU layers (default 1), all
+of width ``hidden``: the first reads the ``input_dim`` features, each
+layer above reads the hidden state of the one below, and the FC head
+reads the top layer.  The weights are one dict, as the program's session
+is handed them: the first layer's ``w_x, w_h, b``, the head's ``w_fc,
+b_fc``, and layer ``l`` above the first (``l`` = 1, 2, ...) as
+``w_x_<l>, w_h_<l>, b_<l>``.
+"""
 from __future__ import annotations
 
 import numpy as np
@@ -11,7 +20,20 @@ def jax_key(seed: int):
         int(np.random.default_rng(seed).integers(0, 2 ** 31 - 1)))
 
 
-def _init_fn(input_dim: int, hidden: int, classes: int, w: dict):
+def layers(config: dict) -> int:
+    """Depth of the configuration's ΔGRU stack."""
+    return int(config.get("layers", 1))
+
+
+def layer_keys(layer: int) -> tuple[str, str, str]:
+    """The keys of layer ``layer``'s (0: the first) input weights,
+    recurrent weights and bias."""
+    suffix = f"_{layer}" if layer else ""
+    return ("w_x" + suffix, "w_h" + suffix, "b" + suffix)
+
+
+def _init_fn(input_dim: int, hidden: int, classes: int, depth: int,
+             w: dict):
     import jax
     import jax.numpy as jnp
 
@@ -23,19 +45,31 @@ def _init_fn(input_dim: int, hidden: int, classes: int, w: dict):
     def init(key):
         k = jax.random.split(key, 4)
         H3 = 3 * hidden
-        return {
+        params = {
             "w_x": scaled(k[0], (input_dim, H3), w["w_x_max"]),
             "w_h": scaled(k[1], (hidden, H3), w["w_h_max"]),
             "b": w["b_std"] * jax.random.normal(k[2], (H3,), jnp.float32),
             "w_fc": scaled(k[3], (hidden, classes), w["w_fc_max"]),
             "b_fc": jnp.zeros((classes,), jnp.float32),
         }
+        # Layers above the first draw from keys of their own, after the
+        # first layer's and the head's; their input is a hidden state, of
+        # the recurrent weights' range.
+        for layer in range(1, depth):
+            kl = jax.random.split(jax.random.fold_in(key, layer), 3)
+            w_x, w_h, b = layer_keys(layer)
+            params[w_x] = scaled(kl[0], (hidden, H3), w["w_h_max"])
+            params[w_h] = scaled(kl[1], (hidden, H3), w["w_h_max"])
+            params[b] = w["b_std"] * jax.random.normal(kl[2], (H3,),
+                                                       jnp.float32)
+        return params
     return init
 
 
 def make_params(config: dict, seed: int) -> dict:
-    """float32 weights as the program serves them, in one jitted call on
-    the default device (the int8 session promotes them itself).
+    """float32 weights as the program serves them, every layer's in one
+    jitted call on the default device (the int8 session promotes them
+    itself).
 
     Each weight tensor is normal, scaled so that its largest magnitude is
     the configuration's ``*_max``: the int8 path's per-tensor power-of-two
@@ -44,5 +78,5 @@ def make_params(config: dict, seed: int) -> dict:
     w = {k: float(config["weights"][k])
          for k in ("w_x_max", "w_h_max", "w_fc_max", "b_std")}
     init = _init_fn(int(config["input_dim"]), int(config["hidden"]),
-                    int(config["classes"]), w)
+                    int(config["classes"]), layers(config), w)
     return init(jax_key(seed))

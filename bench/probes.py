@@ -9,6 +9,8 @@ check can compare exactly what the timed path produced.
 
 ``CompileCounter`` counts JAX trace and compile events: the harness fails
 a run in whose window one happened.
+
+``hidden_values`` reads the slots' hidden state out of a session.
 """
 from __future__ import annotations
 
@@ -20,6 +22,18 @@ SPAN_ADMIT = "bench.admit"             # SlotScheduler.admit
 SPAN_RESET = "bench.reset_streams"     # session.reset_streams
 SPAN_WINDOW = "bench.window"           # the measured window
 SPANS = (SPAN_DISPATCH, SPAN_FETCH, SPAN_ADMIT, SPAN_RESET)
+
+
+def hidden_values(session) -> np.ndarray:
+    """The slots' hidden state as values, (B, L·H): each layer's ``h``
+    side by side, the first layer's first, int16 codes read at 15
+    fraction bits.  The session's ``state`` is the one ΔGRU layer's state
+    (``h``: (B, H)), or for a stack of L layers a sequence of L such
+    states, the first layer's first."""
+    state = session.state
+    states = [state] if hasattr(state, "h") else list(state)
+    h = np.concatenate([np.asarray(s.h) for s in states], axis=-1)
+    return h.astype(np.float64) * (2.0 ** -15 if h.dtype.kind == "i" else 1)
 
 
 def annotation(name: str):

@@ -25,6 +25,16 @@ Two numerics, as the configuration states:
            scales, int16 hidden state, a 24-bit saturating accumulator,
            σ/tanh evaluated exactly on the dequantized pre-activation.
 
+A configuration may state ``layers``, a stack of ΔGRU layers of width
+``hidden`` (default one): the first reads the features, each layer above
+reads the hidden state the layer below computed in the same frame, and
+the FC head reads the top layer.  The weights' keys are
+``bench/model.py``'s (``w_x, w_h, b``, then ``w_x_<l>, w_h_<l>, b_<l>``).
+In int8 a layer above the first Δ-encodes the int16 hidden codes of the
+layer below (``hid_frac`` fraction bits, its Δ threshold on that grid)
+as its input; its input weights take a power-of-two scale of their own,
+and its accumulator, saturation and gates are the first layer's.
+
 ``precision`` selects the comparison's control: "high" computes every
 float contraction in three bfloat16 passes (what the TPU's ``high``
 precision does), and ``weight_bits=4`` rounds the int8 weights onto the
@@ -39,6 +49,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.model import layer_keys, layers
 
 NO_EVENT = -1
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -125,12 +137,17 @@ def filter_bank(fex: dict):
 
 
 # ------------------------------------------------------------- the model
+class GruLayer(NamedTuple):
+    """One ΔGRU layer's weights: float32, or int8 codes and int32 bias."""
+    w_x: jax.Array           # (I, 3H): I = C for the first layer, H above
+    w_h: jax.Array           # (H, 3H)
+    b: jax.Array             # (3H,)
+
+
 class Model(NamedTuple):
     """Everything a block step needs, as device arrays and static ints."""
     coef: jax.Array          # (6, C) float32 rows, or int32 codes
-    w_x: jax.Array           # (I, 3H)
-    w_h: jax.Array           # (H, 3H)
-    b: jax.Array             # (3H,)
+    gru: tuple               # a GruLayer per layer, the first first
     w_fc: jax.Array          # (H, K)
     b_fc: jax.Array          # (K,)
 
@@ -157,8 +174,8 @@ class Static:
     hid_frac: int = 15
     acc_frac: int = 18
     acc_bits: int = 24
-    shift_x: int = 0
-    shift_h: int = 0
+    shift_x: tuple = ()      # per layer
+    shift_h: tuple = ()
     logit_frac: int = 0
     # always-on head
     vad_threshold: float = -1.0
@@ -197,6 +214,7 @@ def build(config: dict, params: dict, *, precision: str = "highest",
     vad = config.get("vad", {"energy_threshold": -1.0, "hangover_frames": 0})
     p = {k: np.asarray(v, np.float64) for k, v in params.items()}
     H = int(config["hidden"])
+    keys = [layer_keys(layer) for layer in range(layers(config))]
     common = dict(
         precision=precision, frame_shift=int(fex["frame_shift"]),
         env_alpha=alpha, log_eps=float(fex["log_eps"]),
@@ -209,28 +227,35 @@ def build(config: dict, params: dict, *, precision: str = "highest",
         refractory=int(det.get("refractory_frames", 0)),
         first_keyword=int(det.get("first_keyword", 0)))
     if config["numerics"] == "float32":
-        model = Model(coef=jnp.asarray(rows, jnp.float32),
-                      **{k: jnp.asarray(p[k], jnp.float32)
-                         for k in ("w_x", "w_h", "b", "w_fc", "b_fc")})
+        f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+        model = Model(coef=f32(rows),
+                      gru=tuple(GruLayer(*(f32(p[k]) for k in ks))
+                                for ks in keys),
+                      w_fc=f32(p["w_fc"]), b_fc=f32(p["b_fc"]))
         return model, Static(numerics="float32", **common)
     f = config["formats"]
     bits = weight_bits or int(f["weight_bits"])
-    w_x, e_x = _weight_codes(p["w_x"], bits)
-    w_h, e_h = _weight_codes(p["w_h"], bits)
-    w_fc, e_fc = _weight_codes(p["w_fc"], bits)
     acc_frac, acc_bits = int(f["acc_frac"]), int(f["acc_bits"])
-    logit_frac = f["hid_frac"] + 7 - e_fc
     lim = 1 << (acc_bits - 1)
-    b = np.clip(np.round(p["b"] * 2.0 ** acc_frac), -lim, lim - 1)
+    gru, shift_x, shift_h = [], [], []
+    for layer, (k_x, k_h, k_b) in enumerate(keys):
+        in_frac = f["feat_frac"] if layer == 0 else f["hid_frac"]
+        w_x, e_x = _weight_codes(p[k_x], bits)
+        w_h, e_h = _weight_codes(p[k_h], bits)
+        b = np.clip(np.round(p[k_b] * 2.0 ** acc_frac), -lim, lim - 1)
+        gru.append(GruLayer(w_x=jnp.asarray(w_x), w_h=jnp.asarray(w_h),
+                            b=jnp.asarray(b, jnp.int32)))
+        shift_x.append(acc_frac - (in_frac + 7 - e_x))
+        shift_h.append(acc_frac - (f["hid_frac"] + 7 - e_h))
+    w_fc, e_fc = _weight_codes(p["w_fc"], bits)
+    logit_frac = f["hid_frac"] + 7 - e_fc
     b_fc = np.clip(np.round(p["b_fc"] * 2.0 ** logit_frac),
                    -2 ** 31, 2 ** 31 - 1)
     coef = np.empty_like(rows)
     coef[[0, 3]] = np.round(rows[[0, 3]] * 2.0 ** b_frac)
     coef[[1, 2, 4, 5]] = np.round(rows[[1, 2, 4, 5]] * 2.0 ** a_frac)
-    model = Model(coef=jnp.asarray(coef, jnp.int32),
-                  w_x=jnp.asarray(w_x), w_h=jnp.asarray(w_h),
-                  b=jnp.asarray(b, jnp.int32), w_fc=jnp.asarray(w_fc),
-                  b_fc=jnp.asarray(b_fc, jnp.int32))
+    model = Model(coef=jnp.asarray(coef, jnp.int32), gru=tuple(gru),
+                  w_fc=jnp.asarray(w_fc), b_fc=jnp.asarray(b_fc, jnp.int32))
     static = Static(
         numerics="int8", b_frac=b_frac, a_frac=a_frac,
         sig_frac=int(f["sig_frac"]), env_frac=int(f["env_frac"]),
@@ -239,21 +264,21 @@ def build(config: dict, params: dict, *, precision: str = "highest",
         eps_code=int(round(fex["log_eps"] * 2 ** f["env_frac"])),
         feat_frac=int(f["feat_frac"]), hid_frac=int(f["hid_frac"]),
         acc_frac=acc_frac, acc_bits=acc_bits,
-        shift_x=acc_frac - (f["feat_frac"] + 7 - e_x),
-        shift_h=acc_frac - (f["hid_frac"] + 7 - e_h),
+        shift_x=tuple(shift_x), shift_h=tuple(shift_h),
         logit_frac=logit_frac, **common)
     return model, static
 
 
 # ------------------------------------------------------------- state
 class State(NamedTuple):
+    """The ΔGRU fields hold one array per layer, the first layer's first."""
     fex: jax.Array       # (B, 5, C) section-0, section-1 registers + env
-    h: jax.Array         # (B, H)
-    x_hat: jax.Array     # (B, I)
-    h_hat: jax.Array     # (B, H)
-    m_x: jax.Array       # (B, 3H) input pre-activation memory (with bias)
-    m_h: jax.Array       # (B, 3H) hidden pre-activation memory
-    hold: jax.Array      # (B, I) VAD hold register
+    h: tuple             # (B, H)
+    x_hat: tuple         # (B, I): I = C for the first layer, H above
+    h_hat: tuple         # (B, H)
+    m_x: tuple           # (B, 3H) input pre-activation memory (with bias)
+    m_h: tuple           # (B, 3H) hidden pre-activation memory
+    hold: jax.Array      # (B, C) VAD hold register
     hang: jax.Array      # (B,) VAD hangover
     smooth: jax.Array    # (B, K) smoothed posteriors
     active: jax.Array    # (B,) open event class or NO_EVENT
@@ -262,14 +287,19 @@ class State(NamedTuple):
 
 def init_state(model: Model, static: Static, batch: int) -> State:
     C = model.coef.shape[1]
-    I, H3 = model.w_x.shape
     H, K = model.w_fc.shape
     dt = jnp.float32 if static.numerics == "float32" else jnp.int32
+
+    def zeros(*shape):
+        return jnp.zeros((batch, *shape), dt)
+
     return State(
-        fex=jnp.zeros((batch, 5, C), dt), h=jnp.zeros((batch, H), dt),
-        x_hat=jnp.zeros((batch, I), dt), h_hat=jnp.zeros((batch, H), dt),
-        m_x=jnp.broadcast_to(model.b, (batch, H3)).astype(dt),
-        m_h=jnp.zeros((batch, H3), dt), hold=jnp.zeros((batch, I), dt),
+        fex=zeros(5, C), h=tuple(zeros(H) for _ in model.gru),
+        x_hat=tuple(zeros(g.w_x.shape[0]) for g in model.gru),
+        h_hat=tuple(zeros(H) for _ in model.gru),
+        m_x=tuple(jnp.broadcast_to(g.b, (batch, 3 * H)).astype(dt)
+                  for g in model.gru),
+        m_h=tuple(zeros(3 * H) for _ in model.gru), hold=zeros(C),
         hang=jnp.zeros((batch,), jnp.int32),
         smooth=jnp.zeros((batch, K), jnp.float32),
         active=jnp.full((batch,), NO_EVENT, jnp.int32),
@@ -363,8 +393,11 @@ def _compress(env, st: Static):
     return _sat(jnp.round(v * (1 << st.feat_frac)).astype(jnp.int32), 16)
 
 
-def _gru_frame(x, h, x_hat, h_hat, m_x, m_h, model: Model, st: Static):
-    """One ΔGRU frame: Δ-encode x and h, accumulate W·Δ into M, gates."""
+def _gru_frame(x, h, x_hat, h_hat, m_x, m_h, g: GruLayer, layer: int,
+               st: Static):
+    """One ΔGRU layer's frame: Δ-encode x and h, accumulate W·Δ into M,
+    gates.  In int8 the first layer's x is a feature code, a higher
+    layer's the hidden code of the layer below."""
     H = st.hidden
     if st.numerics == "float32":
         th = st.threshold
@@ -374,14 +407,15 @@ def _gru_frame(x, h, x_hat, h_hat, m_x, m_h, model: Model, st: Static):
         mh = jnp.abs(h - h_hat) > th
         dh = jnp.where(mh, h - h_hat, 0.0)
         h_hat = jnp.where(mh, h, h_hat)
-        m_x = m_x + fdot(dx, model.w_x, st.precision)
-        m_h = m_h + fdot(dh, model.w_h, st.precision)
+        m_x = m_x + fdot(dx, g.w_x, st.precision)
+        m_h = m_h + fdot(dh, g.w_h, st.precision)
         r = jax.nn.sigmoid(m_x[:, :H] + m_h[:, :H])
         u = jax.nn.sigmoid(m_x[:, H:2 * H] + m_h[:, H:2 * H])
         c = jnp.tanh(m_x[:, 2 * H:] + r * m_h[:, 2 * H:])
         h = u * h + (1.0 - u) * c
     else:
-        th_x = int(np.floor(st.threshold * (1 << st.feat_frac)))
+        in_frac = st.feat_frac if layer == 0 else st.hid_frac
+        th_x = int(np.floor(st.threshold * (1 << in_frac)))
         th_h = int(np.floor(st.threshold * (1 << st.hid_frac)))
         mx = jnp.abs(x - x_hat) > th_x
         dx = jnp.where(mx, x - x_hat, 0)
@@ -390,9 +424,9 @@ def _gru_frame(x, h, x_hat, h_hat, m_x, m_h, model: Model, st: Static):
         dh = jnp.where(mh, h - h_hat, 0)
         h_hat = jnp.where(mh, h, h_hat)
         idot = functools.partial(jnp.dot, preferred_element_type=jnp.int32)
-        m_x = _sat(m_x + _to_grid(idot(dx, model.w_x), st.shift_x),
+        m_x = _sat(m_x + _to_grid(idot(dx, g.w_x), st.shift_x[layer]),
                    st.acc_bits)
-        m_h = _sat(m_h + _to_grid(idot(dh, model.w_h), st.shift_h),
+        m_h = _sat(m_h + _to_grid(idot(dh, g.w_h), st.shift_h[layer]),
                    st.acc_bits)
         one = 1 << st.hid_frac
         acc = float(2.0 ** -st.acc_frac)
@@ -476,9 +510,14 @@ def block(model: Model, state: State, audio, *, st: Static, detect: bool):
                              jnp.maximum(hang - 1, 0)).astype(jnp.int32)
             x = jnp.where(gate[:, None], x, hold)
             hold = x
-        h, x_hat, h_hat, m_x, m_h, nz = _gru_frame(
-            x, s.h, s.x_hat, s.h_hat, s.m_x, s.m_h, model, st)
-        votes, logits = _logits(h, model, st)
+        gru = []
+        for layer, g in enumerate(model.gru):
+            out = _gru_frame(x, s.h[layer], s.x_hat[layer], s.h_hat[layer],
+                             s.m_x[layer], s.m_h[layer], g, layer, st)
+            gru.append(out)
+            x = out[0]                    # the next layer's input
+        h, x_hat, h_hat, m_x, m_h, nz = (tuple(f) for f in zip(*gru))
+        votes, logits = _logits(h[-1], model, st)
         smooth, active, refract = s.smooth, s.active, s.refract
         events = jnp.full((B,), NO_EVENT, jnp.int32)
         if detect:
@@ -488,6 +527,6 @@ def block(model: Model, state: State, audio, *, st: Static, detect: bool):
                   h_hat=h_hat, m_x=m_x, m_h=m_h, hold=hold, hang=hang,
                   smooth=smooth, active=active, refract=refract)
         return s, Frames(votes=votes, events=events, gate=gate,
-                         nz=nz.astype(jnp.int32))
+                         nz=sum(nz).astype(jnp.int32))
 
     return jax.lax.scan(frame_step, state, (samples, energy))

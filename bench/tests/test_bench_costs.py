@@ -48,6 +48,56 @@ def test_model_ops_per_frame_by_hand():
     assert costs.model_ops_per_frame(F32) == 332 + 141 + 28
 
 
+# The first layer as above; the second reads the first's hidden state.
+F32_2 = dict(F32, layers=2)
+I8_2 = dict(I8, layers=2)
+
+
+def test_two_layer_counts_by_hand():
+    # layer 1: 141 as above; layer 2 (I = H = 3): 2*(3+3)*9 = 108
+    # contraction, 18 accumulate, 33 gates -> 159.  5 slots x 2 frames.
+    assert costs.gru_frame_ops(F32_2) == 141 + 159
+    w = costs.gru_call(F32_2, slots=5, frames=2)
+    assert w.ops == 10 * 300
+    # layer 2: hidden in 5*2*3*4 = 120, hidden out 120; state per slot
+    # (h, h_hat 6*4) + (x_hat 3*4) + (M 18*4) = 108, in and out for 5
+    # slots = 1080; weights 6*9*4 = 216.
+    assert w.bytes == (80 + 120 + 1040 + 180) + (120 + 120 + 1080 + 216)
+    # int8, layer 2 reads int16 codes: 60 + 60 + 2*5*(12 + 6 + 72) + 54.
+    assert costs.gru_call(I8_2, slots=5, frames=2).bytes == \
+        (40 + 60 + 880 + 45) + (60 + 60 + 900 + 54)
+    # FEx 332 and FC 28 as for one layer.
+    assert costs.model_ops_per_frame(F32_2) == 332 + 300 + 28
+
+
+# (fex ops, fex bytes, gru ops, gru bytes, model ops) of 1000 steps and
+# 123457 served frames, recorded from the one-layer code before a
+# configuration could state ``layers``.
+PINNED_WORK = {
+    "int8-utt-c4096": (674365440000, 9249008000, 966787072000, 8574848000,
+                       6484949296.0),
+    "f32-utt-c4096": (674365440000, 18497776000, 966787072000,
+                      14032384000, 6484949296.0),
+    "int8-live-c128": (21073920000, 487664000, 30212096000, 3876736000,
+                       6484949296.0),
+    "int8-utt-c4096-x4": (2697461760000, 36995312000, 3867148288000,
+                          34256768000, 6484949296.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED_WORK))
+def test_committed_cells_work_is_pinned(name):
+    from bench import spec
+    cell = spec.find_cell(spec.load_benchmark(), name)
+    t = cell.traffic
+    work = costs.StepWork.of(cell, {"steps": 1000, "slots": t["slots"],
+                                    "chunk_samples": t["chunk_samples"],
+                                    "served_frames": 123457})
+    assert (work.fex.ops, work.fex.bytes, work.gru.ops, work.gru.bytes,
+            work.model_ops) == PINNED_WORK[name]
+    assert work.steps == 1000
+
+
 def test_peaks_and_roofline():
     p = costs.peaks_for("TPU v5 lite")
     assert p["bf16_ops_per_s"] == 197e12 and p["hbm_bytes_per_s"] == 819e9

@@ -54,7 +54,7 @@ def test_filter_bank_matches_the_program():
     assert (bundle.ffmt.b_frac, bundle.ffmt.a_frac) == (b_frac, a_frac)
     m, _ = REF.build(cfg, model.make_params(cfg, 0))
     np.testing.assert_array_equal(np.asarray(m.coef), np.asarray(bundle.coef))
-    np.testing.assert_array_equal(np.asarray(m.w_x).astype(np.int8),
+    np.testing.assert_array_equal(np.asarray(m.gru[0].w_x).astype(np.int8),
                                   np.asarray(bundle.gru.w_x))
     np.testing.assert_array_equal(np.asarray(m.b_fc), np.asarray(bundle.b_fc))
 
@@ -74,7 +74,7 @@ def test_utterance_votes_and_state_equal(name):
                            st=st, detect=False)
     np.testing.assert_array_equal(votes, np.asarray(out.votes))
     np.testing.assert_array_equal(np.asarray(sess.state.h),
-                                  np.asarray(state.h).astype(
+                                  np.asarray(state.h[0]).astype(
                                       np.asarray(sess.state.h).dtype))
     assert len(np.unique(votes)) > 2          # the model is not constant
 
@@ -97,6 +97,6 @@ def test_detection_equal(name):
     assert sess.summary().vad_duty == pytest.approx(
         float(np.asarray(out.gate).mean()))
     np.testing.assert_array_equal(np.asarray(sess.state.h),
-                                  np.asarray(state.h).astype(
+                                  np.asarray(state.h[0]).astype(
                                       np.asarray(sess.state.h).dtype))
     assert (events >= 0).sum() > 0            # the head fires

@@ -132,3 +132,59 @@ def test_program_config_check_refuses_a_different_width():
     with pytest.raises(harness.RunError):
         harness._check_program_config(cfg, get_config("deltakws"),
                                       FExConfig())
+
+
+@pytest.mark.parametrize("name", ["int8-utt-c4096", "int8-live-c128"])
+def test_a_run_of_a_two_layer_config_refuses_the_one_layer_program(name):
+    import dataclasses
+    from bench.tests.small import run_small, small_cell
+    cell = small_cell(name)
+    two = dataclasses.replace(cell, config=dict(cell.config, layers=2))
+    with pytest.raises(harness.RunError, match="layers"):
+        run_small(two)
+
+
+def test_a_two_layer_config_added_as_a_file_alone(tmp_path):
+    """A stacked configuration is a config file with ``layers``: weights,
+    counts and reference follow it, and no file under bench/ changes."""
+    from bench import costs, model
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
+              if p.is_file()}
+    cfg = json.loads((ROOT / "bench/configs/deltakws-f32.json").read_text())
+    cfg.update(name="test-two-layer", layers=2)
+    (tmp_path / "bench/configs/test-two-layer.json").write_text(
+        json.dumps(cfg))
+    (tmp_path / "bench/limits/test-two-layer-utt.json").write_text(
+        (ROOT / "bench/limits/f32-utt-c4096.json").read_text())
+    b = json.loads((ROOT / "BENCHMARK.json").read_text())
+    b["configs"].append({"name": "test-two-layer", "source": "test",
+                         "file": "bench/configs/test-two-layer.json",
+                         "reduced": [], "why": "test"})
+    b["workloads"].append({"name": "test-two-layer-utt",
+                           "config": "test-two-layer",
+                           "traffic": "utt-c4096", "chips": 1,
+                           "why": "test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    cell = spec.find_cell(spec.load_benchmark(tmp_path),
+                          "test-two-layer-utt", tmp_path)
+    one = spec.find_cell(BENCH, "f32-utt-c4096", ROOT)
+    params = model.make_params(cell.config, 3)
+    assert {"w_x_1", "w_h_1", "b_1"} <= set(params)
+    served = {"steps": 10, "slots": 8, "chunk_samples": 4096,
+              "served_frames": 100}
+    two_work = costs.StepWork.of(cell, served)
+    one_work = costs.StepWork.of(one, served)
+    assert two_work.fex == one_work.fex
+    assert two_work.gru.ops > one_work.gru.ops
+    assert two_work.model_ops > one_work.model_ops
+    ref_model, _ = spec.reference(cell).build(cell.config, params)
+    assert len(ref_model.gru) == 2
+    from repro.configs import get_config
+    from repro.frontend import FExConfig
+    with pytest.raises(harness.RunError, match="layers"):
+        harness._check_program_config(cell.config, get_config("deltakws"),
+                                      FExConfig())
+    for p, data in before.items():
+        assert p.read_bytes() == data
