@@ -124,6 +124,7 @@ _MODULES = {
     "zamba2-2.7b": "zamba2_2_7b",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
     "deltakws": "deltakws",
+    "edgedrnn": "edgedrnn",
 }
 
 

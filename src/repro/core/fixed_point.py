@@ -76,20 +76,20 @@ def align(x, shift: int):
 
 def align_pair(p, shift_x: int, shift_h: int):
     """``align`` across a fused ``[x-half | h-half]`` block whose halves
-    sit on different grids: the shift amounts become per-COLUMN constant
-    vectors (baked at trace time), so the whole block moves in one
-    add+shift pass instead of two per-half passes.  Bit-identical to
-    ``align`` applied per half."""
+    sit on different grids: the shift amounts become per-COLUMN values
+    (a select on the column index, so a kernel body captures no constant
+    array), so the whole block moves in one add+shift pass instead of
+    two per-half passes.  Bit-identical to ``align`` applied per half."""
     if shift_x == shift_h:
         return align(p, shift_x)
     n = p.shape[-1] // 2
-    if shift_x < 0 and shift_h < 0:
-        s = np.concatenate([np.full(n, -shift_x), np.full(n, -shift_h)])
-        bias = jnp.asarray((1 << (s - 1)).astype(np.int32))
-        return (p + bias) >> jnp.asarray(s.astype(np.int32))
-    if shift_x >= 0 and shift_h >= 0:
-        s = np.concatenate([np.full(n, shift_x), np.full(n, shift_h)])
-        return p << jnp.asarray(s.astype(np.int32))
+    if (shift_x < 0) == (shift_h < 0):
+        first = jax.lax.broadcasted_iota(jnp.int32, p.shape, p.ndim - 1) < n
+        if shift_x < 0:
+            s = jnp.where(first, -shift_x, -shift_h)
+            bias = jnp.where(first, 1 << (-shift_x - 1), 1 << (-shift_h - 1))
+            return (p + bias) >> s
+        return p << jnp.where(first, shift_x, shift_h)
     return jnp.concatenate([align(p[:, :n], shift_x),
                             align(p[:, n:], shift_h)], axis=-1)
 
@@ -247,66 +247,46 @@ def int_gru_gates(m, h, fmt: GruFormats):
     return sat(h_new, 16)
 
 
-# Byte-plane packed dot: exact for contraction dims up to 2^9 (see
-# ``packed_int8_dot``); beyond it the kernels fall back to the int32 dot.
-PACKED_DOT_MAX_K = 512
+# Exact Δ·W on the MXU.  A Δ code is a difference of saturated int16
+# codes, so |d| ≤ 2^16: 17 signed bits.  Split it into byte planes,
+# lo = d & 0xFF ∈ [0, 2^8) and hi = d >> 8 ∈ [−2^8, 2^8], and contract
+# both against the same float32 image of the int8 weights: every
+# product is ≤ 2^15 in magnitude, so a block of ≤ 2^9 contraction rows
+# sums to ≤ 2^24, inside float32's exact-integer range.  Longer
+# contractions run in blocks of ``PLANE_DOT_BLOCK_K`` rows whose exact
+# sums add in int32, and ``(hi << 8) + lo`` recombines in int32 —
+# bit-identical to the int32 dot (``jnp.dot(d, w.astype(int32))``,
+# which wraps mod 2^32 as the recombination does).  On the chip this
+# kept the 64-wide kernel's time, where native int8 planes (three of
+# them, for 17 bits) cost 10 % more (PERF.md §6).
+PLANE_DOT_BLOCK_K = 512
 
 
 def packed_int8_dot(d, w_f32):
-    """Exact Δ·W as ONE f32 matmul via byte-plane packing of the deltas.
-
-    The int kernel's hot op is ``int32 (B, K) @ int8 (K, N)``.  XLA's
-    integer matmul is far off the f32 MXU/SIMD path, so we run it AS a
-    float matmul — exactly.  Split each delta code into its unsigned low
-    byte and arithmetic high byte, ``d = (d >> 8)·2^8 + (d & 0xFF)``,
-    stack the two planes along the row axis, and contract both against
-    the SAME f32-valued int8 weight image in one dot:
-
-      * deltas are differences of saturated int16 codes, so
-        ``|d| ≤ 2^16``, giving ``d >> 8 ∈ [−2^8, 2^8)`` and
-        ``d & 0xFF ∈ [0, 2^8)``;
-      * every partial product is then ≤ 2^8 · 2^7 = 2^15 in magnitude,
-        and a K-term accumulation is ≤ K · 2^15 ≤ 2^24 for K ≤ 2^9 —
-        inside float32's exact-integer range, so BOTH plane dots are
-        exact integers (``PACKED_DOT_MAX_K`` gates this statically);
-      * the recombination ``(hi_dot << 8) + lo_dot`` is exact int32.
-
-    Args:
-      d: (B, K) int32 delta codes, |d| ≤ 2^16.
-      w_f32: (K, N) float32 holding EXACT int8 weight code values (the
-        kernel converts the int8 image once into VMEM scratch).
-
-    Returns the exact (B, N) int32 product — bit-identical to
-    ``jnp.dot(d, w.astype(int32))``.
-    """
-    rows = d.shape[0]
+    """Exact (B, K) int32 ``d`` × (K, N) float32 image of int8 codes as
+    the (2B, N) plane products [lo; hi]: one float32 dot where K ≤
+    ``PLANE_DOT_BLOCK_K`` (its values exact integers), else the int32
+    sum of exact ``PLANE_DOT_BLOCK_K``-row block dots."""
     planes = jnp.concatenate([d & 0xFF, d >> 8],
                              axis=0).astype(jnp.float32)
-    prod = jnp.dot(planes, w_f32,
-                   preferred_element_type=jnp.float32).astype(jnp.int32)
-    return (prod[rows:] << 8) + prod[:rows]
+    K = d.shape[1]
+    if K <= PLANE_DOT_BLOCK_K:
+        return jnp.dot(planes, w_f32, preferred_element_type=jnp.float32)
+    return sum(jnp.dot(planes[:, k0:k0 + PLANE_DOT_BLOCK_K],
+                       w_f32[k0:k0 + PLANE_DOT_BLOCK_K],
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+               for k0 in range(0, K, PLANE_DOT_BLOCK_K))
 
 
 def packed_int8_dot_pair(dx, dh, wx_f32, wh_f32):
-    """Both ΔGRU contractions through the packed path with ONE shared
-    recombination.
-
-    Each operand keeps its own plane split and f32 dot (the exactness
-    argument of ``packed_int8_dot`` applies per contraction), but the
-    two plane products concatenate on the OUTPUT axis so a single
-    astype/shift/add recombines ``[Δx·Wx | Δh·Wh]`` at once — the fused
-    (B, 6H) product block the frame step accumulates into.  Bit-
-    identical to two ``packed_int8_dot`` calls side by side; roughly
-    half the recombination ops, which is what interpret mode charges
-    per frame.
-    """
+    """Both ΔGRU contractions through ``packed_int8_dot`` with ONE shared
+    recombination: the two plane products concatenate on the OUTPUT
+    axis, so one cast and shift/add recombine the fused (B, 6H) int32
+    [Δx·W_x | Δh·W_h] block the frame step accumulates."""
     rows = dx.shape[0]
-    px = jnp.dot(jnp.concatenate([dx & 0xFF, dx >> 8],
-                                 axis=0).astype(jnp.float32),
-                 wx_f32, preferred_element_type=jnp.float32)
-    ph = jnp.dot(jnp.concatenate([dh & 0xFF, dh >> 8],
-                                 axis=0).astype(jnp.float32),
-                 wh_f32, preferred_element_type=jnp.float32)
+    px, ph = packed_int8_dot(dx, wx_f32), packed_int8_dot(dh, wh_f32)
+    if px.dtype != ph.dtype:             # one blocked: join as int32
+        px, ph = px.astype(jnp.int32), ph.astype(jnp.int32)
     prod = jnp.concatenate([px, ph], axis=-1).astype(jnp.int32)
     return (prod[rows:] << 8) + prod[:rows]
 
@@ -328,10 +308,11 @@ def gru_frame_step(fmt: GruFormats | None, x, h, x_hat, h_hat, m,
     With a ``GruFormats``, everything is integer-code arithmetic.
 
     ``dot`` swaps the Δ·W contraction implementation (int mode only):
-    ``None`` is the plain int32 ``jnp.dot`` pair; the packed kernel
-    passes ``packed_int8_dot_pair`` with f32-valued weight images —
-    exact, so the frame step stays the single source of the math either
-    way.  Signature: ``dot(dx, dh, w_x, w_h) -> (B, 6H)``.
+    ``None`` is the plain int32 ``jnp.dot`` pair; the kernel passes the
+    exact byte-plane dot (``packed_int8_dot_pair``) on its float32
+    weight image, so the frame step stays the single source of the math
+    either way.
+    Signature: ``dot(dx, dh, w_x, w_h) -> (B, 6H)``.
 
     Returns ``(h', x̂', ĥ', m', mask_x, mask_h)``.
     """
@@ -361,31 +342,22 @@ def gru_frame_step(fmt: GruFormats | None, x, h, x_hat, h_hat, m,
     return h_new, x_hat, h_hat, m, mask_x, mask_h
 
 
-# VMEM budget for the sequence-resident int kernel (weights must stay
-# resident).  Same budget as the float path in core.delta_gru; int8
-# weights are 4× smaller, so the practical model ceiling is 4× higher.
-_INT_SEQ_KERNEL_VMEM_BUDGET_BYTES = 8 * 2 ** 20
-
-
 @jax.named_scope("kws.gru")
 def int_gru_scan(w: IntGruWeights, fmt: GruFormats, xs_codes,
                  threshold: float, state=None, *, backend: str = "xla",
                  block_b: int | None = None, block_t: int | None = None,
-                 packed: bool | None = None, interpret: bool | None = None,
-                 event_driven: bool = False,
-                 vmem_budget_bytes: int = _INT_SEQ_KERNEL_VMEM_BUDGET_BYTES):
+                 interpret: bool | None = None, event_driven: bool = False):
     """Run the integer ΔGRU over codes ``xs_codes`` (T, B, I) int16.
 
     ``backend="xla"`` is the golden ``lax.scan``; ``"pallas"`` the fused
     sequence-resident kernel — bit-identical by single-source math.
     Returns ``(hs_codes (T,B,H) int16, final state, nz_dx, nz_dh)``.
 
-    ``block_b``/``block_t``/``packed`` forward to the kernel's tiling /
-    packed-dot knobs (numerics-invariant); left ``None``, the dispatch
-    consults the ``kernels.autotune`` cache for this (shape, dtype,
-    threshold-bucket, platform) and falls back to the static defaults on
-    a cold cache.  ``interpret`` forwards to the Pallas platform
-    resolution; ``vmem_budget_bytes`` is the resident-weight ceiling.
+    ``block_b``/``block_t`` forward to the kernel's tiling knobs
+    (numerics-invariant); left ``None``, the dispatch consults the
+    ``kernels.autotune`` cache for this (shape, dtype, threshold-bucket,
+    platform) and falls back to the static defaults on a cold cache.
+    ``interpret`` forwards to the Pallas platform resolution.
 
     ``event_driven`` enables active-slot compaction on the integer
     datapath (``kernels.compaction``, DESIGN.md §13): slots whose whole
@@ -398,9 +370,10 @@ def int_gru_scan(w: IntGruWeights, fmt: GruFormats, xs_codes,
     float path during VAD-clamped silence).
 
     Unlike the float ``delta_gru_scan``, there is no block-sparse
-    fallback for weights exceeding the VMEM budget (no int image of
-    ``delta_matvec`` yet) — the dispatch REFUSES loudly instead of
-    compiling a kernel that cannot keep its weights resident.
+    fallback for weights that cannot stay resident in VMEM (no int image
+    of ``delta_matvec`` yet) — the kernel's VMEM plan
+    (``kernels.delta_gru_seq.int_vmem_plan``) REFUSES loudly instead of
+    compiling a kernel that Mosaic would reject.
     """
     T, B, I = xs_codes.shape
     H = w.w_h.shape[0]
@@ -416,8 +389,7 @@ def int_gru_scan(w: IntGruWeights, fmt: GruFormats, xs_codes,
             return int_gru_scan(
                 w, fmt, jnp.asarray(xs_c), threshold,
                 DeltaState(*[jnp.asarray(s) for s in st]), backend=backend,
-                packed=packed, interpret=interpret,
-                vmem_budget_bytes=vmem_budget_bytes)
+                interpret=interpret)
 
         held = compaction.held_slots(xs_codes, state.x_hat, th_x)
         hs, st, nz_dx, nz_dh, _ = compaction.event_driven_seq(
@@ -427,13 +399,6 @@ def int_gru_scan(w: IntGruWeights, fmt: GruFormats, xs_codes,
                 jnp.asarray(nz_dx), jnp.asarray(nz_dh))
 
     if backend == "pallas":
-        weight_bytes = (I + H) * 3 * H          # int8: one byte per weight
-        if weight_bytes > vmem_budget_bytes:
-            raise NotImplementedError(
-                f"int8 weights ({weight_bytes} B) exceed the sequence "
-                f"kernel's VMEM budget ({vmem_budget_bytes} B) and the "
-                "blocked int fallback does not exist — use backend='xla' "
-                "or the float path's block-sparse composition")
         from repro.kernels import autotune
         from repro.kernels.delta_gru_seq import delta_gru_seq_int
         if block_b is None or block_t is None:
@@ -447,7 +412,7 @@ def int_gru_scan(w: IntGruWeights, fmt: GruFormats, xs_codes,
                                  state.h_hat, state.m_x, state.m_h,
                                  w.w_x, w.w_h, th, fmt=fmt,
                                  block_b=block_b, block_t=block_t,
-                                 packed=packed, interpret=interpret)
+                                 interpret=interpret)
     if backend != "xla":
         raise ValueError(f"unknown int ΔGRU backend: {backend!r}")
 
@@ -702,12 +667,16 @@ class IntKwsBundle:
     """Everything the integer serving path consumes: the promoted weight
     codes, the static formats, and the deployment threshold.  ``coef``/
     ``ffmt`` are None until a FEx is folded in (feature-chunk serving
-    needs only the GRU+FC half)."""
+    needs only the GRU+FC half).
 
-    gru: IntGruWeights
+    A ΔGRU stack holds ``gru`` and ``gfmt`` as tuples, one entry per
+    layer, the first layer's first; each layer's formats carry the FC
+    head's exponent, so any of them gives ``logit_frac``."""
+
+    gru: IntGruWeights | tuple
     w_fc: Array                     # (H, 12) int8
     b_fc: Array                     # (12,)  int32 on the logit grid
-    gfmt: GruFormats
+    gfmt: GruFormats | tuple
     threshold: float
     coef: Array | None = None       # (6, C) int32
     ffmt: FexFormats | None = None
@@ -717,7 +686,9 @@ def promote_kws(params, threshold: float, fex=None) -> IntKwsBundle:
     """Fold a (QAT-)trained float parameter tree into the integer bundle.
 
     Args:
-      params: the ``models.kws.init_kws`` tree (w_x/w_h/b/w_fc/b_fc).
+      params: the ``models.kws.init_kws`` tree (w_x/w_h/b/w_fc/b_fc, and
+        ``w_x_<l>, w_h_<l>, b_<l>`` for each layer ``l`` of a stack above
+        the first).
       threshold: the float Δ_TH to serve at; stored on the bundle and
         FLOOR-quantized to a code at serving time so the integer gate
         transmits exactly the deltas the float gate transmits on grid
@@ -729,15 +700,31 @@ def promote_kws(params, threshold: float, fex=None) -> IntKwsBundle:
     Returns:
       An ``IntKwsBundle``: int8 Q0.7×2^e weight codes, bias codes on
       the Q5.18 accumulator grid, static per-tensor formats, and Δ_TH.
+      Each layer of a stack takes its own per-tensor exponents; a layer
+      above the first reads the int16 hidden codes below it, so its
+      input grid is the hidden grid.
 
     Pure fold — no retraining, no calibration data: every format is
     either fixed by the IC or derived from the trained dynamic range.
     """
     from repro.core.delta_gru import DeltaGRUParams
-    gru_p = DeltaGRUParams(params["w_x"], params["w_h"], params["b"])
-    gru, gfmt = quantize_gru(gru_p)
-    w_fc, b_fc, gfmt = quantize_fc(params["w_fc"], params["b_fc"], gfmt)
-    bundle = IntKwsBundle(gru=gru, w_fc=w_fc, b_fc=b_fc, gfmt=gfmt,
+    from repro.models.kws import gru_stack
+    stack = gru_stack(params)
+    layers = (stack,) if isinstance(stack, DeltaGRUParams) else stack
+    grus, fmts = [], []
+    for layer, gru_p in enumerate(layers):
+        base = GruFormats()
+        if layer:
+            base = dataclasses.replace(base, feat_frac=base.hid_frac)
+        gru, gfmt = quantize_gru(gru_p, base)
+        grus.append(gru)
+        fmts.append(gfmt)
+    w_fc, b_fc, top = quantize_fc(params["w_fc"], params["b_fc"], fmts[-1])
+    fmts = [dataclasses.replace(f, e_fc=top.e_fc) for f in fmts]
+    one = len(grus) == 1
+    bundle = IntKwsBundle(gru=grus[0] if one else tuple(grus),
+                          w_fc=w_fc, b_fc=b_fc,
+                          gfmt=fmts[0] if one else tuple(fmts),
                           threshold=float(threshold))
     return bundle if fex is None else fold_fex(bundle, fex)
 

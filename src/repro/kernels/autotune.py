@@ -91,13 +91,14 @@ def legal_block_b(B: int, compiled: bool = False) -> list[int]:
 
 def validate_block_b(kernel: str, B: int, block_b: int | None, *,
                      interpret: bool | None = None,
-                     slot_bytes: int = 0) -> int:
+                     slot_bytes: int = 0, fixed_bytes: int = 0) -> int:
     """Resolve/validate a batch tile against ``legal_block_b``.
 
     ``None`` picks the default: one tile (``B``) under the interpreter;
-    compiled, the largest legal tile whose ``slot_bytes``-per-slot VMEM
-    footprint fits ``VMEM_TILE_BUDGET_BYTES`` (the smallest legal tile
-    when none fits).  Raises ``ValueError`` naming the kernel, ``B`` and
+    compiled, the largest legal tile whose VMEM footprint —
+    ``fixed_bytes`` held whatever the tile, plus ``slot_bytes`` per slot
+    — fits ``VMEM_TILE_BUDGET_BYTES`` (the smallest legal tile when none
+    fits).  Raises ``ValueError`` naming the kernel, ``B`` and
     the offending ``block_b`` — instead of the opaque grid/BlockSpec
     error Pallas or Mosaic produces.
     """
@@ -106,7 +107,8 @@ def validate_block_b(kernel: str, B: int, block_b: int | None, *,
     if block_b is None:
         if not compiled:
             return B
-        fits = [d for d in legal if d * slot_bytes <= VMEM_TILE_BUDGET_BYTES]
+        fits = [d for d in legal
+                if fixed_bytes + d * slot_bytes <= VMEM_TILE_BUDGET_BYTES]
         return max(fits) if fits else min(legal)
     if (isinstance(block_b, bool) or not isinstance(block_b, int)
             or block_b < 1 or B % block_b != 0):

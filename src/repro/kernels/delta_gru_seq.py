@@ -33,14 +33,14 @@ accumulated on-device.  Weights that do NOT fit VMEM take the
 block-sparse path instead (``core.delta_gru`` composes ``delta_matvec``'s
 scalar-prefetch block mask per step — see DESIGN.md §2/§3).
 
-The int variant additionally supports the PACKED datapath (DESIGN.md
-§12): the int8 weight image is converted ONCE (at grid step 0) into an
-f32-valued copy held in persistent VMEM scratch, and every Δ·W
-contraction runs as ``fixed_point.packed_int8_dot_pair`` — f32 matmuls
-over byte-plane-split deltas, exact by construction for contraction dims
-≤ ``fixed_point.PACKED_DOT_MAX_K``.  That keeps the 4×-denser int8
-operands on the float matmul path instead of XLA's slow integer dot,
-which is what made the int kernel 0.53× the float kernel's speed.
+The int variant runs every Δ·W contraction exactly on the float MXU
+path (DESIGN.md §12): the int8 weight image is converted ONCE (at grid
+step 0) into an f32-valued copy held in persistent VMEM scratch, and
+each contraction runs as ``fixed_point.packed_int8_dot_pair`` — f32
+matmuls over byte-plane-split deltas in blocks of ≤ 512 contraction
+rows, exact at any width.  Its VMEM plan (``int_vmem_plan``) counts the
+weights, their image and the tile, raises the scoped-VMEM limit where a
+768-wide layer needs it, and refuses weights that cannot stay resident.
 """
 from __future__ import annotations
 
@@ -199,14 +199,49 @@ def delta_gru_seq(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, threshold,
 
 
 # --------------------------------------------------------------- int variant
+# The default scoped-VMEM limit of a Mosaic kernel on v5e, and the most a
+# kernel may ask for: v5e has 128 MiB of VMEM per core; the rest is left
+# to the compiler's own buffers.
+SCOPED_VMEM_DEFAULT_BYTES = 16 * 2 ** 20
+VMEM_CAP_BYTES = 100 * 2 ** 20
+
+
+def int_vmem_plan(I: int, H: int, T: int, B: int, block_t: int,
+                  block_b: int | None,
+                  interpret: bool | None = None) -> tuple[int, int]:
+    """(batch tile, VMEM bytes) of the int kernel, from what it holds:
+    the int8 weights (double-buffered by the pipeline) and their float32
+    image, and per slot the tile's blocks (``_slot_vmem_bytes``) plus
+    the frame step's live temporaries.
+
+    The tile is ``validate_block_b``'s rule with the resident bytes
+    counted against its budget.  Raises ``NotImplementedError`` when even
+    the smallest legal tile cannot hold its weights resident under
+    ``VMEM_CAP_BYTES``: no kernel is built that Mosaic would refuse."""
+    resident = (2 + 4) * (I + H) * 3 * H
+    tile = _slot_vmem_bytes(I, H, T, block_t)
+    bb = validate_block_b("delta_gru_seq_int", B, block_b,
+                          interpret=interpret, slot_bytes=tile,
+                          fixed_bytes=resident)
+    # Fused M and its increment, both dots' two plane products, the
+    # float32 planes and the gates' float32 intermediates.
+    temps = 4 * (12 * H + 12 * H + 2 * (I + H) + 12 * H)
+    need = resident + bb * (tile + temps)
+    if need > VMEM_CAP_BYTES:
+        raise NotImplementedError(
+            f"delta_gru_seq_int: I={I}, H={H} needs {need} B of VMEM at "
+            f"block_b={bb} ({resident} B of resident weights), above the "
+            f"{VMEM_CAP_BYTES} B a kernel may use; the weights cannot stay "
+            f"resident and no int8 kernel streams them")
+    return bb, need
+
+
 def _int_kernel(x_ref, h0_ref, xh0_ref, hh0_ref, mx0_ref, mh0_ref,
                 wx_ref, wh_ref, th_ref,
                 hs_ref, nzx_ref, nzh_ref,
                 h_ref, xh_ref, hh_ref, mx_ref, mh_ref,
-                wxf_ref=None, whf_ref=None, *, fmt, block_t: int,
-                packed: bool):
-    from repro.core.fixed_point import (gru_frame_step,
-                                        packed_int8_dot_pair)
+                wxf_ref=None, whf_ref=None, *, fmt, block_t: int):
+    from repro.core import fixed_point as fp
 
     t = pl.program_id(1)
     row0 = t * block_t
@@ -218,17 +253,17 @@ def _int_kernel(x_ref, h0_ref, xh0_ref, hh0_ref, mx0_ref, mh0_ref,
         hh_ref[...] = hh0_ref[...]
         mx_ref[...] = mx0_ref[...]
         mh_ref[...] = mh0_ref[...]
-        if packed:
+        if fmt is not None:
             # One-time weight conversion: the int8 image becomes an
-            # f32-valued copy in persistent VMEM scratch, so every grid
-            # step's packed dot reads float operands (no per-frame cast).
+            # f32-valued copy in persistent VMEM scratch, so every
+            # frame's dot reads float operands (no per-frame cast).
             wxf_ref[...] = wx_ref[...].astype(jnp.float32)
             whf_ref[...] = wh_ref[...].astype(jnp.float32)
 
-    if packed:
-        dot, w_x, w_h = packed_int8_dot_pair, wxf_ref[...], whf_ref[...]
-    else:
+    if fmt is None:
         dot, w_x, w_h = None, wx_ref[...], wh_ref[...]
+    else:
+        dot, w_x, w_h = fp.packed_int8_dot_pair, wxf_ref[...], whf_ref[...]
     th_x, th_h = th_ref[0, 0], th_ref[0, 1]
 
     # State rides the fori_loop CARRY, not the refs: the refs are read
@@ -245,7 +280,7 @@ def _int_kernel(x_ref, h0_ref, xh0_ref, hh0_ref, mx0_ref, mh0_ref,
 
     def step(k, carry):
         h, xh, hh, m = carry
-        h, xh, hh, m, mask_x, mask_h = gru_frame_step(
+        h, xh, hh, m, mask_x, mask_h = fp.gru_frame_step(
             fmt, x_ref[k], h, xh, hh, m, w_x, w_h,
             th_x, th_h, dot=dot)
         hs_ref[k] = h.astype(hs_ref.dtype)
@@ -266,11 +301,10 @@ def _int_kernel(x_ref, h0_ref, xh0_ref, hh0_ref, mx0_ref, mh0_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "block_b", "block_t",
-                                             "packed", "interpret"))
+                                             "interpret"))
 def delta_gru_seq_int(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th,
                       *, fmt=None, block_b: int | None = None,
                       block_t: int | None = None,
-                      packed: bool | None = None,
                       interpret: bool | None = None):
     """The int8-weight/int16-state variant of the fused sequence kernel.
 
@@ -280,8 +314,11 @@ def delta_gru_seq_int(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th,
 
       * ``fmt`` a ``GruFormats`` — integer-code operands: xs/h/x̂/ĥ are
         int16 codes, m_x/m_h int32 on the 24-bit saturating accumulator
-        grid, weights int8, ``th`` a (1, 2) int32 [th_x, th_h].  Bit-
-        identical to the golden ``fixed_point.int_gru_scan`` scan.
+        grid, weights int8, ``th`` a (1, 2) int32 [th_x, th_h].  Every
+        Δ·W runs as exact byte-plane f32 dots against a one-time f32
+        weight image in VMEM scratch (``fixed_point.
+        packed_int8_dot_pair``), at any contraction length: bit-identical
+        to the golden ``fixed_point.int_gru_scan`` scan.
       * ``fmt=None`` — identity-quant conformance mode: float operands
         (``th`` (1, 2) float32, both entries Δ_TH) through the SAME
         kernel skeleton, executing the float math in the float kernel's
@@ -290,12 +327,8 @@ def delta_gru_seq_int(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th,
         state carry) from quantization in the differential fuzz suite.
 
     ``block_b``/``block_t`` tile the batch/time grid axes (numerics-
-    invariant, autotunable).  ``packed`` selects the byte-plane-packed
-    Δ·W datapath (``fixed_point.packed_int8_dot_pair`` against a one-time
-    f32 weight image in VMEM scratch — exact, so still bit-identical to
-    the golden model); ``None`` auto-enables it whenever the integer
-    format is active and both contraction dims fit the exactness bound
-    ``fixed_point.PACKED_DOT_MAX_K``.
+    invariant, autotunable); the tile and the kernel's VMEM limit follow
+    ``int_vmem_plan``, which refuses weights that cannot stay resident.
 
     Returns ``(hs, (h, x̂, ĥ, m_x, m_h), nz_dx, nz_dh)``.
     """
@@ -308,29 +341,19 @@ def delta_gru_seq_int(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th,
     assert w_h.shape == (H, 3 * H), (w_h.shape, (H, 3 * H))
     assert th.shape == (1, 2), th.shape
     bt = validate_divisor("delta_gru_seq_int", "block_t", block_t, "T", T)
-    bb = validate_block_b("delta_gru_seq_int", B, block_b,
-                          interpret=interpret,
-                          slot_bytes=_slot_vmem_bytes(I, H, T, bt))
-    from repro.core.fixed_point import PACKED_DOT_MAX_K
-    if packed is None:
-        packed = fmt is not None and max(I, H) <= PACKED_DOT_MAX_K
-    elif packed:
-        if fmt is None:
-            raise ValueError("delta_gru_seq_int: packed=True requires an "
-                             "integer GruFormats (fmt is None — the "
-                             "identity-quant mode has no int8 image)")
-        if max(I, H) > PACKED_DOT_MAX_K:
-            raise ValueError(
-                f"delta_gru_seq_int: packed=True is only exact for "
-                f"contraction dims <= {PACKED_DOT_MAX_K}, got I={I}, H={H}")
+    bb, vmem = int_vmem_plan(I, H, T, B, bt, block_b, interpret)
+    compiler_params = _COMPILER_PARAMS
+    if vmem > SCOPED_VMEM_DEFAULT_BYTES:
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=int(vmem))
 
     if fmt is not None:
         # Widen the code stream once at dispatch, not once per frame:
         # the frame step computes on int32, so feeding int32 blocks
         # makes its per-frame x cast a no-op (values unchanged).
         xs = xs.astype(jnp.int32)
-    kernel = functools.partial(_int_kernel, fmt=fmt, block_t=bt,
-                               packed=packed)
+    kernel = functools.partial(_int_kernel, fmt=fmt, block_t=bt)
     state_spec = lambda d: pl.BlockSpec((bb, d), lambda b, t: (b, 0))
     fixed_spec = lambda s: pl.BlockSpec(s, lambda b, t: tuple(
         0 for _ in s))
@@ -354,9 +377,6 @@ def delta_gru_seq_int(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th,
         state_spec(H), state_spec(I), state_spec(H),
         state_spec(3 * H), state_spec(3 * H),
     )
-    scratch_shapes = ([pltpu.VMEM((I, 3 * H), jnp.float32),
-                       pltpu.VMEM((H, 3 * H), jnp.float32)]
-                      if packed else [])
     hs, nz_dx, nz_dh, h, x_hat, h_hat, m_x, m_h = pl.pallas_call(
         kernel,
         grid=(B // bb, T // bt),
@@ -369,8 +389,10 @@ def delta_gru_seq_int(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th,
         ],
         out_specs=out_specs,
         out_shape=out_shapes,
-        scratch_shapes=scratch_shapes,
-        compiler_params=_COMPILER_PARAMS,
+        scratch_shapes=([pltpu.VMEM((I, 3 * H), jnp.float32),
+                         pltpu.VMEM((H, 3 * H), jnp.float32)]
+                        if fmt is not None else []),
+        compiler_params=compiler_params,
         name="delta_gru_seq_int",
         interpret=resolve_interpret(interpret),
     )(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th)

@@ -310,24 +310,31 @@ def slot_health(input_bad: Array, fex_state: FExState | None,
             bad |= _slot_any(jnp.abs(e32) >= _INT16_RAIL)
         flags |= _flag(HEALTH_FEX, bad)
     if gru_state is not None:
-        if jnp.issubdtype(gru_state.h.dtype, jnp.floating):
-            bad = _slot_any(~jnp.isfinite(gru_state.h))
-            for leaf in (gru_state.x_hat, gru_state.h_hat,
-                         gru_state.m_x, gru_state.m_h):
-                bad |= _slot_any(~jnp.isfinite(leaf))
-            flags |= _flag(HEALTH_GRU, bad)
-        else:
-            x32 = gru_state.x_hat.astype(jnp.int32)
-            flags |= _flag(HEALTH_GRU,
-                           _slot_any(jnp.abs(x32) > _FEAT_CODE_BOUND))
-            sat = _slot_any(jnp.abs(gru_state.m_x) >= _ACC_RAIL)
-            sat |= _slot_any(jnp.abs(gru_state.m_h) >= _ACC_RAIL)
-            flags |= _flag(HEALTH_SAT, sat)
+        for layer, st in enumerate(_layers(gru_state)):
+            flags |= _gru_flags(st, first=layer == 0)
     if vad_state is not None:
         flags |= _flag(HEALTH_VAD, vad_state_flags(vad_state))
     if det_state is not None:
         flags |= _flag(HEALTH_DET, detector_state_flags(det_state))
     return flags
+
+
+def _gru_flags(state, *, first: bool) -> Array:
+    """HEALTH_GRU / HEALTH_SAT flags of one ΔGRU layer's state.  An int
+    layer's x̂ holds feature codes only in the first layer; above it x̂
+    holds the int16 hidden codes below, which no code bound exceeds."""
+    if jnp.issubdtype(state.h.dtype, jnp.floating):
+        bad = _slot_any(~jnp.isfinite(state.h))
+        for leaf in (state.x_hat, state.h_hat, state.m_x, state.m_h):
+            bad |= _slot_any(~jnp.isfinite(leaf))
+        return _flag(HEALTH_GRU, bad)
+    flags = jnp.zeros(state.h.shape[:1], jnp.int32)
+    if first:
+        x32 = state.x_hat.astype(jnp.int32)
+        flags = _flag(HEALTH_GRU, _slot_any(jnp.abs(x32) > _FEAT_CODE_BOUND))
+    sat = _slot_any(jnp.abs(state.m_x) >= _ACC_RAIL)
+    sat |= _slot_any(jnp.abs(state.m_h) >= _ACC_RAIL)
+    return flags | _flag(HEALTH_SAT, sat)
 
 
 # ------------------------------------------------------ exact telemetry --
@@ -355,14 +362,27 @@ def _count_zero(n_shards: int) -> _Count:
                   lo=jnp.zeros((n_shards,), jnp.int32))
 
 
-def _count_add(c: _Count, d) -> _Count:
-    """Add a per-chunk delta (int32, < 2^31) with carry propagation.
-    Saturates the hi lane instead of wrapping."""
+def _count_add(c: _Count, d, scale: int = 1) -> _Count:
+    """Add ``d · scale`` with carry propagation: ``d`` a device count
+    (int32, 0 ≤ d < 2^31; an array adds elementwise) and ``scale`` <
+    2^15, or ``d`` a Python int of any size below 2^61 (split on the
+    host).  Saturates the hi lane instead of wrapping."""
+    if isinstance(d, int):
+        v = d * scale
+        lo = c.lo + (v & _COUNT_MASK)
+        hi = c.hi + (v >> _COUNT_SHIFT) + (lo >> _COUNT_SHIFT)
+        return _Count(hi=jnp.minimum(hi, _HI_SAT), lo=lo & _COUNT_MASK)
+    # d·scale = (d >> 15)·scale·2^15 + (d & 0x7FFF)·scale: both partial
+    # products stay under 2^31, and each is carried into hi before the
+    # next lands on lo.
     d = jnp.asarray(d, jnp.int32)
-    lo = c.lo + (d & _COUNT_MASK)
-    hi = jnp.minimum(c.hi + (d >> _COUNT_SHIFT) + (lo >> _COUNT_SHIFT),
-                     _HI_SAT)
-    return _Count(hi=hi, lo=lo & _COUNT_MASK)
+    a = (d & 0x7FFF) * scale
+    b = (d >> 15) * scale
+    lo = c.lo + a
+    hi = c.hi + (lo >> _COUNT_SHIFT)
+    lo = (lo & _COUNT_MASK) + ((b & 0x7FFF) << 15)
+    hi = hi + (b >> 15) + (lo >> _COUNT_SHIFT)
+    return _Count(hi=jnp.minimum(hi, _HI_SAT), lo=lo & _COUNT_MASK)
 
 
 def _count_value(c: _Count) -> tuple[int, bool]:
@@ -372,6 +392,16 @@ def _count_value(c: _Count) -> tuple[int, bool]:
     lo = np.asarray(c.lo, np.int64)
     return (int(hi.sum()) << _COUNT_SHIFT) + int(lo.sum()), \
         bool(np.any(hi >= _HI_SAT))
+
+
+def _count_values(c: _Count) -> tuple[list[int], bool]:
+    """``_count_value`` of a per-layer counter ((n_shards, L) lanes):
+    one exact value per layer."""
+    hi = np.asarray(c.hi, np.int64)
+    lo = np.asarray(c.lo, np.int64)
+    return ([(int(h) << _COUNT_SHIFT) + int(v)
+             for h, v in zip(hi.sum(0), lo.sum(0))],
+            bool(np.any(hi >= _HI_SAT)))
 
 
 class _Accum(NamedTuple):
@@ -386,6 +416,9 @@ class _Accum(NamedTuple):
     Keeping the partials sharded instead of psum-reducing them keeps the
     hot path free of collectives — the one host-side ``summary()`` fetch
     does the final reduction.
+
+    Per-layer fields (``nz_dx``, ``nz_dh``) carry an (n_shards, L)
+    pair, one column per ΔGRU layer of the stack, the first first.
     """
 
     macs: _Count         # (stage-1) ΔGRU MACs actually executed
@@ -397,6 +430,8 @@ class _Accum(NamedTuple):
     s0_macs: _Count      # stage-0 micro-ΔGRU MACs (0 without a cascade)
     awake: _Count        # frame-slots stage 1 was powered
                          #   (== frames when no cascade is gating)
+    nz_dx: _Count        # transmitted input Δs, per layer
+    nz_dh: _Count        # transmitted hidden Δs, per layer
 
 
 @dataclasses.dataclass
@@ -418,6 +453,10 @@ class StreamSummary:
     recoveries: int = 0                    # slots auto-reset by supervisor
     recovery_reasons: dict = dataclasses.field(default_factory=dict)
     sat_events: int = 0                    # HEALTH_SAT slot-checks observed
+    # Per ΔGRU layer, the first first: Δs transmitted (input + hidden)
+    # and temporal sparsity, 1 − transmitted / (frames · (I + H)).
+    layer_transmits: tuple = ()
+    layer_sparsity: tuple = ()
     # Chunks joined to a carried sample remainder: how the host framed
     # the audio, not what the streams decided, so — unlike ``chunks`` —
     # it takes no part in comparing two summaries.
@@ -429,44 +468,111 @@ class StreamSummary:
     slo: dict = dataclasses.field(default_factory=dict)
 
 
-def _zero_accum(n_shards: int = 1) -> _Accum:
-    return _Accum(*[_count_zero(n_shards) for _ in _Accum._fields])
+def _zero_accum(n_shards: int = 1, n_layers: int = 1) -> _Accum:
+    per_layer = _Count(hi=jnp.zeros((n_shards, n_layers), jnp.int32),
+                       lo=jnp.zeros((n_shards, n_layers), jnp.int32))
+    return _Accum(*[per_layer if f in ("nz_dx", "nz_dh")
+                    else _count_zero(n_shards) for f in _Accum._fields])
+
+
+def _layers(x) -> tuple:
+    """A ΔGRU stack's per-layer entries: a stack (a tuple or list of
+    weights, formats or states) as a tuple, one layer's entry (a
+    NamedTuple or GruFormats) as a one-tuple."""
+    return tuple(x) if type(x) in (tuple, list) else (x,)
+
+
+class LayerCounts(NamedTuple):
+    """One ΔGRU layer's transmitted Δs over a chunk, and its widths."""
+
+    nz_dx: Array    # (F, B) int32
+    nz_dh: Array    # (F, B) int32
+    in_dim: int
+    hidden: int
+
+
+@jax.named_scope("kws.gru")
+def _gru_stack(gru, state, xs, *, threshold: float, backend: str,
+               interpret: bool | None, gfmt=None):
+    """The ΔGRU stage of every fused step: the stack's layers in turn
+    over the chunk, layer 1 on ``xs`` (T, B, I), each layer above on the
+    hidden states of the layer below.  Layer l at frame t reads only
+    layer l−1 at frame t, so running each layer over the whole chunk in
+    turn is the frame-interleaved order.  ``gru``/``state`` (and, int8,
+    ``gfmt``) are one layer's entry or a tuple per layer; with ``gfmt``
+    the layers run the integer datapath on codes.  Each layer's kernel
+    runs under the named scope ``kws.gru.<l>`` (l = 0 first).
+
+    Returns (the top layer's hidden states, the new state in the form
+    of ``state``, one ``LayerCounts`` per layer)."""
+    grus, states = _layers(gru), _layers(state)
+    fmts = _layers(gfmt) if gfmt is not None else (None,) * len(grus)
+    new, counts = [], []
+    for layer, (g, st, fmt) in enumerate(zip(grus, states, fmts)):
+        in_dim = xs.shape[-1]
+        with jax.named_scope(f"kws.gru.{layer}"):
+            if fmt is None:
+                xs, st, stats = dg.delta_gru_scan(
+                    g, xs, threshold=threshold, state=st, backend=backend,
+                    interpret=interpret)
+                nz_dx, nz_dh = stats.nz_dx, stats.nz_dh
+            else:
+                xs, st, nz_dx, nz_dh = fp.int_gru_scan(
+                    g, fmt, xs, threshold, state=st, backend=backend,
+                    interpret=interpret)
+        new.append(st)
+        counts.append(LayerCounts(nz_dx, nz_dh, in_dim, g.w_h.shape[0]))
+    state = tuple(new) if type(state) in (tuple, list) else new[0]
+    return xs, state, tuple(counts)
+
+
+def _transmits(counts) -> Array:
+    """(F, B) Δs transmitted per frame and slot, summed over layers."""
+    return sum(c.nz_dx + c.nz_dh for c in counts)
 
 
 @jax.named_scope("kws.head")
-def _classify(w_fc, b_fc, hs, stats):
+def _classify(w_fc, b_fc, hs, nz):
     logits = f32_dot(hs, w_fc) + b_fc             # (F, B, 12)
     votes = jnp.argmax(logits, -1).astype(jnp.int32)
-    return ChunkResult(logits=logits, votes=votes,
-                       nz=stats.nz_dx + stats.nz_dh)
+    return ChunkResult(logits=logits, votes=votes, nz=nz)
 
 
-def _bump(acc: _Accum, stats, n_frames: int, n_samples: int,
-          vad_open=None, awake=None, s0_macs=0) -> _Accum:
-    """Accumulate one chunk's telemetry.  ``vad_open`` is the device-side
-    count of gate-open frame-slots (detect mode); ungated paths count
-    every frame as open so ``vad_duty`` reads 1.0.  ``awake``/``s0_macs``
-    are the cascade's stage-1 power count and stage-0 MAC count —
-    cascade-free paths default to every frame awake (duty 1.0) and zero
-    stage-0 work, so their telemetry is unchanged.
+def _bump(acc: _Accum, counts, n_frames: int, n_samples: int,
+          vad_open=None, awake=None, s0=()) -> _Accum:
+    """Accumulate one chunk's telemetry.  ``counts`` are the stage-1
+    stack's ``LayerCounts``, ``s0`` the cascade's stage-0 ones (none
+    without a cascade, so its MAC count stays 0).  ``vad_open`` is the
+    device-side count of gate-open frame-slots (detect mode); ungated
+    paths count every frame as open so ``vad_duty`` reads 1.0.
+    ``awake`` is the cascade's stage-1 power count — cascade-free paths
+    default to every frame awake (duty 1.0).
 
-    Per-chunk deltas are summed as int32 — the per-frame MAC counts are
-    exact small floats, and casting BEFORE the reduction keeps a big
-    chunk's sum exact where a float32 reduction would round (a serve
-    chunk is bounded well under 2^31 MACs; the carried total uses the
-    2^61 split counters above).
+    Exact at any chunk size the transmit counts fit: each layer's chunk
+    sum of transmitted Δs is an int32 (under 2^31), scaled by its 3H
+    MACs per Δ inside the split counter (``_count_add``), and the dense
+    count is a Python int from the static shapes.
     """
+    def macs(c, layer_counts):
+        for lc in layer_counts:
+            c = _count_add(c, jnp.sum(lc.nz_dx + lc.nz_dh), 3 * lc.hidden)
+        return c
+
+    per_layer = lambda f: jnp.stack([jnp.sum(getattr(lc, f))
+                                     for lc in counts])
+    dense = n_frames * sum((lc.in_dim + lc.hidden) * 3 * lc.hidden
+                           for lc in counts)
     return _Accum(
-        macs=_count_add(acc.macs,
-                        jnp.sum(stats.macs.astype(jnp.int32))),
-        macs_dense=_count_add(acc.macs_dense,
-                              jnp.sum(stats.macs_dense.astype(jnp.int32))),
+        macs=macs(acc.macs, counts),
+        macs_dense=_count_add(acc.macs_dense, dense),
         frames=_count_add(acc.frames, n_frames),
         fex_samples=_count_add(acc.fex_samples, n_samples),
         vad_open=_count_add(acc.vad_open,
                             n_frames if vad_open is None else vad_open),
-        s0_macs=_count_add(acc.s0_macs, s0_macs),
+        s0_macs=macs(acc.s0_macs, s0),
         awake=_count_add(acc.awake, n_frames if awake is None else awake),
+        nz_dx=_count_add(acc.nz_dx, per_layer("nz_dx")),
+        nz_dh=_count_add(acc.nz_dh, per_layer("nz_dh")),
     )
 
 
@@ -475,60 +581,60 @@ def _feats_bad(feats) -> Array:
     return jnp.any(~jnp.isfinite(feats), axis=(0, 2))
 
 
-def _process_chunk(gru: dg.DeltaGRUParams, w_fc, b_fc, state: dg.DeltaState,
-                   acc: _Accum, feats, *, threshold: float, backend: str,
-                   interpret: bool | None):
+def _process_chunk(gru, w_fc, b_fc, state, acc: _Accum, feats, *,
+                   threshold: float, backend: str, interpret: bool | None):
     """Pure chunk step:
     (state, acc, feats (F,B,C)) -> (state', acc', out, health)."""
     in_bad = _feats_bad(feats)
-    hs, state, stats = dg.delta_gru_scan(
-        gru, feats, threshold=threshold, state=state,
-        backend=backend, interpret=interpret)
-    out = _classify(w_fc, b_fc, hs, stats)
+    hs, state, counts = _gru_stack(gru, state, feats, threshold=threshold,
+                                   backend=backend, interpret=interpret)
+    out = _classify(w_fc, b_fc, hs, _transmits(counts))
     health = slot_health(in_bad, None, state, None, None)
-    return (state, _bump(acc, stats, feats.shape[0] * feats.shape[1], 0),
+    return (state, _bump(acc, counts, feats.shape[0] * feats.shape[1], 0),
             out, health)
 
 
 @jax.named_scope("kws.head")
-def _classify_int(w_fc, b_fc, hs_codes, stats, logit_frac: int):
+def _classify_int(w_fc, b_fc, hs_codes, nz, logit_frac: int):
     """FC + argmax on integer codes — the decision is the argmax over
     int32 logit codes (bit-true); the dequantized logits are returned
     for the float-typed ChunkResult surface."""
     codes = fp.int_fc(hs_codes, w_fc, b_fc)           # (F, B, 12) int32
     votes = jnp.argmax(codes, -1).astype(jnp.int32)
     return ChunkResult(logits=fp.from_code(codes, logit_frac),
-                       votes=votes, nz=stats.nz_dx + stats.nz_dh)
+                       votes=votes, nz=nz)
 
 
-def _process_chunk_int(gru: fp.IntGruWeights, w_fc, b_fc,
-                       state: dg.DeltaState, acc: _Accum, feats, *,
-                       threshold: float, gfmt: fp.GruFormats, backend: str,
+def _logit_frac(gfmt) -> int:
+    """The FC head's logit grid of a (stack of) promoted formats."""
+    return _layers(gfmt)[-1].logit_frac
+
+
+def _process_chunk_int(gru, w_fc, b_fc, state, acc: _Accum, feats, *,
+                       threshold: float, gfmt, backend: str,
                        interpret: bool | None):
     """Integer mirror of ``_process_chunk``: feats (F, B, C) floats on the
-    12-bit grid → code domain → int ΔGRU → int FC.  ``state`` carries
-    integer codes (int16/int32 ``DeltaState``)."""
+    12-bit grid → code domain → int ΔGRU stack → int FC.  ``state``
+    carries integer codes (int16/int32 ``DeltaState``s)."""
     in_bad = _feats_bad(feats)
-    xs = fp.to_code(feats, gfmt.feat_frac, 16, jnp.int16)
-    hs, state, nz_dx, nz_dh = fp.int_gru_scan(
-        gru, gfmt, xs, threshold, state=state, backend=backend,
-        interpret=interpret)
-    stats = dg._stats_from_counts(nz_dx, nz_dh, xs.shape[-1],
-                                  gru.w_h.shape[0])
-    out = _classify_int(w_fc, b_fc, hs, stats, gfmt.logit_frac)
+    xs = fp.to_code(feats, _layers(gfmt)[0].feat_frac, 16, jnp.int16)
+    hs, state, counts = _gru_stack(gru, state, xs, threshold=threshold,
+                                   backend=backend, interpret=interpret,
+                                   gfmt=gfmt)
+    out = _classify_int(w_fc, b_fc, hs, _transmits(counts),
+                        _logit_frac(gfmt))
     health = slot_health(in_bad, None, state, None, None)
-    return (state, _bump(acc, stats, feats.shape[0] * feats.shape[1], 0),
+    return (state, _bump(acc, counts, feats.shape[0] * feats.shape[1], 0),
             out, health)
 
 
-def _process_audio_chunk_int(gru: fp.IntGruWeights, w_fc, b_fc, coef,
-                             fex_state: FExState, state: dg.DeltaState,
-                             acc: _Accum, audio, *, threshold: float,
+def _process_audio_chunk_int(gru, w_fc, b_fc, coef, fex_state: FExState,
+                             state, acc: _Accum, audio, *, threshold: float,
                              backend: str, fex_backend: str,
                              interpret: bool | None, frame_shift: int,
-                             gfmt: fp.GruFormats, ffmt: fp.FexFormats):
+                             gfmt, ffmt: fp.FexFormats):
     """Fused INTEGER audio→decision step: 12-bit ADC → int FEx → int ΔGRU
-    → int FC in one jitted graph — the deployed datapath, bit-true
+    stack → int FC in one jitted graph — the deployed datapath, bit-true
     against the golden fixed-point model.  ``fex_state`` holds int16
     register codes, ``state`` int16/int32 ΔGRU codes."""
     in_bad = jnp.any(~jnp.isfinite(audio), axis=1)    # pre-quantizer
@@ -538,26 +644,25 @@ def _process_audio_chunk_int(gru: fp.IntGruWeights, w_fc, b_fc, coef,
         audio_codes, coef, _pack_state(fex_state), ffmt,
         frame_shift=frame_shift, backend=fex_backend, interpret=interpret)
     xs = jnp.moveaxis(feats, 1, 0)                    # (F, B, C) codes
-    hs, state, nz_dx, nz_dh = fp.int_gru_scan(
-        gru, gfmt, xs, threshold, state=state, backend=backend,
-        interpret=interpret)
-    stats = dg._stats_from_counts(nz_dx, nz_dh, xs.shape[-1],
-                                  gru.w_h.shape[0])
-    out = _classify_int(w_fc, b_fc, hs, stats, gfmt.logit_frac)
+    hs, state, counts = _gru_stack(gru, state, xs, threshold=threshold,
+                                   backend=backend, interpret=interpret,
+                                   gfmt=gfmt)
+    out = _classify_int(w_fc, b_fc, hs, _transmits(counts),
+                        _logit_frac(gfmt))
     decisions = xs.shape[0] * xs.shape[1]             # frames × streams
-    acc = _bump(acc, stats, decisions, decisions * frame_shift)
+    acc = _bump(acc, counts, decisions, decisions * frame_shift)
     fex_state = _unpack_state(fex_buf)
     health = slot_health(in_bad, fex_state, state, None, None)
     return fex_state, state, acc, out, health
 
 
-def _process_audio_chunk(gru: dg.DeltaGRUParams, w_fc, b_fc, coef,
-                         fex_state: FExState, state: dg.DeltaState,
-                         acc: _Accum, audio, *, threshold: float,
+def _process_audio_chunk(gru, w_fc, b_fc, coef, fex_state: FExState,
+                         state, acc: _Accum, audio, *, threshold: float,
                          backend: str, fex_backend: str,
                          interpret: bool | None, frame_shift: int,
                          env_alpha: float, log_eps: float):
-    """Fused audio→decision step: FEx → ΔGRU → FC in one jitted graph.
+    """Fused audio→decision step: FEx → ΔGRU stack → FC in one jitted
+    graph.
 
     audio: (B, S) raw samples, S a multiple of frame_shift.  Nothing in
     here leaves the device — only final logits/votes/counters do, when
@@ -570,17 +675,16 @@ def _process_audio_chunk(gru: dg.DeltaGRUParams, w_fc, b_fc, coef,
         env_alpha=env_alpha, log_eps=log_eps, compress=True,
         backend=fex_backend, interpret=interpret)
     xs = jnp.moveaxis(feats, 1, 0)                # (F, B, C)
-    hs, state, stats = dg.delta_gru_scan(
-        gru, xs, threshold=threshold, state=state,
-        backend=backend, interpret=interpret)
-    out = _classify(w_fc, b_fc, hs, stats)
+    hs, state, counts = _gru_stack(gru, state, xs, threshold=threshold,
+                                   backend=backend, interpret=interpret)
+    out = _classify(w_fc, b_fc, hs, _transmits(counts))
     decisions = xs.shape[0] * xs.shape[1]            # frames × streams
-    acc = _bump(acc, stats, decisions, decisions * frame_shift)
+    acc = _bump(acc, counts, decisions, decisions * frame_shift)
     health = slot_health(in_bad, fex_state, state, None, None)
     return fex_state, state, acc, out, health
 
 
-def _detect_tail(w_fc, b_fc, hs, stats, gate, *, logit_frac=None,
+def _detect_tail(w_fc, b_fc, hs, nz, gate, *, logit_frac=None,
                  det_cfg: DetectorConfig, det_state: DetectorState,
                  awake=None):
     """Shared back half of the detect steps: FC → posterior smoothing →
@@ -590,9 +694,9 @@ def _detect_tail(w_fc, b_fc, hs, stats, gate, *, logit_frac=None,
     stage-1 h keeps emitting its held logits, and a keyword event may
     not fire while stage 0 says nothing is happening."""
     if logit_frac is None:
-        cls = _classify(w_fc, b_fc, hs, stats)
+        cls = _classify(w_fc, b_fc, hs, nz)
     else:
-        cls = _classify_int(w_fc, b_fc, hs, stats, logit_frac)
+        cls = _classify_int(w_fc, b_fc, hs, nz, logit_frac)
     post = jax.nn.softmax(cls.logits, axis=-1)       # (F, B, K)
     det_state, events = detector_scan(det_cfg, det_state, post)
     if awake is not None:
@@ -602,8 +706,8 @@ def _detect_tail(w_fc, b_fc, hs, stats, gate, *, logit_frac=None,
     return det_state, out
 
 
-def _process_audio_chunk_detect(gru: dg.DeltaGRUParams, w_fc, b_fc, coef,
-                                fex_state: FExState, state: dg.DeltaState,
+def _process_audio_chunk_detect(gru, w_fc, b_fc, coef,
+                                fex_state: FExState, state,
                                 vad_state: VADState,
                                 det_state: DetectorState, acc: _Accum,
                                 audio, *, threshold: float, backend: str,
@@ -611,11 +715,12 @@ def _process_audio_chunk_detect(gru: dg.DeltaGRUParams, w_fc, b_fc, coef,
                                 frame_shift: int, env_alpha: float,
                                 log_eps: float, vad_cfg: VADConfig,
                                 det_cfg: DetectorConfig):
-    """Fused always-on DETECTION step: audio → FEx → VAD gate → ΔGRU →
-    FC → posterior smoothing/hysteresis, one jitted graph, all state
-    (filters, hold/hangover, x̂/ĥ/M, smoothed posteriors) slot-resident
-    on device.  The VAD clamps the delta path by sample-and-holding the
-    features during silence — Δx = 0 exactly, no kernel change."""
+    """Fused always-on DETECTION step: audio → FEx → VAD gate → ΔGRU
+    stack → FC → posterior smoothing/hysteresis, one jitted graph, all
+    state (filters, hold/hangover, x̂/ĥ/M, smoothed posteriors)
+    slot-resident on device.  The VAD clamps the delta path by
+    sample-and-holding the features during silence — Δx = 0 exactly, no
+    kernel change."""
     in_bad = jnp.any(~jnp.isfinite(audio), axis=1)   # pre-quantizer
     audio = quantize_audio_12b(audio.astype(jnp.float32))
     energy = frame_energy(audio, frame_shift)        # (F, B)
@@ -625,27 +730,25 @@ def _process_audio_chunk_detect(gru: dg.DeltaGRUParams, w_fc, b_fc, coef,
         backend=fex_backend, interpret=interpret)
     xs = jnp.moveaxis(feats, 1, 0)                   # (F, B, C)
     xs, gate, vad_state = vad_gate(xs, energy, vad_state, vad_cfg)
-    hs, state, stats = dg.delta_gru_scan(
-        gru, xs, threshold=threshold, state=state,
-        backend=backend, interpret=interpret)
-    det_state, out = _detect_tail(w_fc, b_fc, hs, stats, gate,
+    hs, state, counts = _gru_stack(gru, state, xs, threshold=threshold,
+                                   backend=backend, interpret=interpret)
+    det_state, out = _detect_tail(w_fc, b_fc, hs, _transmits(counts), gate,
                                   det_cfg=det_cfg, det_state=det_state)
     decisions = xs.shape[0] * xs.shape[1]
-    acc = _bump(acc, stats, decisions, decisions * frame_shift,
+    acc = _bump(acc, counts, decisions, decisions * frame_shift,
                 vad_open=jnp.sum(gate))
     health = slot_health(in_bad, fex_state, state, vad_state, det_state)
     return fex_state, state, vad_state, det_state, acc, out, health
 
 
-def _process_audio_chunk_detect_int(gru: fp.IntGruWeights, w_fc, b_fc, coef,
-                                    fex_state: FExState,
-                                    state: dg.DeltaState,
+def _process_audio_chunk_detect_int(gru, w_fc, b_fc, coef,
+                                    fex_state: FExState, state,
                                     vad_state: VADState,
                                     det_state: DetectorState, acc: _Accum,
                                     audio, *, threshold: float,
                                     backend: str, fex_backend: str,
                                     interpret: bool | None,
-                                    frame_shift: int, gfmt: fp.GruFormats,
+                                    frame_shift: int, gfmt,
                                     ffmt: fp.FexFormats,
                                     vad_cfg: VADConfig,
                                     det_cfg: DetectorConfig):
@@ -662,16 +765,14 @@ def _process_audio_chunk_detect_int(gru: fp.IntGruWeights, w_fc, b_fc, coef,
         frame_shift=frame_shift, backend=fex_backend, interpret=interpret)
     xs = jnp.moveaxis(feats, 1, 0)                   # (F, B, C) codes
     xs, gate, vad_state = vad_gate(xs, energy, vad_state, vad_cfg)
-    hs, state, nz_dx, nz_dh = fp.int_gru_scan(
-        gru, gfmt, xs, threshold, state=state, backend=backend,
-        interpret=interpret)
-    stats = dg._stats_from_counts(nz_dx, nz_dh, xs.shape[-1],
-                                  gru.w_h.shape[0])
-    det_state, out = _detect_tail(w_fc, b_fc, hs, stats, gate,
-                                  logit_frac=gfmt.logit_frac,
+    hs, state, counts = _gru_stack(gru, state, xs, threshold=threshold,
+                                   backend=backend, interpret=interpret,
+                                   gfmt=gfmt)
+    det_state, out = _detect_tail(w_fc, b_fc, hs, _transmits(counts), gate,
+                                  logit_frac=_logit_frac(gfmt),
                                   det_cfg=det_cfg, det_state=det_state)
     decisions = xs.shape[0] * xs.shape[1]
-    acc = _bump(acc, stats, decisions, decisions * frame_shift,
+    acc = _bump(acc, counts, decisions, decisions * frame_shift,
                 vad_open=jnp.sum(gate))
     fex_state = _unpack_state(fex_buf)
     health = slot_health(in_bad, fex_state, state, vad_state, det_state)
@@ -716,13 +817,16 @@ def _process_audio_chunk_cascade(gru: dg.DeltaGRUParams, w_fc, b_fc,
                                              cas_state.hang, score)
     hs, state, stats = dg.masked_delta_gru_scan(gru, xs, threshold, state,
                                                 awake_t)
-    det_state, out = _detect_tail(w_fc, b_fc, hs, stats, gate,
+    counts = (LayerCounts(stats.nz_dx, stats.nz_dh, xs.shape[-1],
+                          gru.w_h.shape[0]),)
+    det_state, out = _detect_tail(w_fc, b_fc, hs, _transmits(counts), gate,
                                   det_cfg=det_cfg, det_state=det_state,
                                   awake=awake_t)
     decisions = xs.shape[0] * xs.shape[1]
-    acc = _bump(acc, stats, decisions, decisions * frame_shift,
+    acc = _bump(acc, counts, decisions, decisions * frame_shift,
                 vad_open=jnp.sum(gate), awake=jnp.sum(awake_t),
-                s0_macs=jnp.sum(stats0.macs.astype(jnp.int32)))
+                s0=(LayerCounts(stats0.nz_dx, stats0.nz_dh, xs0.shape[-1],
+                                gru0.w_h.shape[0]),))
     health = slot_health(in_bad, fex_state, state, vad_state, det_state)
     health |= slot_health(jnp.zeros_like(in_bad), None, s0_state,
                           None, None)
@@ -773,18 +877,16 @@ def _process_audio_chunk_cascade_int(gru: fp.IntGruWeights, w_fc, b_fc,
                                              cas_state.hang, score)
     hs, state, nz_dx, nz_dh = fp.masked_int_gru_scan(
         gru, gfmt, xs, threshold, state, awake_t)
-    stats = dg._stats_from_counts(nz_dx, nz_dh, xs.shape[-1],
-                                  gru.w_h.shape[0])
-    stats0 = dg._stats_from_counts(nzx0, nzh0, xs0.shape[-1],
-                                   gru0.w_h.shape[0])
-    det_state, out = _detect_tail(w_fc, b_fc, hs, stats, gate,
+    counts = (LayerCounts(nz_dx, nz_dh, xs.shape[-1], gru.w_h.shape[0]),)
+    det_state, out = _detect_tail(w_fc, b_fc, hs, _transmits(counts), gate,
                                   logit_frac=gfmt.logit_frac,
                                   det_cfg=det_cfg, det_state=det_state,
                                   awake=awake_t)
     decisions = xs.shape[0] * xs.shape[1]
-    acc = _bump(acc, stats, decisions, decisions * frame_shift,
+    acc = _bump(acc, counts, decisions, decisions * frame_shift,
                 vad_open=jnp.sum(gate), awake=jnp.sum(awake_t),
-                s0_macs=jnp.sum(stats0.macs.astype(jnp.int32)))
+                s0=(LayerCounts(nzx0, nzh0, xs0.shape[-1],
+                                gru0.w_h.shape[0]),))
     fex_state = _unpack_state(fex_buf)
     health = slot_health(in_bad, fex_state, state, vad_state, det_state)
     health |= slot_health(jnp.zeros_like(in_bad), None, s0_state,
@@ -794,18 +896,7 @@ def _process_audio_chunk_cascade_int(gru: fp.IntGruWeights, w_fc, b_fc,
             health)
 
 
-@jax.jit
-def _reset_gru_slots(state: dg.DeltaState, bias, mask) -> dg.DeltaState:
-    """Fresh-stream state for every slot where ``mask`` is True.
-
-    Mask-select instead of per-slot dynamic updates: ONE compiled
-    elementwise op resets an arbitrary admission wave (continuous
-    batching can churn every slot of a shard in one serve step — a
-    dispatch per slot would cost more than the chunk step itself).
-    Slot-local by construction: under a sharded state the op is
-    elementwise along the slot axis, so each shard rewrites only its own
-    rows — no collectives, no reshard, no stall for other shards.
-    """
+def _reset_layer(state: dg.DeltaState, bias, mask) -> dg.DeltaState:
     m = mask[:, None]
 
     def zero(a):
@@ -815,6 +906,26 @@ def _reset_gru_slots(state: dg.DeltaState, bias, mask) -> dg.DeltaState:
         h=zero(state.h), x_hat=zero(state.x_hat), h_hat=zero(state.h_hat),
         m_x=jnp.where(m, bias.astype(state.m_x.dtype), state.m_x),
         m_h=zero(state.m_h))
+
+
+@jax.jit
+def _reset_gru_slots(state, bias, mask):
+    """Fresh-stream state for every slot where ``mask`` is True, in every
+    layer: ``state``/``bias`` are one layer's ``DeltaState`` and bias, or
+    a tuple of each per layer of a stack.
+
+    Mask-select instead of per-slot dynamic updates: ONE compiled
+    elementwise op resets an arbitrary admission wave (continuous
+    batching can churn every slot of a shard in one serve step — a
+    dispatch per slot would cost more than the chunk step itself).
+    Slot-local by construction: under a sharded state the op is
+    elementwise along the slot axis, so each shard rewrites only its own
+    rows — no collectives, no reshard, no stall for other shards.
+    """
+    if type(state) in (tuple, list):
+        return tuple(_reset_layer(st, b, mask)
+                     for st, b in zip(state, bias))
+    return _reset_layer(state, bias, mask)
 
 
 @jax.jit
@@ -1022,6 +1133,12 @@ class StreamingKwsSession:
         else:
             self._gru, self._w_fc, self._b_fc = kws.serving_weights(
                 params, quantize_8b, mesh)
+        self.n_layers = len(_layers(self._gru))
+        if cascade is not None and self.n_layers > 1:
+            raise ValueError(
+                f"the wake cascade gates one stage-1 ΔGRU layer; a "
+                f"{self.n_layers}-layer stack is served in audio and "
+                f"detect mode only")
         # The class count rides the FC head's shape — an 11/35-class (or
         # 2-class stage-0) head serves through the same session code.
         self.n_classes = int(self._b_fc.shape[-1])
@@ -1049,7 +1166,8 @@ class StreamingKwsSession:
         self._det_state: DetectorState | None = None
         self._audio_rem: np.ndarray | None = None   # carried tail samples
         self.frame_joins = 0                # steps that joined a tail
-        self._acc = shp.put_slot_sharded(_zero_accum(self.n_shards), mesh)
+        self._acc = shp.put_slot_sharded(
+            _zero_accum(self.n_shards, self.n_layers), mesh)
         self._chunks = 0
         self._input_dim = input_dim
         if fex_backend is None:
@@ -1188,7 +1306,7 @@ class StreamingKwsSession:
                                    self._interpret) if enabled else None)
             return {"shape": list(shape), "config": cfg or {}}
 
-        H = int(self._gru.w_h.shape[0])
+        H = int(_layers(self._gru)[0].w_h.shape[0])
         gru_kernel = ("delta_gru_seq_int" if self.numerics == "int8"
                       else "delta_gru_seq")
         gru_dtype = "int8" if self.numerics == "int8" else "float32"
@@ -1266,14 +1384,18 @@ class StreamingKwsSession:
                                  out_specs=out_specs)
 
     def _init_state(self, input_dim: int):
+        """Fresh ΔGRU state: one ``DeltaState``, or a tuple of one per
+        layer of a stack (layer 1 reads ``input_dim`` features, each
+        layer above the ``d_model`` hidden state below)."""
         self._input_dim = input_dim
-        if self.numerics == "int8":
-            state = fp.init_int_delta_state(self.batch, input_dim,
-                                            self.cfg.d_model,
-                                            self._bundle.gru)
-        else:
-            state = dg.init_delta_state(self.batch, input_dim,
-                                        self.cfg.d_model, self._gru)
+        H = self.cfg.d_model
+        init = (fp.init_int_delta_state if self.numerics == "int8"
+                else dg.init_delta_state)
+        grus = _layers(self._bundle.gru if self.numerics == "int8"
+                       else self._gru)
+        states = tuple(init(self.batch, input_dim if layer == 0 else H, H,
+                            g) for layer, g in enumerate(grus))
+        state = states[0] if self.n_layers == 1 else states
         self._state = shp.put_slot_sharded(state, self.mesh)
 
     def _fresh_fex_state(self, n_channels: int) -> FExState:
@@ -1514,7 +1636,9 @@ class StreamingKwsSession:
         return out
 
     @property
-    def state(self) -> dg.DeltaState | None:
+    def state(self):
+        """The slots' ΔGRU state: one ``DeltaState``, or for a stack a
+        tuple of one per layer, the first layer's first."""
         return self._state
 
     @property
@@ -1542,8 +1666,8 @@ class StreamingKwsSession:
             self._cas_state = shp.put_slot_sharded(
                 init_cascade_state(self.batch, s0_gru,
                                    int8=self.numerics == "int8"), self.mesh)
-        self._acc = shp.put_slot_sharded(_zero_accum(self.n_shards),
-                                         self.mesh)
+        self._acc = shp.put_slot_sharded(
+            _zero_accum(self.n_shards, self.n_layers), self.mesh)
         self._chunks = 0
         self.frame_joins = 0
         self._last_health = None
@@ -1587,7 +1711,9 @@ class StreamingKwsSession:
         mask[slots] = True
         mask = jnp.asarray(mask)
         if self._state is not None:
-            self._state = _reset_gru_slots(self._state, self._gru.b, mask)
+            bias = tuple(g.b for g in _layers(self._gru))
+            self._state = _reset_gru_slots(
+                self._state, bias if self.n_layers > 1 else bias[0], mask)
         if self._fex_state is not None:
             self._fex_state = _reset_fex_slots(self._fex_state, mask)
         if self._vad_state is not None:
@@ -1700,6 +1826,18 @@ class StreamingKwsSession:
         """Which mesh shard owns global slot ``i`` (block partitioning)."""
         return i // (self.batch // self.n_shards)
 
+    def _layer_telemetry(self, acc: _Accum, frames: int):
+        """(Δs transmitted per layer, or None if a counter saturated;
+        temporal sparsity per layer) from the fetched telemetry."""
+        dx, sat_x = _count_values(acc.nz_dx)
+        dh, sat_h = _count_values(acc.nz_dh)
+        tx = tuple(a + b for a, b in zip(dx, dh))
+        H = int(_layers(self._gru)[0].w_h.shape[0])
+        width = [(self._input_dim or 0) + H] + [2 * H] * (len(tx) - 1)
+        sparsity = tuple(1.0 - t / (frames * w) if frames else 0.0
+                         for t, w in zip(tx, width))
+        return (None if sat_x or sat_h else tx), sparsity
+
     def summary(self) -> StreamSummary:
         """Fetch device telemetry ONCE and price it with the IC model.
 
@@ -1713,10 +1851,14 @@ class StreamingKwsSession:
         for name, cnt in zip(_Accum._fields, acc):
             totals[name], sat = _count_value(cnt)
             overflow = overflow or sat
+        layer_tx, layer_sp = self._layer_telemetry(acc, totals["frames"])
+        overflow = overflow or layer_tx is None
         robust = dict(overflowed=overflow, recoveries=self._recoveries,
                       recovery_reasons=dict(self._recovery_reasons),
                       sat_events=self._sat_events,
-                      frame_joins=self.frame_joins, slo=dict(self._slo))
+                      frame_joins=self.frame_joins, slo=dict(self._slo),
+                      layer_transmits=layer_tx or (),
+                      layer_sparsity=layer_sp)
         if totals["frames"] == 0:
             # Nothing processed yet: report an identifiable empty state,
             # not a spurious 100%-sparsity / 0-energy datapoint.

@@ -28,27 +28,50 @@ CLASSES = ["silence", "unknown", "down", "go", "left", "no",
            "off", "on", "right", "stop", "up", "yes"]
 
 
+def layer_keys(layer: int) -> tuple[str, str, str]:
+    """The parameter keys of ΔGRU layer ``layer`` (0: the first, which
+    reads the features): ``w_x, w_h, b``, and ``w_x_<l>, w_h_<l>, b_<l>``
+    for layer ``l`` above it, which reads the hidden state below."""
+    suffix = f"_{layer}" if layer else ""
+    return ("w_x" + suffix, "w_h" + suffix, "b" + suffix)
+
+
+def n_gru_layers(params) -> int:
+    """Depth of the ΔGRU stack a parameter tree holds."""
+    n = 1
+    while layer_keys(n)[0] in params:
+        n += 1
+    return n
+
+
 def init_kws(key, cfg, input_dim: int = 10):
     """cfg.d_model = GRU hidden size (64 in the paper); cfg.vocab_size =
     FC head width (12 for the paper's GSCD head — but the head is fully
     parameterized: an 11-class head, a 35-class GSCD-v2 head or the
     2-class stage-0 wake gate all train/promote/serve through the same
-    code, the class count riding the weight shapes end to end)."""
+    code, the class count riding the weight shapes end to end).
+
+    ``cfg.num_layers`` ΔGRU layers of width ``d_model``: the first reads
+    the features and draws from ``key`` as a one-layer model always has;
+    layer ``l`` above it reads the hidden state below, under
+    ``layer_keys(l)``, from ``fold_in(key, l)``."""
     k1, k2 = jax.random.split(key)
     n_classes = getattr(cfg, "vocab_size", N_CLASSES)
-    gru = dg.init_delta_gru(k1, input_dim, cfg.d_model)
+    H = cfg.d_model
     t = AxTree()
-    t.add("w_x", gru.w_x, (None, None))
-    t.add("w_h", gru.w_h, (None, None))
-    t.add("b", gru.b, (None,))
-    t.add("w_fc", jax.random.normal(k2, (cfg.d_model, n_classes)) /
-          np.sqrt(cfg.d_model), (None, None))
+    for layer in range(getattr(cfg, "num_layers", 1)):
+        gru = (dg.init_delta_gru(k1, input_dim, H) if layer == 0 else
+               dg.init_delta_gru(jax.random.fold_in(key, layer), H, H))
+        for name, w in zip(layer_keys(layer), gru):
+            t.add(name, w, (None,) * w.ndim)
+    t.add("w_fc", jax.random.normal(k2, (H, n_classes)) /
+          np.sqrt(H), (None, None))
     t.add("b_fc", jnp.zeros((n_classes,)), (None,))
     return t.build()
 
 
-def _gru_params(params, quantize_8b: bool):
-    w_x, w_h = params["w_x"], params["w_h"]
+def _gru_params(params, quantize_8b: bool, layer: int = 0):
+    w_x, w_h, b = (params[k] for k in layer_keys(layer))
     if quantize_8b:
         # Per-tensor power-of-two scale, 8-bit STE (IC weight format).
         def q(w):
@@ -56,19 +79,27 @@ def _gru_params(params, quantize_8b: bool):
                 jax.lax.stop_gradient(jnp.max(jnp.abs(w))), 1e-8)))
             return ste_quantize(w / scale, WEIGHT_Q) * scale
         w_x, w_h = q(w_x), q(w_h)
-    return dg.DeltaGRUParams(w_x, w_h, params["b"])
+    return dg.DeltaGRUParams(w_x, w_h, b)
+
+
+def gru_stack(params, quantize_8b: bool = False):
+    """The ΔGRU weights a session serves: one ``DeltaGRUParams``, or for
+    a stack a tuple of them, the first layer's first."""
+    layers = tuple(_gru_params(params, quantize_8b, layer)
+                   for layer in range(n_gru_layers(params)))
+    return layers[0] if len(layers) == 1 else layers
 
 
 def serving_weights(params, quantize_8b: bool = False, mesh=None):
-    """(DeltaGRUParams, w_fc, b_fc) for a serving session, replicated
-    over ``mesh`` (no-op when ``mesh`` is None).
+    """(ΔGRU weights (``gru_stack``), w_fc, b_fc) for a serving session,
+    replicated over ``mesh`` (no-op when ``mesh`` is None).
 
     Replication is the serving sharding contract: every shard reads its
     weights from local memory and admission/eviction never moves them —
     only per-stream state is partitioned (see parallel/sharding.py).
     """
     from repro.parallel import sharding as shp
-    gru = _gru_params(params, quantize_8b)
+    gru = gru_stack(params, quantize_8b)
     return shp.put_replicated((gru, params["w_fc"], params["b_fc"]), mesh)
 
 

@@ -30,8 +30,10 @@ from repro.launch.serve import (AdmissionController, OverloadPolicy,
                                 build_parser, validate_args)
 from repro.launch.streaming import (HEALTH_INPUT, QUARANTINE_DEFAULT,
                                     StreamInputError, StreamingKwsSession,
-                                    SupervisorConfig, _count_add,
-                                    _count_value, _count_zero, _HI_SAT,
+                                    SupervisorConfig, LayerCounts,
+                                    _bump, _count_add, _count_value,
+                                    _count_values, _count_zero,
+                                    _zero_accum, _HI_SAT,
                                     _Count)
 
 CHUNK = 512                      # 4 frames at frame_shift=128
@@ -360,6 +362,41 @@ def test_split_counters_flag_saturation_instead_of_wrapping():
     c2 = _count_add(c, (1 << 31) - 1)        # hi stays pinned, no wrap
     total2, saturated2 = _count_value(c2)
     assert saturated2 and total2 >= total
+
+
+def test_chunk_mac_counts_exact_past_int32():
+    """One chunk of the 2-layer ΔGRU-768 cell (1024 slots × 32 frames,
+    every Δ transmitted) is ~1.2·10^11 MACs, far past 2^31: ``_bump``
+    scales each layer's int32 transmit sum inside the split counters, so
+    ``macs`` and ``macs_dense`` stay exact (arrays of counts, no model
+    run)."""
+    import jax.numpy as jnp
+    F, B, I, H = 32, 1024, 10, 768
+    full = lambda n: jnp.full((F, B), n, jnp.int32)
+    counts = (LayerCounts(full(I), full(H), I, H),
+              LayerCounts(full(H), full(H), H, H))
+    acc = _bump(_zero_accum(1, 2), counts, F * B, F * B * 128)
+    dense = F * B * ((I + H) + (H + H)) * 3 * H
+    assert dense > 50 * 2 ** 31
+    assert _count_value(acc.macs) == (dense, False)
+    assert _count_value(acc.macs_dense) == (dense, False)
+    assert _count_values(acc.nz_dx) == ([F * B * I, F * B * H], False)
+    assert _count_values(acc.nz_dh) == ([F * B * H, F * B * H], False)
+    # Half the Δs, twice over: still exact.
+    half = (LayerCounts(full(I // 2), full(H // 2), I, H),
+            LayerCounts(full(H // 2), full(H // 2), H, H))
+    acc = _bump(_bump(acc, half, F * B, 0), half, F * B, 0)
+    assert _count_value(acc.macs) == (2 * dense, False)
+
+
+@settings(deadline=None, max_examples=6)
+@given(d=st.integers(min_value=0, max_value=2 ** 31 - 1),
+       scale=st.integers(min_value=1, max_value=2 ** 15 - 1))
+def test_scaled_count_add_matches_python_ints(d, scale):
+    import jax.numpy as jnp
+    c = _count_add(_count_zero(1), jnp.int32(d), scale)
+    c = _count_add(c, jnp.int32(d), scale)
+    assert _count_value(c) == (2 * d * scale, False)
 
 
 def test_summary_tracks_host_counted_frames(kws_bits):

@@ -112,31 +112,35 @@ def test_int_gru_batch_tiles_bit_identical():
 def test_packed_int8_dot_exact_at_extremes():
     """Unit proof of the byte-plane dot: exact against the int32 dot for
     extreme deltas (±2^16, the saturated-code worst case), full-scale
-    int8 weights, and the max gated contraction dim (DESIGN.md §12)."""
+    int8 weights, and contractions past one 512-row block (the
+    ΔGRU-768's 768 and 1536; DESIGN.md §12)."""
     rng = np.random.default_rng(0)
-    K = fp.PACKED_DOT_MAX_K
-    d = rng.integers(-(1 << 16), (1 << 16) + 1, (4, K)).astype(np.int32)
-    d[0, :] = 1 << 16                 # all-max positive deltas
-    d[1, :] = -(1 << 16)              # all-max negative
-    w = rng.integers(-128, 128, (K, 8)).astype(np.int8)
-    w[:, 0] = 127
-    w[:, 1] = -128
-    ref = d @ w.astype(np.int32)
-    got = fp.packed_int8_dot(jnp.asarray(d),
-                             jnp.asarray(w, jnp.float32))
-    np.testing.assert_array_equal(ref, np.asarray(got))
+    for K in (10, 512, 768, 1536):
+        d = rng.integers(-(1 << 16), (1 << 16) + 1, (4, K)).astype(np.int32)
+        d[0, :] = 1 << 16                 # all-max positive deltas
+        d[1, :] = -(1 << 16)              # all-max negative
+        w = rng.integers(-128, 128, (K, 8)).astype(np.int8)
+        w[:, 0] = 127
+        w[:, 1] = -128
+        ref = d @ w.astype(np.int32)      # wraps mod 2^32 like the dot
+        w32 = jnp.asarray(w, jnp.float32)
+        got = fp.packed_int8_dot_pair(jnp.asarray(d), jnp.asarray(d[:, ::-1]),
+                                      w32, w32[::-1])
+        np.testing.assert_array_equal(ref, np.asarray(got)[:, :8],
+                                      err_msg=str(K))
+        np.testing.assert_array_equal(ref, np.asarray(got)[:, 8:],
+                                      err_msg=str(K))
 
 
 @pytest.mark.parametrize("seed", range(3))
 def test_int_gru_packed_and_tiled_bit_identical_to_golden(seed):
-    """The packed datapath + time tiling vs the golden scan — the
-    conformance gate for the lane-dim packing win."""
+    """The byte-plane datapath + time tiling vs the golden scan — the
+    conformance gate of the kernel's MXU contraction."""
     rng = np.random.default_rng(100 + seed)
     w, fmt, xs, th = _rand_gru(rng)
     T = xs.shape[0]
     golden = fp.int_gru_scan(w, fmt, xs, th, backend="xla")
-    for kw in ({"packed": True}, {"packed": False},
-               {"packed": True, "block_t": T}, {"block_t": 1}):
+    for kw in ({}, {"block_t": T}, {"block_t": 1}):
         got = fp.int_gru_scan(w, fmt, xs, th, backend="pallas", **kw)
         np.testing.assert_array_equal(np.asarray(golden[0]),
                                       np.asarray(got[0]))
@@ -146,35 +150,59 @@ def test_int_gru_packed_and_tiled_bit_identical_to_golden(seed):
                                       np.asarray(got[2]))
 
 
-def test_packed_requires_int_format_and_bound():
+def test_int_kernel_exact_past_512_contraction_at_saturating_deltas():
+    """I = H = 640, so each contraction is 640 rows (past the old
+    512-row bound), with every carried code on a rail: x̂, ĥ at −2^15
+    against x, h at 2^15 − 1, so every Δ is 2^16 − 1 in the first
+    frame.  The kernel equals the golden scan bit for bit (state,
+    outputs, transmit counts)."""
+    from repro.core.delta_gru import DeltaState
     from repro.kernels.delta_gru_seq import delta_gru_seq_int
-    p = dg.init_delta_gru(jax.random.PRNGKey(1), 8, 8)
-    w, fmt = fp.quantize_gru(p)
-    xs = fp.to_code(jnp.zeros((4, 2, 8), jnp.float32), fmt.feat_frac, 16,
-                    jnp.int16)
-    s = fp.init_int_delta_state(2, 8, 8, w)
-    th = jnp.zeros((1, 2), jnp.int32)
-    with pytest.raises(ValueError, match="packed=True requires"):
-        delta_gru_seq_int(xs.astype(jnp.float32), s.h.astype(jnp.float32),
-                          s.x_hat.astype(jnp.float32),
-                          s.h_hat.astype(jnp.float32),
-                          s.m_x.astype(jnp.float32),
-                          s.m_h.astype(jnp.float32),
-                          w.w_x.astype(jnp.float32),
-                          w.w_h.astype(jnp.float32),
-                          th.astype(jnp.float32), fmt=None, packed=True)
-    with pytest.raises(ValueError, match="only exact for"):
-        big_I, H = fp.PACKED_DOT_MAX_K + 1, 8
-        delta_gru_seq_int(
-            jnp.zeros((1, 1, big_I), jnp.int16),
-            jnp.zeros((1, H), jnp.int16),           # h0
-            jnp.zeros((1, big_I), jnp.int16),       # x_hat0
-            jnp.zeros((1, H), jnp.int16),           # h_hat0
-            jnp.zeros((1, 3 * H), jnp.int32),       # m_x0
-            jnp.zeros((1, 3 * H), jnp.int32),       # m_h0
-            jnp.zeros((big_I, 3 * H), jnp.int8),
-            jnp.zeros((H, 3 * H), jnp.int8), th,
-            fmt=fmt, packed=True)
+    T, B, I, H = 4, 8, 640, 640
+    rng = np.random.default_rng(7)
+    p = dg.init_delta_gru(jax.random.PRNGKey(3), I, H)
+    w, fmt = fp.quantize_gru(p, fp.GruFormats(feat_frac=15))
+    w = w._replace(w_x=jnp.asarray(rng.choice([-128, 127], (I, 3 * H)),
+                                   jnp.int8))
+    xs = jnp.asarray(rng.choice([-32768, 32767], (T, B, I)), jnp.int16)
+    xs = xs.at[0].set(32767)
+    rail = lambda n: jnp.full((B, n), -32768, jnp.int16)
+    state = DeltaState(h=jnp.full((B, H), 32767, jnp.int16), x_hat=rail(I),
+                       h_hat=rail(H),
+                       m_x=jnp.zeros((B, 3 * H), jnp.int32),
+                       m_h=jnp.zeros((B, 3 * H), jnp.int32))
+    golden = fp.int_gru_scan(w, fmt, xs, 0.2, state=state, backend="xla")
+    th_x, th_h = fmt.th_codes(0.2)
+    got = delta_gru_seq_int(xs, *state, w.w_x, w.w_h,
+                            jnp.asarray([[th_x, th_h]], jnp.int32), fmt=fmt,
+                            interpret=True)
+    assert int(np.asarray(golden[2])[0].min()) == I     # every Δ sent
+    for a, b in zip(jax.tree.leaves(golden), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_int_vmem_plan_counts_weights_and_tile_at_768():
+    """The int kernel's VMEM plan at the ΔGRU-768's layers, compiled:
+    the resident int8 weights (double-buffered) alone pass the default
+    tile budget, so the plan takes the smallest legal tile (128 slots of
+    1024), counts weights + tile + temporaries, and that lies above the
+    default scoped limit (raised for the kernel) and under the cap.  The
+    64-wide kernel keeps its 512-slot tile under the default limit, and
+    weights that cannot stay resident are refused."""
+    from repro.kernels import delta_gru_seq as k
+    for I in (10, 768):
+        bb, need = k.int_vmem_plan(I, 768, 32, 1024, 1, None,
+                                   interpret=False)
+        resident = 2 * (I + 768) * 3 * 768
+        assert bb == 128
+        assert need >= resident + bb * k._slot_vmem_bytes(I, 768, 32, 1)
+        assert k.SCOPED_VMEM_DEFAULT_BYTES < need <= k.VMEM_CAP_BYTES
+    bb, need = k.int_vmem_plan(10, 64, 32, 1024, 1, None, interpret=False)
+    assert bb == 512 and need <= k.SCOPED_VMEM_DEFAULT_BYTES
+    with pytest.raises(NotImplementedError, match="cannot stay resident"):
+        k.int_vmem_plan(4096, 4096, 32, 1024, 1, None, interpret=False)
+    with pytest.raises(NotImplementedError, match="cannot stay resident"):
+        k.int_vmem_plan(4096, 4096, 32, 8, 1, None, interpret=True)
 
 
 def test_int_gru_state_carry_bit_invisible():

@@ -50,7 +50,7 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _kernel_args(kernel: str, B: int, T: int):
+def _kernel_args(kernel: str, B: int, T: int, I: int = I, H: int = H):
     f32, i16, i32 = jnp.float32, jnp.int16, jnp.int32
     if kernel == "delta_gru_seq":
         return (functools.partial(delta_gru_seq, interpret=False),
@@ -89,6 +89,16 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, B, T):
     assert kernel in _custom_calls(text)
 
 
+@pytest.mark.parametrize("I_, H_", [(10, 768), (768, 768)])
+def test_wide_int_kernel_compiles_for_v5e(one_chip, I_, H_):
+    """The ΔGRU-768's two layers at 1024 slots × 32 frames: the int8
+    plane dots at contraction 768 and the raised VMEM limit compile."""
+    fn, shapes = _kernel_args("delta_gru_seq_int", 1024, 32, I_, H_)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "delta_gru_seq_int" in _custom_calls(text)
+
+
 @pytest.mark.parametrize("numerics,kernels", [
     ("float32", {"batched_iir_fex", "delta_gru_seq"}),
     ("int8", {"batched_iir_fex_int", "delta_gru_seq_int"}),
@@ -111,3 +121,25 @@ def test_fused_audio_step_compiles_for_v5e(one_chip, numerics, kernels):
         args)
     text = step.lower(*shapes).compile().as_text()
     assert kernels <= _custom_calls(text)
+
+
+def test_stacked_fused_audio_step_compiles_for_v5e(one_chip):
+    """The ``edgedrnn`` configuration's int8 step at 1024 slots: both
+    layers' kernels, each under its ``kws.gru.<l>`` scope."""
+    from repro.configs import get_config
+    from repro.frontend import FeatureExtractor
+    from repro.launch.streaming import StreamingKwsSession
+    from repro.models import kws
+    cfg = get_config("edgedrnn")
+    params, _ = kws.init_kws(jax.random.PRNGKey(0), cfg, input_dim=C)
+    sess = StreamingKwsSession(params, cfg, batch=1024,
+                               fex=FeatureExtractor(), numerics="int8",
+                               interpret=False)
+    step, args = sess.fused_audio_step(4096)
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    text = step.lower(*shapes).compile().as_text()
+    assert {"batched_iir_fex_int", "delta_gru_seq_int"} <= _custom_calls(text)
+    for layer in (0, 1):
+        assert f"/kws.gru/kws.gru.{layer}/" in text
