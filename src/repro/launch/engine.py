@@ -23,10 +23,11 @@ pieces, then fault/churn resets, then admission resets — and every
 scheduling decision in the serve loops (eviction at ``chunks_per_utt``,
 admission order, churn-storm restarts) depends only on chunk COUNTS,
 which are known at dispatch time from device-array shapes.  Only the
-vote VALUES arrive late, and they are tallied per stream *incarnation*
-(slot × admission generation) so a slot recycled mid-flight never
-pollutes its predecessor's tally.  ``depth=1`` IS the synchronous loop
-(dispatch, then immediately drain); the conformance suite in
+vote VALUES arrive late, and they are tallied when their step drains
+by stream *incarnation* id (a row of ``_VoteTable``, one per life on a
+slot) so a slot recycled mid-flight never pollutes its predecessor's
+tally.  ``depth=1`` IS the synchronous loop (dispatch, then
+immediately drain); the conformance suite in
 tests/test_engine.py proves ``depth>=2`` equal to ``depth=1`` decision
 for decision and counter for counter, in float and int8, under churn
 storms, fault plans and mesh>1.
@@ -347,20 +348,88 @@ def warm_session(sess, chunk: int) -> float:
 # SAME code path with a different pipeline window.
 
 
-class _Incarnation:
-    """One admitted stream life on a slot: vote tally + chunk progress.
+class _VoteTable:
+    """Vote counts of stream incarnations, one reused row per life.
 
-    A churn storm or re-admission starts a NEW incarnation, so a fetch
-    landing after the slot was recycled still credits the life that was
-    live when its chunk was dispatched.  ``progress`` is the sync
-    loop's per-slot [chunks consumed, real frames left to vote on]."""
+    An incarnation is one admitted stream life on a slot; a churn storm
+    or a re-admission starts a new one, so a fetch landing after the slot
+    was recycled still credits the life that was live when its chunk was
+    dispatched.  Its id is a row here: ``counts[row]`` tallies its votes,
+    ``requests[row]`` holds its request.  A row is taken at admission (or
+    a storm's restart) and given back when the last step that can add to
+    it drains: the step that carried its last chunk, which decides it, or
+    for a life a storm cut short, the storm's step.  Steps drain in
+    dispatch order, so no late vote reaches a row after it is given back.
 
-    __slots__ = ("req", "counts", "progress")
+    The rows held at once are the live slots' and those of the undrained
+    steps' evictions, at most ``batch · depth`` on clean traffic: the
+    table's size.  Churn storms can hold more: each of the ``depth``
+    undrained steps adds at most ``batch`` decided and ``batch``
+    orphaned rows, so never above ``batch · (2 · depth + 1)``.  The
+    table then grows by whole ``batch`` rows, and never with the length
+    of the run.
+    """
 
-    def __init__(self, req, n_classes, real_frames):
-        self.req = req
-        self.counts = np.zeros(n_classes, np.int64)
-        self.progress = [0, real_frames]
+    def __init__(self, n_classes: int, batch: int, depth: int):
+        self._batch = batch
+        rows = batch * depth
+        self.counts = np.zeros((rows, n_classes), np.int64)
+        self.requests = np.empty(rows, object)
+        self._free = np.arange(rows)        # a stack: the first _n_free
+        self._n_free = rows
+
+    def take(self, requests: np.ndarray) -> np.ndarray:
+        """Rows for new lives serving ``requests`` (an object array)."""
+        k = len(requests)
+        short = k - self._n_free
+        if short > 0:
+            n, grow = len(self.counts), -(-short // self._batch) * self._batch
+            self.counts = np.concatenate(
+                [self.counts, np.zeros((grow, self.counts.shape[1]),
+                                       np.int64)])
+            self.requests = np.concatenate(
+                [self.requests, np.empty(grow, object)])
+            self._free = np.concatenate(
+                [self._free[:self._n_free], np.arange(n, n + grow),
+                 np.empty(n - self._n_free, np.intp)])
+            self._n_free += grow
+        self._n_free -= k
+        rows = self._free[self._n_free:self._n_free + k].copy()
+        self.counts[rows] = 0
+        self.requests[rows] = requests
+        return rows
+
+    def add(self, votes: np.ndarray, rows, n_real):
+        """Tally a drained step, ``votes`` (frames, batch): slot ``b``'s
+        first ``n_real[b]`` votes go to row ``rows[b]`` (a slot with no
+        life has ``n_real`` 0), in one ``bincount`` over
+        ``row · n_classes + vote``.  The votes past a slot's ``n_real``
+        go to a spare last bin; only frames past the shortest ``n_real``
+        are masked."""
+        n_bins = self.counts.size
+        keys = votes + (rows * self.counts.shape[1]).astype(np.int32)
+        lo = int(n_real.min())
+        keys[lo:][np.arange(lo, len(keys))[:, None] >= n_real] = n_bins
+        flat = self.counts.reshape(-1)
+        flat += np.bincount(keys.ravel(), minlength=n_bins + 1)[:n_bins]
+
+    def decide(self, rows) -> list[tuple[Any, int]]:
+        """[(request, argmax of its votes)] for ``rows``, in their order."""
+        return list(zip(self.requests[rows].tolist(),
+                        self.counts[rows].argmax(axis=1).tolist()))
+
+    def release(self, rows):
+        self._free[self._n_free:self._n_free + len(rows)] = rows
+        self._n_free += len(rows)
+
+
+class _StepVotes:
+    """A step's share of the tally, fixed at dispatch (``FetchedStep.meta``):
+    each slot's life (row) and real-frame count; the rows it decides
+    (lives whose last chunk it carried, in ``sched.live`` order) and the
+    rows a storm in it orphaned."""
+
+    __slots__ = ("rows", "n_real", "decided", "orphaned")
 
 
 def run_audio_requests(sess, sched, ctl, *, audio_q, chunk: int,
@@ -374,8 +443,11 @@ def run_audio_requests(sess, sched, ctl, *, audio_q, chunk: int,
     admission resets]; eviction happens when a slot has consumed
     ``chunks_per_utt`` chunks (known at dispatch); only real-audio
     frames vote (``real_frames`` bounds the tally against zero-padding
-    and idle-slot frames).  Vote VALUES are integrated when their step
-    drains, into the incarnation that was live at dispatch.
+    and idle-slot frames).  Vote VALUES are tallied when their step
+    drains, into the incarnation that was live at dispatch
+    (``_VoteTable``), and a request is decided when the step that
+    carried its last chunk drains.  The slot state is arrays over the
+    slot axis, so the bookkeeping costs a few array operations a step.
 
     Returns ``(done, stats)``: the ordered [(request, predicted class)]
     list and the loop counters (steps, frames_served, pad_frames,
@@ -386,32 +458,52 @@ def run_audio_requests(sess, sched, ctl, *, audio_q, chunk: int,
                           scheduler=sched, clock=clock)
     warmup_s = warm_session(sess, chunk) if warm else 0.0
 
-    incarnations: dict[int, _Incarnation] = {}   # slot -> current life
-    order: list[_Incarnation] = []               # eviction order
+    batch = sess.batch
+    table = _VoteTable(sess.n_classes, batch, depth)
+    live = np.zeros(batch, bool)
+    row = np.zeros(batch, np.intp)          # the slot's current life
+    consumed = np.zeros(batch, np.int64)    # chunks consumed
+    left = np.zeros(batch, np.int64)        # real frames left to vote on
+    seq = np.zeros(batch, np.int64)         # admission number: live order
+    n_admitted = 0
+    done: list[tuple[Any, int]] = []
     frames_served = pad_frames = steps = 0
 
+    def start(slots: np.ndarray, requests: np.ndarray):
+        row[slots] = table.take(requests)
+        consumed[slots] = 0
+        left[slots] = real_frames
+
     def integrate(f: FetchedStep):
-        v = (np.concatenate(f.arrays, axis=0) if f.arrays
-             else np.zeros((0, sess.batch), np.int32))
-        for inc, slot, n_real in f.meta:
-            inc.counts += np.bincount(v[:n_real, slot],
-                                      minlength=sess.n_classes)
+        d = f.meta
+        pieces = f.arrays or (np.zeros((0, batch), np.int32),)
+        v = pieces[0] if len(pieces) == 1 else np.concatenate(pieces)
+        table.add(v, d.rows, d.n_real)
+        done.extend(table.decide(d.decided))
+        table.release(d.decided)
+        table.release(d.orphaned)
 
     def admit():
+        nonlocal n_admitted
         admitted = sched.admit()
         with spans.span(spans.SLOTS):
-            for slot, req in admitted:
-                incarnations[slot] = _Incarnation(req, sess.n_classes,
-                                                  real_frames)
+            if admitted:
+                slots, requests = zip(*admitted)
+                slots = np.fromiter(slots, np.intp, len(slots))
+                start(slots, np.fromiter(requests, object, len(slots)))
+                live[slots] = True
+                seq[slots] = np.arange(n_admitted, n_admitted + len(slots))
+                n_admitted += len(slots)
 
     admit()
     while not sched.idle:
         eng.begin()
         with spans.span(spans.ASSEMBLE):
-            block = eng.staging((sess.batch, chunk))
-            idle = np.ones(sess.batch, bool)
+            block = eng.staging((batch, chunk))
+            idle = np.ones(batch, bool)
+            chunk_of = consumed.tolist()
             for slot, req in sched.live.items():
-                c0 = incarnations[slot].progress[0]
+                c0 = chunk_of[slot]
                 seg = audio_q[req, c0 * chunk:(c0 + 1) * chunk]
                 n = len(seg)
                 block[slot, :n] = seg
@@ -421,33 +513,33 @@ def run_audio_requests(sess, sched, ctl, *, audio_q, chunk: int,
             block[idle] = 0.0                   # idle slots hear silence
             pieces, actions = ([block], []) if injector is None \
                 else injector.inject(block)
-        contribs: list[tuple] = []          # filled below, post-submit
-        piece_frames, drained = eng.submit(pieces, meta=contribs)
+        d = _StepVotes()                    # filled below, post-submit
+        piece_frames, drained = eng.submit(pieces, meta=d)
         n_f = sum(piece_frames)
         with spans.span(spans.SLOTS):
+            orphaned = [np.zeros(0, np.intp)]
             for act in actions:             # driver directives
                 if act.kind == "stall":
                     time.sleep(act.detail)
                 elif act.kind == "churn_storm":
                     storm = [s for s in act.slots if s in sched.live]
                     sess.reset_streams(storm)   # poof — streams restart
-                    for s in storm:             # same request, new life
-                        incarnations[s] = _Incarnation(sched.live[s],
-                                                       sess.n_classes,
-                                                       real_frames)
-            pad_frames += n_f * (sess.batch - len(sched.live))  # idle
-            for slot in list(sched.live):
-                inc = incarnations[slot]
-                st = inc.progress
-                n_real = min(n_f, st[1])
-                contribs.append((inc, slot, n_real))
-                st[1] -= n_real
-                frames_served += n_real
-                pad_frames += n_f - n_real
-                st[0] += 1
-                if st[0] >= chunks_per_utt:
-                    sched.evict(slot)
-                    order.append(inc)
+                    # a slot named twice restarts once
+                    s = np.array(list(dict.fromkeys(storm)), np.intp)
+                    orphaned.append(row[s])     # same request, new life
+                    start(s, table.requests[row[s]])
+            n_real = np.where(live, np.minimum(left, n_f), 0)
+            left -= n_real
+            consumed += live
+            served = int(n_real.sum())
+            frames_served += served
+            pad_frames += n_f * batch - served  # idle slots, short tails
+            out = np.flatnonzero(live & (consumed >= chunks_per_utt))
+            out = out[np.argsort(seq[out])]
+            sched.evict_many(out)
+            live[out] = False
+            d.rows, d.n_real = row.copy(), n_real
+            d.decided, d.orphaned = row[out], np.concatenate(orphaned)
             for f in drained:
                 integrate(f)
         admit()
@@ -458,7 +550,6 @@ def run_audio_requests(sess, sched, ctl, *, audio_q, chunk: int,
     for f in eng.flush():
         integrate(f)
 
-    done = [(inc.req, int(inc.counts.argmax())) for inc in order]
     slo = eng.report()
     slo["warmup_s"] = warmup_s
     sess.attach_slo(slo)
@@ -488,20 +579,19 @@ def run_continuous_detect(sess, streams_audio, *, chunk: int,
     ``det_point``), the total frame count, and the loop stats + SLO
     report (also attached to the session summary).
     """
-    from repro.models.detector import fires_from_events
+    from repro.models.detector import fires_from_event_block
 
     eng = PipelinedEngine(sess, depth=depth, field="events", clock=clock)
     warmup_s = warm_session(sess, chunk) if warm else 0.0
-    slots = sess.batch
-    fires: list[list] = [[] for _ in range(slots)]
+    fires: list[list] = [[] for _ in range(sess.batch)]
     frame_base = 0
     steps = 0
     dtype = np.result_type(*streams_audio)
 
     def integrate(f: FetchedStep):
         for ev, base in zip(f.arrays, f.meta):
-            for slot in range(slots):
-                fires[slot] += fires_from_events(ev[:, slot], base)
+            for slot, frame, cls in fires_from_event_block(ev, base):
+                fires[slot].append((frame, cls))
 
     for off in range(0, n_samples, chunk):
         eng.begin()

@@ -30,9 +30,11 @@ The spans, where they are entered, and the per-layer benchmark metric
                 health fetch and its resets               (report only)
   kws.fetch     ``PipelinedEngine``: ``np.asarray`` of a step's result
                                                           fetch_ms_per_step
-  kws.slots     loop drivers: per-slot Python — dispatch-time eviction
-                and vote bookkeeping, the integration of drained results
-                (vote tallies, ``fires_from_events``)     slots_ms_per_step
+  kws.slots     loop drivers: the slot-axis array bookkeeping —
+                incarnation ids at admission, real frames, eviction
+                (``evict_many``) at dispatch — and a drained step's
+                tally (one ``bincount``) or fire decode
+                (``fires_from_event_block``)              slots_ms_per_step
   kws.admit     ``SlotScheduler.admit``: the admission loop and its
                 batched ``reset_streams`` wave            admit_ms_per_step
 

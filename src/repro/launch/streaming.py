@@ -1822,8 +1822,9 @@ class StreamingKwsSession:
         carries it in ``StreamSummary.slo``.  Cleared by ``reset``."""
         self._slo = dict(report)
 
-    def shard_of_slot(self, i: int) -> int:
-        """Which mesh shard owns global slot ``i`` (block partitioning)."""
+    def shard_of_slot(self, i):
+        """Which mesh shard owns global slot ``i`` (block partitioning);
+        an int array of slots gives their shards."""
         return i // (self.batch // self.n_shards)
 
     def _layer_telemetry(self, acc: _Accum, frames: int):
@@ -1913,6 +1914,9 @@ class StreamingKwsSession:
             **cascade_kw,
             **robust,
         )
+
+
+_DOUBLE_EVICT = "already free (double evict?)"
 
 
 class SlotScheduler:
@@ -2010,13 +2014,44 @@ class SlotScheduler:
         as crashes far from the cause.
         """
         if slot not in self.live:
-            if not 0 <= slot < self.n_slots:
-                state = f"out of range [0, {self.n_slots})"
-            elif slot in self._free[self._sess.shard_of_slot(slot)]:
-                state = "already free (double evict?)"
-            else:
-                state = "never admitted"
-            raise ValueError(f"cannot evict slot {slot}: {state}")
+            raise self._not_live(slot)
         payload = self.live.pop(slot)
         self._free[self._sess.shard_of_slot(slot)].append(slot)
         return payload
+
+    def evict_many(self, slots) -> list:
+        """``[evict(s) for s in slots]`` as one call (``slots`` a 1-D int
+        array or sequence): the same payloads, free lists and refusals,
+        with the free-list appends done per shard.  Checked before
+        anything is freed: a slot that is not live, or that ``slots``
+        repeats, raises ``evict``'s ``ValueError`` and leaves the
+        scheduler as it was."""
+        order = np.asarray(slots, np.int64)
+        slots = order.tolist()
+        wanted = set(slots)
+        if len(wanted) != len(slots) or not wanted <= self.live.keys():
+            seen = set()
+            for slot in slots:
+                if slot in seen:
+                    raise ValueError(f"cannot evict slot {slot}: "
+                                     f"{_DOUBLE_EVICT}")
+                if slot not in self.live:
+                    raise self._not_live(slot)
+                seen.add(slot)
+        payloads = list(map(self.live.pop, slots))
+        if len(self._free) == 1:
+            self._free[0].extend(slots)
+        else:
+            shard = self._sess.shard_of_slot(order)
+            for s, free in enumerate(self._free):
+                free.extend(order[shard == s].tolist())
+        return payloads
+
+    def _not_live(self, slot: int) -> ValueError:
+        if not 0 <= slot < self.n_slots:
+            state = f"out of range [0, {self.n_slots})"
+        elif slot in self._free[self._sess.shard_of_slot(slot)]:
+            state = _DOUBLE_EVICT
+        else:
+            state = "never admitted"
+        return ValueError(f"cannot evict slot {slot}: {state}")

@@ -234,6 +234,22 @@ def fires_from_events(events: np.ndarray, frame_offset: int = 0
     return [(int(f) + frame_offset, int(ev[f])) for f in frames]
 
 
+def fires_from_event_block(events: np.ndarray, frame_offset: int = 0
+                           ) -> list[tuple[int, int, int]]:
+    """Decode a batched ``events`` block into ``(slot, frame, class_id)``.
+
+    events: (F, B) int32, frame-major, as a serving step fetches it.
+    One ``np.nonzero`` over the block; its row-major order lists fires
+    frame by frame, so each slot's fires come in frame order and
+    ``fires_from_events(events[:, b], frame_offset)`` equals slot ``b``'s
+    ``(frame, class_id)`` pairs here.
+    """
+    ev = np.asarray(events)
+    frames, slots = np.nonzero(ev != NO_EVENT)
+    return list(zip(slots.tolist(), (frames + frame_offset).tolist(),
+                    ev[frames, slots].tolist()))
+
+
 def match_fires(fires: Sequence[tuple[int, int]],
                 truth: Sequence[tuple[int, int, int]],
                 tol_frames: int = 0) -> tuple[int, int]:

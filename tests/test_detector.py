@@ -191,6 +191,58 @@ def test_fires_from_events_offsets():
     assert det.fires_from_events(ev, frame_offset=100) == [(104, 6)]
 
 
+def _event_block(rng, frames, slots, pattern):
+    ev = np.full((frames, slots), det.NO_EVENT, np.int32)
+    if pattern == "every":
+        ev[:] = rng.integers(0, 12, (frames, slots))
+    elif pattern == "sparse":
+        hit = rng.random((frames, slots)) < 0.1
+        ev[hit] = rng.integers(0, 12, int(hit.sum()))
+    return ev
+
+
+def _per_slot(pieces):
+    """Each slot's fires, one ``fires_from_events`` call per slot and
+    piece: the decode a serving step did before the block decode."""
+    fires = [[] for _ in range(pieces[0][0].shape[1])]
+    for ev, base in pieces:
+        for slot in range(ev.shape[1]):
+            fires[slot] += det.fires_from_events(ev[:, slot], base)
+    return fires
+
+
+@pytest.mark.parametrize("offset", [0, 7, 100000])
+@pytest.mark.parametrize("pattern", ["none", "every", "sparse"])
+@pytest.mark.parametrize("frames", [1, 32])
+def test_fires_from_event_block_equals_per_slot_decode(frames, pattern,
+                                                       offset):
+    ev = _event_block(np.random.default_rng(frames), frames, 16, pattern)
+    fires = [[] for _ in range(16)]
+    for slot, frame, cls in det.fires_from_event_block(ev, offset):
+        fires[slot].append((frame, cls))
+    assert fires == _per_slot([(ev, offset)])
+    assert all(type(x) is int for f in fires for fire in f for x in fire)
+    if pattern == "every":
+        assert all(len(f) == frames for f in fires)
+
+
+def test_fires_from_event_block_over_multi_piece_steps():
+    # Steps split into pieces of 0-3 frames, each decoded at its base:
+    # every slot's fires stay in frame order across pieces and steps.
+    rng = np.random.default_rng(3)
+    pieces, base = [], 0
+    for _ in range(40):
+        frames = int(rng.integers(0, 4))
+        pieces.append((_event_block(rng, frames, 8, "sparse"), base))
+        base += frames
+    fires = [[] for _ in range(8)]
+    for ev, b in pieces:
+        for slot, frame, cls in det.fires_from_event_block(ev, b):
+            fires[slot].append((frame, cls))
+    assert fires == _per_slot(pieces)
+    assert sum(map(len, fires)) > 0
+
+
 def test_pool_points_recomputes_rates_from_counts():
     a = det.det_point([(10, 3)], [(5, 20, 3)], n_frames=HOUR_FRAMES)
     b = det.det_point([(50, 4)], [(100, 120, 5)], n_frames=HOUR_FRAMES)

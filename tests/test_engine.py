@@ -18,6 +18,7 @@ import dataclasses
 import pathlib
 import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -296,6 +297,251 @@ def test_audio_conformance_under_fault_storms():
     assert stats3["frames_served"] == stats1["frames_served"]
 
 
+# ---------------------------------------------------------------------------
+# Differential: the slot-axis bookkeeping against the per-slot loop it
+# replaced.  ``oracle_run_audio_requests`` is that loop, kept verbatim as
+# the reference; ``ReplaySession`` feeds both the same vote arrays.
+
+class _OracleIncarnation:
+    """One admitted stream life on a slot: vote tally + chunk progress.
+
+    A churn storm or re-admission starts a NEW incarnation, so a fetch
+    landing after the slot was recycled still credits the life that was
+    live when its chunk was dispatched.  ``progress`` is the sync
+    loop's per-slot [chunks consumed, real frames left to vote on]."""
+
+    __slots__ = ("req", "counts", "progress")
+
+    def __init__(self, req, n_classes, real_frames):
+        self.req = req
+        self.counts = np.zeros(n_classes, np.int64)
+        self.progress = [0, real_frames]
+
+
+def oracle_run_audio_requests(sess, sched, ctl, *, audio_q, chunk: int,
+                              chunks_per_utt: int, real_frames: int,
+                              injector=None, depth: int = 1,
+                              warm: bool = True, clock=None):
+    """The utterance loop with per-slot Python bookkeeping, as it was
+    before the slot state became arrays."""
+    import time
+    from repro.launch import spans
+    from repro.launch.engine import FetchedStep, PipelinedEngine, \
+        warm_session
+    _Incarnation = _OracleIncarnation
+    eng = PipelinedEngine(sess, depth=depth, field="votes",
+                          scheduler=sched, clock=clock or time.perf_counter)
+    warmup_s = warm_session(sess, chunk) if warm else 0.0
+
+    incarnations: dict[int, _Incarnation] = {}   # slot -> current life
+    order: list[_Incarnation] = []               # eviction order
+    frames_served = pad_frames = steps = 0
+
+    def integrate(f: FetchedStep):
+        v = (np.concatenate(f.arrays, axis=0) if f.arrays
+             else np.zeros((0, sess.batch), np.int32))
+        for inc, slot, n_real in f.meta:
+            inc.counts += np.bincount(v[:n_real, slot],
+                                      minlength=sess.n_classes)
+
+    def admit():
+        admitted = sched.admit()
+        with spans.span(spans.SLOTS):
+            for slot, req in admitted:
+                incarnations[slot] = _Incarnation(req, sess.n_classes,
+                                                  real_frames)
+
+    admit()
+    while not sched.idle:
+        eng.begin()
+        with spans.span(spans.ASSEMBLE):
+            block = eng.staging((sess.batch, chunk))
+            idle = np.ones(sess.batch, bool)
+            for slot, req in sched.live.items():
+                c0 = incarnations[slot].progress[0]
+                seg = audio_q[req, c0 * chunk:(c0 + 1) * chunk]
+                n = len(seg)
+                block[slot, :n] = seg
+                if n < chunk:
+                    block[slot, n:] = 0.0       # zero-pad a short chunk
+                idle[slot] = False
+            block[idle] = 0.0                   # idle slots hear silence
+            pieces, actions = ([block], []) if injector is None \
+                else injector.inject(block)
+        contribs: list[tuple] = []          # filled below, post-submit
+        piece_frames, drained = eng.submit(pieces, meta=contribs)
+        n_f = sum(piece_frames)
+        with spans.span(spans.SLOTS):
+            for act in actions:             # driver directives
+                if act.kind == "stall":
+                    time.sleep(act.detail)
+                elif act.kind == "churn_storm":
+                    storm = [s for s in act.slots if s in sched.live]
+                    sess.reset_streams(storm)   # poof — streams restart
+                    for s in storm:             # same request, new life
+                        incarnations[s] = _Incarnation(sched.live[s],
+                                                       sess.n_classes,
+                                                       real_frames)
+            pad_frames += n_f * (sess.batch - len(sched.live))  # idle
+            for slot in list(sched.live):
+                inc = incarnations[slot]
+                st = inc.progress
+                n_real = min(n_f, st[1])
+                contribs.append((inc, slot, n_real))
+                st[1] -= n_real
+                frames_served += n_real
+                pad_frames += n_f - n_real
+                st[0] += 1
+                if st[0] >= chunks_per_utt:
+                    sched.evict(slot)
+                    order.append(inc)
+            for f in drained:
+                integrate(f)
+        admit()
+        steps += 1
+        eng.end()
+        if ctl is not None:
+            ctl.observe(eng.last_step_s)
+    for f in eng.flush():
+        integrate(f)
+
+    done = [(inc.req, int(inc.counts.argmax())) for inc in order]
+    slo = eng.report()
+    slo["warmup_s"] = warmup_s
+    sess.attach_slo(slo)
+    return done, {"steps": steps, "frames_served": frames_served,
+                  "pad_frames": pad_frames, "warmup_s": warmup_s,
+                  "slo": slo}
+
+
+class ReplaySession:
+    """A session stand-in that plays recorded votes: call ``k`` of
+    ``process_audio`` returns the votes seeded by ``k``, over the frames
+    a 128-sample frame shift completes (a carried remainder included).
+    ``log`` records every device operation the loop issues, in order:
+    each piece (its width and a digest of its samples) and each
+    ``reset_streams`` wave.  Few classes, so tallies often tie."""
+
+    frame_shift = 128
+
+    def __init__(self, batch=3, n_classes=3, n_shards=1, seed=0):
+        self.batch = batch
+        self.n_classes = n_classes
+        self.n_shards = n_shards
+        self.seed = seed
+        self.log: list[tuple] = []
+        self._carry = 0
+
+    def shard_of_slot(self, i):
+        return i // (self.batch // self.n_shards)
+
+    def flagged_slots(self):
+        return frozenset()
+
+    def reset_streams(self, slots):
+        self.log.append(("reset", tuple(int(s) for s in slots)))
+
+    def attach_slo(self, report):
+        pass
+
+    def process_audio(self, piece):
+        piece = np.asarray(piece)
+        width = self._carry + piece.shape[1]
+        frames, self._carry = divmod(width, self.frame_shift)
+        call = sum(1 for op in self.log if op[0] == "piece")
+        self.log.append(("piece", piece.shape[1], hash(piece.tobytes())))
+        rng = np.random.default_rng([self.seed, call])
+        votes = rng.integers(0, self.n_classes, (frames, self.batch),
+                             dtype=np.int32)
+        return types.SimpleNamespace(votes=votes)
+
+
+_STORMS = "churn_storm:0.2,one_sample_chunk:0.25,drop_chunk:0.15"
+
+
+def _replay_run(loop, depth, *, faults=None, requests=12, slots=3,
+                shards=1, utt=4000, chunk=1000):
+    from repro.launch.faults import FaultInjector, FaultPlan, \
+        parse_fault_specs
+    from repro.launch.streaming import SlotScheduler
+    sess = ReplaySession(batch=slots, n_shards=shards)
+    sched = SlotScheduler(sess)
+    for req in range(requests):
+        sched.submit(req)
+    audio_q = np.random.default_rng(11).uniform(
+        -0.5, 0.5, (requests, utt)).astype(np.float32)
+    injector = None
+    if faults:
+        injector = FaultInjector(FaultPlan(seed=5,
+                                           specs=parse_fault_specs(faults)),
+                                 slots)
+    done, stats = loop(sess, sched, None, audio_q=audio_q, chunk=chunk,
+                       chunks_per_utt=-(-utt // chunk),
+                       real_frames=utt // ReplaySession.frame_shift,
+                       injector=injector, depth=depth, warm=False)
+    return done, stats, sess.log
+
+
+@pytest.fixture
+def vote_tables(monkeypatch):
+    """The vote tables the loops under test build, for their size."""
+    from repro.launch import engine
+    tables = []
+
+    class Recorded(engine._VoteTable):
+        def __init__(self, *args):
+            super().__init__(*args)
+            tables.append(self)
+    monkeypatch.setattr(engine, "_VoteTable", Recorded)
+    return tables
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+@pytest.mark.parametrize("traffic", [
+    dict(),                                 # clean
+    dict(faults=_STORMS, requests=40),      # storms, splits, drops
+    # Two shards admit slots out of index order (0, 2, 1, 3).
+    dict(slots=4, shards=2, requests=14),
+    dict(slots=4, shards=2, requests=40, faults=_STORMS),
+], ids=["clean", "storms", "shards2", "shards2-storms"])
+def test_audio_loop_equals_per_slot_oracle(vote_tables, traffic, depth):
+    from repro.launch.engine import run_audio_requests
+    want_done, want, want_log = _replay_run(oracle_run_audio_requests,
+                                            depth, **traffic)
+    done, got, log = _replay_run(run_audio_requests, depth, **traffic)
+    assert done == want_done                # same pairs, same order
+    assert len(done) == traffic.get("requests", 12)
+    if "faults" in traffic:                 # restarts reorder the answers
+        assert [r for r, _ in done] != sorted(r for r, _ in done)
+    for key in ("frames_served", "pad_frames", "steps"):
+        assert got[key] == want[key], key
+    assert log == want_log                  # device operations, in order
+    slots = traffic.get("slots", 3)
+    table, = vote_tables
+    # Clean traffic holds at most batch · depth lives; one storm a step
+    # at most doubles that.
+    bound = slots * depth * (2 if "faults" in traffic else 1)
+    assert len(table.counts) <= bound
+
+
+def test_audio_loop_table_stays_bounded_over_a_long_run(vote_tables):
+    # 5,000 requests through 8 slots at depth 2: the count table keeps
+    # its batch · depth rows and reuses them, and the answers still
+    # equal the per-slot loop's.
+    from repro.launch.engine import run_audio_requests
+    kw = dict(requests=5000, slots=8, utt=2000, chunk=1024)
+    want_done, want, want_log = _replay_run(oracle_run_audio_requests, 2,
+                                            **kw)
+    done, got, log = _replay_run(run_audio_requests, 2, **kw)
+    assert done == want_done and len(done) == 5000
+    assert (got["frames_served"], got["pad_frames"], got["steps"]) == \
+        (want["frames_served"], want["pad_frames"], want["steps"])
+    assert log == want_log
+    table, = vote_tables
+    assert table.counts.shape == (8 * 2, 3)
+    assert len(table.requests) == 8 * 2
+
+
 def test_detect_conformance_async_equals_sync():
     from repro.launch.streaming import StreamingKwsSession
     from repro.launch.engine import run_continuous_detect
@@ -472,14 +718,14 @@ utt, chunk, requests = 4000, 1000, 6
 rng = np.random.default_rng(11)
 audio_q = rng.uniform(-0.5, 0.5, (requests, utt)).astype(np.float32)
 
-def run(depth):
+def run(depth, loop=run_audio_requests):
     sess = StreamingKwsSession(params, cfg, threshold=0.1, batch=4,
                                fex=fex, mesh=make_slot_mesh(2))
     assert sess.n_shards == 2
     sched = SlotScheduler(sess)
     for req in range(requests):
         sched.submit(req)
-    done, stats = run_audio_requests(
+    done, stats = loop(
         sess, sched, ctl=None, audio_q=audio_q, chunk=chunk,
         chunks_per_utt=-(-utt // chunk),
         real_frames=utt // fex.cfg.frame_shift, depth=depth, warm=False)
@@ -491,6 +737,14 @@ assert d2 == d1, (d1, d2)
 assert s2 == s1
 assert st2["frames_served"] == st1["frames_served"]
 assert st2["slo"]["shard_imbalance"]["max"] <= 1
+# The per-slot loop the slot-axis bookkeeping replaced, at each depth.
+from test_engine import oracle_run_audio_requests
+for depth, got in ((1, (d1, s1, st1)), (2, (d2, s2, st2)), (3, run(3))):
+    want = run(depth, oracle_run_audio_requests)
+    assert got[0] == want[0], (depth, got[0], want[0])
+    assert got[1] == want[1]
+    for key in ("frames_served", "pad_frames", "steps"):
+        assert got[2][key] == want[2][key], (depth, key)
 print("ENGINE_MESH2_OK")
 """
 
@@ -500,7 +754,8 @@ def test_engine_mesh2_conformance():
     import os
     r = subprocess.run(
         [sys.executable, "-c", ENGINE_MESH_CHILD], capture_output=True,
-        text=True, env={**os.environ, "PYTHONPATH": str(REPO / "src")},
+        text=True, env={**os.environ, "PYTHONPATH":
+                        f"{REPO / 'src'}{os.pathsep}{REPO / 'tests'}"},
         timeout=540)
     assert r.returncode == 0, f"{r.stdout[-2000:]}\n{r.stderr[-3000:]}"
     assert "ENGINE_MESH2_OK" in r.stdout
@@ -540,6 +795,47 @@ def test_double_evict_raises_not_corrupts():
         sched.submit(r)
     admitted = sched.admit()
     assert sorted(s for s, _ in admitted) == [0, 1, 2, 3]
+
+
+def _stub_sched(batch=8, shards=2, live=8):
+    from repro.launch.streaming import SlotScheduler
+    sched = SlotScheduler(ReplaySession(batch=batch, n_shards=shards))
+    for r in range(live):
+        sched.submit(r)
+    sched.admit()
+    return sched
+
+
+def test_evict_many_refuses_what_evict_refuses():
+    sched = _stub_sched(live=3)              # slots 0, 4, 1 live
+    before = (dict(sched.live), [list(f) for f in sched._free])
+    with pytest.raises(ValueError, match=r"slot 2: already free"):
+        sched.evict_many([0, 2])             # on the free list
+    with pytest.raises(ValueError, match=r"slot 9.*out of range"):
+        sched.evict_many([9])
+    with pytest.raises(ValueError, match=r"slot 4: already free"):
+        sched.evict_many([4, 1, 4])          # repeated in one call
+    # Refused calls free nothing.
+    assert (dict(sched.live), [list(f) for f in sched._free]) == before
+    sched.evict(4)
+    with pytest.raises(ValueError, match=r"slot 4: already free"):
+        sched.evict_many([0, 4])
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_evict_many_equals_evict_one_by_one(shards):
+    order = [5, 0, 7, 2, 3, 6]
+    one, many = _stub_sched(shards=shards), _stub_sched(shards=shards)
+    want = [one.evict(s) for s in order]
+    assert many.evict_many(np.array(order)) == want
+    assert many.live == one.live
+    assert many._free == one._free           # per shard, in order
+    assert all(type(s) is int for f in many._free for s in f)
+    assert many.evict_many([]) == []
+    for r in range(8):                       # and admits alike after
+        one.submit(r)
+        many.submit(r)
+    assert many.admit() == one.admit()
 
 
 def test_admit_refuses_supervisor_flagged_slots():
