@@ -29,7 +29,6 @@ for any prefetcher and overstates the skip win.
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import pathlib
 
@@ -357,8 +356,8 @@ def run_fex_bench(th: float = 0.2):
     every pre-PR deployment (fex(audio) → host → process_chunk) ran.
     """
     from repro.configs import get_config
-    from repro.frontend.fex import FeatureExtractor, init_fex_state
-    from repro.launch import streaming as st
+    from repro.frontend.fex import FeatureExtractor
+    from repro.launch.streaming import StreamingKwsSession
     from repro.models import kws
 
     cfg = get_config("deltakws")
@@ -394,18 +393,13 @@ def run_fex_bench(th: float = 0.2):
             feats = np.asarray(scan_fex(a))
             return gru_fc_j(jnp.asarray(feats))
 
-        fused_step = jax.jit(functools.partial(
-            st._process_audio_chunk, threshold=th, backend="pallas",
-            fex_backend=fex_backend, interpret=None,
-            frame_shift=fex.cfg.frame_shift, env_alpha=fex.cfg.env_alpha,
-            log_eps=fex.cfg.log_eps))
-        fstate = init_fex_state(B, fex.cfg.n_active)
-        gstate = dg.init_delta_state(B, fex.cfg.n_active, cfg.d_model, gru)
-        acc = st._zero_accum()
+        sess = StreamingKwsSession(params, cfg, threshold=th, batch=B,
+                                   fex=fex, fex_backend=fex_backend)
+        fused_step, args = sess.fused_audio_step(audio.shape[1])
+        cut = args[-1].shape[1]
 
         def fused(a):
-            return fused_step(gru, w_fc, b_fc, fex.coef, fstate, gstate,
-                              acc, a)
+            return fused_step(*args[:-1], a[:, :cut])
 
         def row(name, us):
             return {

@@ -3,14 +3,9 @@
 The IC's deployment mode is an always-on stream: 8 kHz audio enters the
 FEx, one decision leaves per 16 ms frame, and every piece of state (biquad
 registers, envelope, x̂/ĥ/M) is resident on-chip.  The serving image of
-that is a session whose FEx state, delta state and op-count telemetry live
-on DEVICE between chunks: the host hands over a chunk of raw audio, gets
+that is a session whose stream state and op-count telemetry live on
+DEVICE between chunks: the host hands over a chunk of raw audio, gets
 device arrays back, and never forces a per-frame sync.
-
-``StreamingKwsSession`` composes the batched sequence-resident FEx kernel
-(``kernels.iir_fex.batched_iir_fex``) with the fused sequence-resident
-ΔGRU kernel (``kernels.delta_gru_seq``) into ONE jitted audio→decision
-step per chunk — no host hop between FEx and ΔGRU:
 
     sess = StreamingKwsSession(params, cfg, threshold=0.1, fex=fex)
     for audio in audio_chunks:                # (samples,) raw 8 kHz audio
@@ -18,65 +13,65 @@ step per chunk — no host hop between FEx and ΔGRU:
         votes = np.asarray(out.votes)         # ONE fetch per chunk
     print(sess.summary())                     # one fetch for telemetry
 
-Pre-computed feature chunks are still accepted via ``process_chunk``.
-Chunk boundaries are invisible to the model either way: processing [a|b]
-equals processing the concatenation in one shot, bit for bit, including
-audio chunks that end mid-frame (the trailing ``< frame_shift`` samples
-are carried host-side and prepended to the next chunk — they are host
-data already, so no device sync is involved).
+One fused step per chunk (``_kws_step``, jitted as ``kws_audio_step``)
+is composed from the stages the session runs, in this order:
+
+    non-finite input flag → 12-bit ADC → [frame energy] → FEx (kws.fex)
+    → [VAD gate] → [stage-0 ΔGRU → wake machine] → ΔGRU stack (kws.gru,
+    one kws.gru.<l> per layer; wake-masked under the cascade) → FC head
+    (kws.head) → [posterior smoothing + hysteresis events] → telemetry
+    → per-slot health mask
+
+``_Stages`` says which bracketed stages run; the numerics object
+(``_Float32`` / ``_Int8``) owns everything that differs between the
+float datapath and the IC's bit-true integer one (DESIGN.md §9: ADC
+codes, the packed int16 FEx registers, int16 feature codes, the
+int8-weight ΔGRU, argmax over int32 logit codes — bit-identical to the
+golden fixed-point model ``core.fixed_point``).  Every stage's per-slot
+state is one ``SlotState`` tree.  Pre-computed features run the same
+step from the ΔGRU on (``process_chunk``).  Chunk boundaries are
+invisible to the model: processing [a|b] equals processing the
+concatenation in one shot, bit for bit, including audio chunks that end
+mid-frame (the trailing ``< frame_shift`` samples are carried host-side
+and prepended to the next chunk).
 
 Scale-out (DESIGN.md §6): pass ``mesh=make_slot_mesh(...)`` and the
 session becomes a sharded continuous-batching engine — the SLOT axis
 (one live stream per slot) is partitioned over the mesh's "data" axis
-with ``shard_map``, weights/coefficients are replicated, per-stream
-FEx+ΔGRU state and telemetry are sharded on slots, and the hot path has
-neither host syncs nor cross-device collectives (telemetry is kept as
-per-shard partial sums, reduced on the host once per ``summary()``).
-``reset_stream`` is slot-local — a jitted dynamic row update that only
-the owning shard executes — so stream churn on one shard never stalls
-the others.  At mesh=None (or one device) the engine is bit-identical
-to the original single-device session.  ``SlotScheduler`` maps a
-request queue onto the global slots, balancing admissions across
-shards.
+with ``shard_map``, weights/coefficients are replicated, the slot state
+and telemetry are sharded on slots, and the hot path has neither host
+syncs nor cross-device collectives (telemetry is kept as per-shard
+partial sums, reduced on the host once per ``summary()``).
+``reset_streams`` is slot-local — one jitted mask-select over the slot
+state — so stream churn on one shard never stalls the others.  At
+mesh=None (or one device) the engine is bit-identical to the
+single-device session.  ``SlotScheduler`` maps a request queue onto the
+global slots, balancing admissions across shards.
 
-Numerics (DESIGN.md §9): ``numerics="int8"`` swaps the fused float step
-for the bit-true integer pipeline — 12-bit ADC codes → integer FEx →
-int8-weight/int16-state ΔGRU → integer FC — consuming a promoted
-``IntKwsBundle`` (``train.promote``) and carrying every piece of stream
-state as integer codes.  Same shard/scheduler machinery, decisions are
-argmaxes over int32 logit codes, bit-identical to the golden
-fixed-point model (``core.fixed_point``).
-
-Fault tolerance (DESIGN.md §11): the fused step also emits a per-slot
-HEALTH bitmask — finite-state predicates over the FEx biquad registers,
-the ΔGRU x̂/ĥ/M, the VAD hold and the detector EMA (saturation-rail
-compares in the int8 engine, where state cannot go non-finite) plus a
+Fault tolerance (DESIGN.md §11): the step's per-slot HEALTH bitmask holds
+finite-state predicates over the carried state (saturation-rail compares
+in the int8 engine, where state cannot go non-finite) plus a
 non-finite-input flag computed before the ADC quantizer.  Pass
 ``supervisor=SupervisorConfig(...)`` and a host-side supervisor reads
 that mask (one tiny fetch per ``check_every`` chunks), quarantines
 slots whose poisoned state can never recover on its own, and resets
-them through the same mask-batched ``reset_streams`` that serves
-continuous-batching churn — a healed slot is bit-identical to a fresh
-stream.  Recovery counts and reasons surface in ``StreamSummary``; on
-healthy streams every flag is zero and the engine is bit-identical to
-an unsupervised session.  ``input_policy`` guards the ``process_audio``
-boundary (reject / sanitize / trust hostile samples), telemetry counters
-are carried as split int32 pairs exact to 2^61 (``overflowed`` flags the
-saturation that would silently wedge float32 partial sums at 2^24), and
+them through the same ``reset_streams`` that serves continuous-batching
+churn — a healed slot is bit-identical to a fresh stream.
+``input_policy`` guards the ``process_audio`` boundary (reject /
+sanitize / trust hostile samples), telemetry counters are carried as
+split int32 pairs exact to 2^61 (``overflowed`` flags saturation), and
 ``set_threshold`` re-points the compiled step at a different Δ_TH
 operating point mid-stream — the graceful-degradation lever the serve
 launcher's admission controller drives under overload.
 
-Detection (DESIGN.md §10): pass ``detector=DetectorConfig(...)`` and the
-session serves the always-on scenario the IC was built for — continuous
-audio in, discrete keyword EVENTS out.  The fused step grows two stages:
-an energy VAD (``frontend.vad``) that sample-and-holds the features
-during silence so the Δ-encoder transmits nothing (the temporal-sparsity
-/ energy knob), and a posterior-smoothing hysteresis head
-(``models.detector``) that turns per-frame posteriors into one event per
-spoken keyword.  Both carry per-slot device state, compose with either
-numerics and the slot mesh, and keep every bit-invariance guarantee
-above; ``process_audio`` returns ``DetectResult`` (events + gate trace).
+Detection (DESIGN.md §10, §13): pass ``detector=DetectorConfig(...)``
+and the session serves continuous audio in, keyword EVENTS out: the
+energy VAD (``frontend.vad``) sample-and-holds the features during
+silence so the Δ-encoder transmits nothing, and the decision head
+(``models.detector``) turns per-frame posteriors into one event per
+spoken keyword; ``process_audio`` returns ``DetectResult``.  Add
+``cascade=CascadeConfig(...)`` and an always-on stage-0 micro-ΔGRU
+powers the stage-1 network only while a candidate event is live.
 """
 from __future__ import annotations
 
@@ -185,15 +180,18 @@ class CascadeState(NamedTuple):
     s0: dg.DeltaState   # stage-0 carried delta state
 
 
-def init_cascade_state(batch: int, s0_gru, *, int8: bool) -> CascadeState:
-    """Fresh cascade state: everyone asleep, stage-0 state zeroed (M
-    seeded at the stage-0 bias, like any fresh ΔGRU stream)."""
-    I = s0_gru.w_x.shape[0]
-    H = s0_gru.w_h.shape[0]
-    s0 = (fp.init_int_delta_state(batch, I, H, s0_gru) if int8
-          else dg.init_delta_state(batch, I, H, s0_gru))
-    return CascadeState(awake=jnp.zeros((batch,), bool),
-                        hang=jnp.zeros((batch,), jnp.int32), s0=s0)
+class SlotState(NamedTuple):
+    """Every stage's per-slot stream state as one tree: slot-major,
+    device-resident, sharded on the slot axis, ``None`` for a stage the
+    session does not run.  The fields are in the fused step's argument
+    order."""
+
+    fex: FExState | None = None           # biquad registers, envelope
+    gru: Any = None                       # ΔGRU x̂/ĥ/M: one DeltaState,
+                                          #   or a tuple per layer
+    cascade: CascadeState | None = None   # wake latch, stage-0 ΔGRU
+    vad: VADState | None = None           # feature hold, hangover
+    det: DetectorState | None = None      # smoothed posteriors, latch
 
 
 def cascade_wake_scan(cfg: CascadeConfig, awake: Array, hang: Array,
@@ -283,9 +281,7 @@ def _flag(bit: int, bad: Array) -> Array:
     return jnp.where(bad, jnp.int32(bit), jnp.int32(0))
 
 
-def slot_health(input_bad: Array, fex_state: FExState | None,
-                gru_state, vad_state: VADState | None,
-                det_state: DetectorState | None) -> Array:
+def slot_health(input_bad: Array, slots: SlotState) -> Array:
     """Fuse the per-slot health predicates into one (B,) int32 bitmask.
 
     Pure reads over the carried state trees, elementwise along the slot
@@ -295,27 +291,29 @@ def slot_health(input_bad: Array, fex_state: FExState | None,
     the "saturation-flag counters" of the paper's datapath, priced at a
     handful of compares per slot per chunk.  ``input_bad`` is the
     pre-quantizer non-finite-sample flag (computed before the 12-bit
-    clip, which would otherwise launder an Inf into full-scale).
+    clip, which would otherwise launder an Inf into full-scale).  The
+    cascade's stage-0 state is checked by its own call, as a ``gru``.
     """
     flags = _flag(HEALTH_INPUT, input_bad)
-    if fex_state is not None:
-        if jnp.issubdtype(fex_state.filt.dtype, jnp.floating):
-            bad = _slot_any(~jnp.isfinite(fex_state.filt)
-                            | (jnp.abs(fex_state.filt) > _FEX_MAG_BOUND))
-            bad |= _slot_any(~jnp.isfinite(fex_state.env))
+    if slots.fex is not None:
+        fex = slots.fex
+        if jnp.issubdtype(fex.filt.dtype, jnp.floating):
+            bad = _slot_any(~jnp.isfinite(fex.filt)
+                            | (jnp.abs(fex.filt) > _FEX_MAG_BOUND))
+            bad |= _slot_any(~jnp.isfinite(fex.env))
         else:
-            f32 = fex_state.filt.astype(jnp.int32)
-            e32 = fex_state.env.astype(jnp.int32)
+            f32 = fex.filt.astype(jnp.int32)
+            e32 = fex.env.astype(jnp.int32)
             bad = _slot_any(jnp.abs(f32) >= _INT16_RAIL)
             bad |= _slot_any(jnp.abs(e32) >= _INT16_RAIL)
         flags |= _flag(HEALTH_FEX, bad)
-    if gru_state is not None:
-        for layer, st in enumerate(_layers(gru_state)):
+    if slots.gru is not None:
+        for layer, st in enumerate(_layers(slots.gru)):
             flags |= _gru_flags(st, first=layer == 0)
-    if vad_state is not None:
-        flags |= _flag(HEALTH_VAD, vad_state_flags(vad_state))
-    if det_state is not None:
-        flags |= _flag(HEALTH_DET, detector_state_flags(det_state))
+    if slots.vad is not None:
+        flags |= _flag(HEALTH_VAD, vad_state_flags(slots.vad))
+    if slots.det is not None:
+        flags |= _flag(HEALTH_DET, detector_state_flags(slots.det))
     return flags
 
 
@@ -491,17 +489,185 @@ class LayerCounts(NamedTuple):
     hidden: int
 
 
+# ------------------------------------------------------------- numerics --
+# What differs between the float and the int8 datapath, owned by one small
+# value per session (``_NUMERICS``): the data format (float values against
+# integer codes) and the kernels that read it.  Everything else in the
+# fused step and the session is the same code for both.
+class _Float32:
+    """The float datapath: float samples into the FEx, float features and
+    ΔGRU state, ``f32_dot`` heads."""
+
+    backends = ("pallas", "pallas-int", "xla")    # ΔGRU dispatch
+    hold_dtype = jnp.float32                # the VAD holds float features
+    gru_kernel, gru_dtype = "delta_gru_seq", "float32"
+    fex_kernel, fex_dtype = "batched_iir_fex", "float32"
+    init_gru = staticmethod(dg.init_delta_state)
+
+    def load(self, params, threshold, *, bundle, fex, quantize_8b, mesh):
+        """(replicated (gru, w_fc, b_fc), the promoted bundle or None)."""
+        return kws.serving_weights(params, quantize_8b, mesh), None
+
+    def attach_fex(self, bundle, fex, mesh):
+        """(bundle, replicated coefficient bank, the FEx's static kwargs)."""
+        return (bundle, shp.put_replicated(fex.coef, mesh),
+                {"env_alpha": fex.cfg.env_alpha, "log_eps": fex.cfg.log_eps})
+
+    def fresh_fex(self, batch: int, n_channels: int) -> FExState:
+        return init_fex_state(batch, n_channels)
+
+    def fex(self, audio, coef, state, *, frame_shift, backend, interpret,
+            env_alpha, log_eps):
+        """(feats (B, F, C), the FEx state as the scan carries it)."""
+        return fex_scan(audio, coef, state, frame_shift=frame_shift,
+                        env_alpha=env_alpha, log_eps=log_eps, compress=True,
+                        backend=backend, interpret=interpret)
+
+    def fex_state(self, carried) -> FExState:
+        return carried
+
+    def features(self, feats, gfmt):
+        """``process_chunk``'s float features as the ΔGRU's input."""
+        return feats
+
+    def scan(self, gru, fmt, xs, threshold, state, *, backend, interpret):
+        """One ΔGRU layer over the chunk: (hs, state, nz_dx, nz_dh)."""
+        hs, state, stats = dg.delta_gru_scan(
+            gru, xs, threshold=threshold, state=state, backend=backend,
+            interpret=interpret)
+        return hs, state, stats.nz_dx, stats.nz_dh
+
+    def masked_scan(self, gru, fmt, xs, threshold, state, awake):
+        """Stage 1 under the cascade's wake mask: asleep frames leave the
+        state bit-frozen and count no Δs."""
+        hs, state, stats = dg.masked_delta_gru_scan(gru, xs, threshold,
+                                                    state, awake)
+        return hs, state, stats.nz_dx, stats.nz_dh
+
+    @jax.named_scope("kws.head")
+    def head(self, w_fc, b_fc, hs, nz, gfmt) -> ChunkResult:
+        logits = f32_dot(hs, w_fc) + b_fc             # (F, B, K)
+        votes = jnp.argmax(logits, -1).astype(jnp.int32)
+        return ChunkResult(logits=logits, votes=votes, nz=nz)
+
+    def logits(self, w_fc, b_fc, hs, gfmt):
+        """Stage 0's logits (no votes, no scope)."""
+        return f32_dot(hs, w_fc) + b_fc
+
+
+class _Int8:
+    """The IC's integer datapath (DESIGN.md §9): 12-bit ADC codes into
+    the FEx with its packed int16 registers, int16 feature codes, the
+    int8-weight/int16-state ΔGRU, FC and argmax on int32 logit codes —
+    bit-true against the golden fixed-point model (``core.fixed_point``).
+    ``gfmt`` is the promoted formats of one layer or a tuple per layer."""
+
+    backends = ("pallas", "xla")
+    hold_dtype = jnp.int16                  # the VAD holds feature codes
+    gru_kernel, gru_dtype = "delta_gru_seq_int", "int8"
+    fex_kernel, fex_dtype = "batched_iir_fex_int", "int16"
+    init_gru = staticmethod(fp.init_int_delta_state)
+
+    def load(self, params, threshold, *, bundle, fex, quantize_8b, mesh):
+        # Without a bundle, ``params`` is promoted here — the train→deploy
+        # fold at session creation, from the model's own dynamic range.
+        if bundle is None:
+            bundle = fp.promote_kws(params, threshold, fex=fex)
+        return shp.put_replicated(
+            (bundle.gru, bundle.w_fc, bundle.b_fc), mesh), bundle
+
+    def attach_fex(self, bundle, fex, mesh):
+        # Fold the FEx coefficient bank into the bundle if the promotion
+        # happened without one (feature-mode bundles).  fold_fex copies —
+        # a caller-shared bundle is untouched.
+        bundle = fp.fold_fex(bundle, fex)
+        return (bundle, shp.put_replicated(bundle.coef, mesh),
+                {"ffmt": bundle.ffmt})
+
+    def fresh_fex(self, batch: int, n_channels: int) -> FExState:
+        return _unpack_state(fp.init_int_fex_state(batch, n_channels))
+
+    def fex(self, audio, coef, state, *, frame_shift, backend, interpret,
+            ffmt):
+        codes = fp.to_code(audio, ffmt.feat_frac, 16, jnp.int16)
+        return fp.int_fex_scan(codes, coef, _pack_state(state), ffmt,
+                               frame_shift=frame_shift, backend=backend,
+                               interpret=interpret)
+
+    def fex_state(self, carried) -> FExState:
+        return _unpack_state(carried)
+
+    def features(self, feats, gfmt):
+        """Features on the 12-bit grid → int16 codes."""
+        return fp.to_code(feats, _layers(gfmt)[0].feat_frac, 16, jnp.int16)
+
+    def scan(self, gru, fmt, xs, threshold, state, *, backend, interpret):
+        return fp.int_gru_scan(gru, fmt, xs, threshold, state=state,
+                               backend=backend, interpret=interpret)
+
+    def masked_scan(self, gru, fmt, xs, threshold, state, awake):
+        return fp.masked_int_gru_scan(gru, fmt, xs, threshold, state, awake)
+
+    @jax.named_scope("kws.head")
+    def head(self, w_fc, b_fc, hs, nz, gfmt) -> ChunkResult:
+        """The decision is the argmax over int32 logit codes (bit-true);
+        the logits are dequantized — grid-exact — for the float-typed
+        result and the detection head."""
+        codes = fp.int_fc(hs, w_fc, b_fc)               # (F, B, K) int32
+        votes = jnp.argmax(codes, -1).astype(jnp.int32)
+        return ChunkResult(
+            logits=fp.from_code(codes, _layers(gfmt)[-1].logit_frac),
+            votes=votes, nz=nz)
+
+    def logits(self, w_fc, b_fc, hs, gfmt):
+        return fp.from_code(fp.int_fc(hs, w_fc, b_fc), gfmt.logit_frac)
+
+
+_NUMERICS = {"float32": _Float32(), "int8": _Int8()}
+
+
+# --------------------------------------------------------------- stages --
+class _Stages(NamedTuple):
+    """Which stages a fused step runs — decided once per session, bound
+    into the step as a static.
+
+    audio: raw audio through the ADC and the FEx (False: features in).
+    vad, det: the VAD gate and the detection head, together (detect mode).
+    cascade: the stage-0 wake cascade in front of the stage-1 ΔGRU.
+    """
+
+    audio: bool
+    vad: VADConfig | None = None
+    det: DetectorConfig | None = None
+    cascade: CascadeConfig | None = None
+
+    @property
+    def fields(self) -> tuple[str, ...]:
+        """The ``SlotState`` fields the step carries, in argument order."""
+        on = (self.audio, True, self.cascade is not None,
+              self.det is not None, self.det is not None)
+        return tuple(f for f, o in zip(SlotState._fields, on) if o)
+
+    @property
+    def n_weights(self) -> int:
+        """Replicated leading arguments: the ΔGRU stack and FC head, stage
+        0's three, the FEx coefficient bank."""
+        return 3 + 3 * (self.cascade is not None) + self.audio
+
+
+_FEATURES = _Stages(audio=False)             # ``process_chunk``'s step
+
+
 @jax.named_scope("kws.gru")
-def _gru_stack(gru, state, xs, *, threshold: float, backend: str,
+def _gru_stack(num, gru, state, xs, *, threshold: float, backend: str,
                interpret: bool | None, gfmt=None):
-    """The ΔGRU stage of every fused step: the stack's layers in turn
-    over the chunk, layer 1 on ``xs`` (T, B, I), each layer above on the
+    """The ΔGRU stage of the fused step: the stack's layers in turn over
+    the chunk, layer 1 on ``xs`` (T, B, I), each layer above on the
     hidden states of the layer below.  Layer l at frame t reads only
     layer l−1 at frame t, so running each layer over the whole chunk in
     turn is the frame-interleaved order.  ``gru``/``state`` (and, int8,
-    ``gfmt``) are one layer's entry or a tuple per layer; with ``gfmt``
-    the layers run the integer datapath on codes.  Each layer's kernel
-    runs under the named scope ``kws.gru.<l>`` (l = 0 first).
+    ``gfmt``) are one layer's entry or a tuple per layer.  Each layer's
+    kernel runs under the named scope ``kws.gru.<l>`` (l = 0 first).
 
     Returns (the top layer's hidden states, the new state in the form
     of ``state``, one ``LayerCounts`` per layer)."""
@@ -511,15 +677,9 @@ def _gru_stack(gru, state, xs, *, threshold: float, backend: str,
     for layer, (g, st, fmt) in enumerate(zip(grus, states, fmts)):
         in_dim = xs.shape[-1]
         with jax.named_scope(f"kws.gru.{layer}"):
-            if fmt is None:
-                xs, st, stats = dg.delta_gru_scan(
-                    g, xs, threshold=threshold, state=st, backend=backend,
-                    interpret=interpret)
-                nz_dx, nz_dh = stats.nz_dx, stats.nz_dh
-            else:
-                xs, st, nz_dx, nz_dh = fp.int_gru_scan(
-                    g, fmt, xs, threshold, state=st, backend=backend,
-                    interpret=interpret)
+            xs, st, nz_dx, nz_dh = num.scan(g, fmt, xs, threshold, st,
+                                            backend=backend,
+                                            interpret=interpret)
         new.append(st)
         counts.append(LayerCounts(nz_dx, nz_dh, in_dim, g.w_h.shape[0]))
     state = tuple(new) if type(state) in (tuple, list) else new[0]
@@ -529,13 +689,6 @@ def _gru_stack(gru, state, xs, *, threshold: float, backend: str,
 def _transmits(counts) -> Array:
     """(F, B) Δs transmitted per frame and slot, summed over layers."""
     return sum(c.nz_dx + c.nz_dh for c in counts)
-
-
-@jax.named_scope("kws.head")
-def _classify(w_fc, b_fc, hs, nz):
-    logits = f32_dot(hs, w_fc) + b_fc             # (F, B, 12)
-    votes = jnp.argmax(logits, -1).astype(jnp.int32)
-    return ChunkResult(logits=logits, votes=votes, nz=nz)
 
 
 def _bump(acc: _Accum, counts, n_frames: int, n_samples: int,
@@ -576,345 +729,100 @@ def _bump(acc: _Accum, counts, n_frames: int, n_samples: int,
     )
 
 
-def _feats_bad(feats) -> Array:
-    """(F, B, C) frame-major features → per-slot (B,) non-finite flag."""
-    return jnp.any(~jnp.isfinite(feats), axis=(0, 2))
+def _kws_step(*args, num, stages: _Stages, threshold: float, backend: str,
+              interpret: bool | None, gfmt=None, gfmt0=None,
+              fex_backend: str | None = None, frame_shift: int = 0,
+              **fex_kw):
+    """The fused serving step, composed from ``stages`` in one jitted
+    graph over ``num``'s datapath:
 
+        (weights…, slot state…, acc, block) → (slot state…, acc, result,
+                                               health)
 
-def _process_chunk(gru, w_fc, b_fc, state, acc: _Accum, feats, *,
-                   threshold: float, backend: str, interpret: bool | None):
-    """Pure chunk step:
-    (state, acc, feats (F,B,C)) -> (state', acc', out, health)."""
-    in_bad = _feats_bad(feats)
-    hs, state, counts = _gru_stack(gru, state, feats, threshold=threshold,
-                                   backend=backend, interpret=interpret)
-    out = _classify(w_fc, b_fc, hs, _transmits(counts))
-    health = slot_health(in_bad, None, state, None, None)
-    return (state, _bump(acc, counts, feats.shape[0] * feats.shape[1], 0),
-            out, health)
-
-
-@jax.named_scope("kws.head")
-def _classify_int(w_fc, b_fc, hs_codes, nz, logit_frac: int):
-    """FC + argmax on integer codes — the decision is the argmax over
-    int32 logit codes (bit-true); the dequantized logits are returned
-    for the float-typed ChunkResult surface."""
-    codes = fp.int_fc(hs_codes, w_fc, b_fc)           # (F, B, 12) int32
-    votes = jnp.argmax(codes, -1).astype(jnp.int32)
-    return ChunkResult(logits=fp.from_code(codes, logit_frac),
-                       votes=votes, nz=nz)
-
-
-def _logit_frac(gfmt) -> int:
-    """The FC head's logit grid of a (stack of) promoted formats."""
-    return _layers(gfmt)[-1].logit_frac
-
-
-def _process_chunk_int(gru, w_fc, b_fc, state, acc: _Accum, feats, *,
-                       threshold: float, gfmt, backend: str,
-                       interpret: bool | None):
-    """Integer mirror of ``_process_chunk``: feats (F, B, C) floats on the
-    12-bit grid → code domain → int ΔGRU stack → int FC.  ``state``
-    carries integer codes (int16/int32 ``DeltaState``s)."""
-    in_bad = _feats_bad(feats)
-    xs = fp.to_code(feats, _layers(gfmt)[0].feat_frac, 16, jnp.int16)
-    hs, state, counts = _gru_stack(gru, state, xs, threshold=threshold,
-                                   backend=backend, interpret=interpret,
-                                   gfmt=gfmt)
-    out = _classify_int(w_fc, b_fc, hs, _transmits(counts),
-                        _logit_frac(gfmt))
-    health = slot_health(in_bad, None, state, None, None)
-    return (state, _bump(acc, counts, feats.shape[0] * feats.shape[1], 0),
-            out, health)
-
-
-def _process_audio_chunk_int(gru, w_fc, b_fc, coef, fex_state: FExState,
-                             state, acc: _Accum, audio, *, threshold: float,
-                             backend: str, fex_backend: str,
-                             interpret: bool | None, frame_shift: int,
-                             gfmt, ffmt: fp.FexFormats):
-    """Fused INTEGER audio→decision step: 12-bit ADC → int FEx → int ΔGRU
-    stack → int FC in one jitted graph — the deployed datapath, bit-true
-    against the golden fixed-point model.  ``fex_state`` holds int16
-    register codes, ``state`` int16/int32 ΔGRU codes."""
-    in_bad = jnp.any(~jnp.isfinite(audio), axis=1)    # pre-quantizer
-    audio = quantize_audio_12b(audio.astype(jnp.float32))
-    audio_codes = fp.to_code(audio, ffmt.feat_frac, 16, jnp.int16)
-    feats, fex_buf = fp.int_fex_scan(
-        audio_codes, coef, _pack_state(fex_state), ffmt,
-        frame_shift=frame_shift, backend=fex_backend, interpret=interpret)
-    xs = jnp.moveaxis(feats, 1, 0)                    # (F, B, C) codes
-    hs, state, counts = _gru_stack(gru, state, xs, threshold=threshold,
-                                   backend=backend, interpret=interpret,
-                                   gfmt=gfmt)
-    out = _classify_int(w_fc, b_fc, hs, _transmits(counts),
-                        _logit_frac(gfmt))
-    decisions = xs.shape[0] * xs.shape[1]             # frames × streams
-    acc = _bump(acc, counts, decisions, decisions * frame_shift)
-    fex_state = _unpack_state(fex_buf)
-    health = slot_health(in_bad, fex_state, state, None, None)
-    return fex_state, state, acc, out, health
-
-
-def _process_audio_chunk(gru, w_fc, b_fc, coef, fex_state: FExState,
-                         state, acc: _Accum, audio, *, threshold: float,
-                         backend: str, fex_backend: str,
-                         interpret: bool | None, frame_shift: int,
-                         env_alpha: float, log_eps: float):
-    """Fused audio→decision step: FEx → ΔGRU stack → FC in one jitted
-    graph.
-
-    audio: (B, S) raw samples, S a multiple of frame_shift.  Nothing in
-    here leaves the device — only final logits/votes/counters do, when
-    the caller fetches them.
+    ``weights`` are the ``stages.n_weights`` replicated arrays; the slot
+    state is the ``stages.fields`` of a ``SlotState``, slot-major;
+    ``block`` is (B, S) raw audio, S a multiple of ``frame_shift``, or
+    (F, B, C) features.  The stages, in order: the pre-quantizer
+    non-finite flag; the 12-bit ADC; [frame energy]; the FEx
+    (``kws.fex``), or the feature input; [the VAD gate, which holds the
+    features during silence so that Δx = 0]; [stage 0 → the wake
+    machine]; the ΔGRU stack (``kws.gru``), or, under the cascade, the
+    wake-masked stage-1 scan; the head (``kws.head``) → [posterior
+    smoothing and hysteresis events]; telemetry; the per-slot health
+    mask.  Nothing in here leaves the device.
     """
-    in_bad = jnp.any(~jnp.isfinite(audio), axis=1)   # pre-quantizer
-    audio = quantize_audio_12b(audio.astype(jnp.float32))
-    feats, fex_state = fex_scan(
-        audio, coef, fex_state, frame_shift=frame_shift,
-        env_alpha=env_alpha, log_eps=log_eps, compress=True,
-        backend=fex_backend, interpret=interpret)
-    xs = jnp.moveaxis(feats, 1, 0)                # (F, B, C)
-    hs, state, counts = _gru_stack(gru, state, xs, threshold=threshold,
-                                   backend=backend, interpret=interpret)
-    out = _classify(w_fc, b_fc, hs, _transmits(counts))
-    decisions = xs.shape[0] * xs.shape[1]            # frames × streams
-    acc = _bump(acc, counts, decisions, decisions * frame_shift)
-    health = slot_health(in_bad, fex_state, state, None, None)
-    return fex_state, state, acc, out, health
-
-
-def _detect_tail(w_fc, b_fc, hs, nz, gate, *, logit_frac=None,
-                 det_cfg: DetectorConfig, det_state: DetectorState,
-                 awake=None):
-    """Shared back half of the detect steps: FC → posterior smoothing →
-    hysteresis events.  ``logit_frac`` set = integer FC on hidden CODES
-    (the decision head consumes the dequantized — grid-exact — logits).
-    ``awake`` (cascade sessions) masks fires on asleep frames: a frozen
-    stage-1 h keeps emitting its held logits, and a keyword event may
-    not fire while stage 0 says nothing is happening."""
-    if logit_frac is None:
-        cls = _classify(w_fc, b_fc, hs, nz)
+    n_w = stages.n_weights
+    weights, acc, block = args[:n_w], args[-2], args[-1]
+    st = SlotState(**dict(zip(stages.fields, args[n_w:-2])))
+    gru, w_fc, b_fc = weights[:3]
+    scan = dict(backend=backend, interpret=interpret)
+    if stages.audio:
+        in_bad = jnp.any(~jnp.isfinite(block), axis=1)    # pre-quantizer
+        audio = quantize_audio_12b(block.astype(jnp.float32))
+        if stages.vad is not None:
+            energy = frame_energy(audio, frame_shift)     # (F, B), float
+        feats, fex_carried = num.fex(
+            audio, weights[-1], st.fex, frame_shift=frame_shift,
+            backend=fex_backend, interpret=interpret, **fex_kw)
+        xs = jnp.moveaxis(feats, 1, 0)                    # (F, B, C)
     else:
-        cls = _classify_int(w_fc, b_fc, hs, nz, logit_frac)
-    post = jax.nn.softmax(cls.logits, axis=-1)       # (F, B, K)
-    det_state, events = detector_scan(det_cfg, det_state, post)
-    if awake is not None:
-        events = jnp.where(awake, events, jnp.int32(-1))
-    out = DetectResult(logits=cls.logits, votes=cls.votes, nz=cls.nz,
-                       events=events, gate=gate, awake=awake)
-    return det_state, out
-
-
-def _process_audio_chunk_detect(gru, w_fc, b_fc, coef,
-                                fex_state: FExState, state,
-                                vad_state: VADState,
-                                det_state: DetectorState, acc: _Accum,
-                                audio, *, threshold: float, backend: str,
-                                fex_backend: str, interpret: bool | None,
-                                frame_shift: int, env_alpha: float,
-                                log_eps: float, vad_cfg: VADConfig,
-                                det_cfg: DetectorConfig):
-    """Fused always-on DETECTION step: audio → FEx → VAD gate → ΔGRU
-    stack → FC → posterior smoothing/hysteresis, one jitted graph, all
-    state (filters, hold/hangover, x̂/ĥ/M, smoothed posteriors)
-    slot-resident on device.  The VAD clamps the delta path by
-    sample-and-holding the features during silence — Δx = 0 exactly, no
-    kernel change."""
-    in_bad = jnp.any(~jnp.isfinite(audio), axis=1)   # pre-quantizer
-    audio = quantize_audio_12b(audio.astype(jnp.float32))
-    energy = frame_energy(audio, frame_shift)        # (F, B)
-    feats, fex_state = fex_scan(
-        audio, coef, fex_state, frame_shift=frame_shift,
-        env_alpha=env_alpha, log_eps=log_eps, compress=True,
-        backend=fex_backend, interpret=interpret)
-    xs = jnp.moveaxis(feats, 1, 0)                   # (F, B, C)
-    xs, gate, vad_state = vad_gate(xs, energy, vad_state, vad_cfg)
-    hs, state, counts = _gru_stack(gru, state, xs, threshold=threshold,
-                                   backend=backend, interpret=interpret)
-    det_state, out = _detect_tail(w_fc, b_fc, hs, _transmits(counts), gate,
-                                  det_cfg=det_cfg, det_state=det_state)
-    decisions = xs.shape[0] * xs.shape[1]
+        in_bad = jnp.any(~jnp.isfinite(block), axis=(0, 2))
+        xs = num.features(block, gfmt)
+    gate = vad = awake = cascade = None
+    if stages.vad is not None:
+        xs, gate, vad = vad_gate(xs, energy, st.vad, stages.vad)
+    if stages.cascade is None:
+        hs, gru_state, counts = _gru_stack(num, gru, st.gru, xs,
+                                           threshold=threshold, gfmt=gfmt,
+                                           **scan)
+        s0 = ()
+    else:
+        # Stage 0: always on, leading-channel subset, its own Δ_TH and
+        # (int8) its own formats; stage 1 runs only on awake frames.
+        cas, (gru0, w_fc0, b_fc0) = stages.cascade, weights[3:6]
+        xs0 = xs[..., :cas.s0_channels]
+        hs0, s0_state, nz_dx0, nz_dh0 = num.scan(
+            gru0, gfmt0, xs0, cas.s0_threshold, st.cascade.s0, **scan)
+        score = jax.nn.softmax(num.logits(w_fc0, b_fc0, hs0, gfmt0),
+                               axis=-1)[..., 1]
+        awake, latch, hang = cascade_wake_scan(cas, st.cascade.awake,
+                                               st.cascade.hang, score)
+        hs, gru_state, nz_dx, nz_dh = num.masked_scan(
+            gru, gfmt, xs, threshold, st.gru, awake)
+        counts = (LayerCounts(nz_dx, nz_dh, xs.shape[-1], gru.w_h.shape[0]),)
+        s0 = (LayerCounts(nz_dx0, nz_dh0, xs0.shape[-1], gru0.w_h.shape[0]),)
+        cascade = CascadeState(awake=latch, hang=hang, s0=s0_state)
+    out = num.head(w_fc, b_fc, hs, _transmits(counts), gfmt)
+    det = None
+    if stages.det is not None:
+        post = jax.nn.softmax(out.logits, axis=-1)        # (F, B, K)
+        det, events = detector_scan(stages.det, st.det, post)
+        if awake is not None:
+            # A frozen stage-1 h keeps emitting its held logits: no event
+            # may fire while stage 0 says nothing is happening.
+            events = jnp.where(awake, events, jnp.int32(-1))
+        out = DetectResult(*out, events=events, gate=gate, awake=awake)
+    decisions = xs.shape[0] * xs.shape[1]                 # frames × streams
     acc = _bump(acc, counts, decisions, decisions * frame_shift,
-                vad_open=jnp.sum(gate))
-    health = slot_health(in_bad, fex_state, state, vad_state, det_state)
-    return fex_state, state, vad_state, det_state, acc, out, health
-
-
-def _process_audio_chunk_detect_int(gru, w_fc, b_fc, coef,
-                                    fex_state: FExState, state,
-                                    vad_state: VADState,
-                                    det_state: DetectorState, acc: _Accum,
-                                    audio, *, threshold: float,
-                                    backend: str, fex_backend: str,
-                                    interpret: bool | None,
-                                    frame_shift: int, gfmt,
-                                    ffmt: fp.FexFormats,
-                                    vad_cfg: VADConfig,
-                                    det_cfg: DetectorConfig):
-    """Integer mirror of ``_process_audio_chunk_detect``: the VAD holds
-    int16 FEATURE CODES (a held code stream is a zero integer delta,
-    bit-true), the detector smooths posteriors from the dequantized int32
-    logit codes (grid-exact floats, deterministic)."""
-    in_bad = jnp.any(~jnp.isfinite(audio), axis=1)   # pre-quantizer
-    audio = quantize_audio_12b(audio.astype(jnp.float32))
-    energy = frame_energy(audio, frame_shift)        # float — pre-codes
-    audio_codes = fp.to_code(audio, ffmt.feat_frac, 16, jnp.int16)
-    feats, fex_buf = fp.int_fex_scan(
-        audio_codes, coef, _pack_state(fex_state), ffmt,
-        frame_shift=frame_shift, backend=fex_backend, interpret=interpret)
-    xs = jnp.moveaxis(feats, 1, 0)                   # (F, B, C) codes
-    xs, gate, vad_state = vad_gate(xs, energy, vad_state, vad_cfg)
-    hs, state, counts = _gru_stack(gru, state, xs, threshold=threshold,
-                                   backend=backend, interpret=interpret,
-                                   gfmt=gfmt)
-    det_state, out = _detect_tail(w_fc, b_fc, hs, _transmits(counts), gate,
-                                  logit_frac=_logit_frac(gfmt),
-                                  det_cfg=det_cfg, det_state=det_state)
-    decisions = xs.shape[0] * xs.shape[1]
-    acc = _bump(acc, counts, decisions, decisions * frame_shift,
-                vad_open=jnp.sum(gate))
-    fex_state = _unpack_state(fex_buf)
-    health = slot_health(in_bad, fex_state, state, vad_state, det_state)
-    return fex_state, state, vad_state, det_state, acc, out, health
-
-
-def _process_audio_chunk_cascade(gru: dg.DeltaGRUParams, w_fc, b_fc,
-                                 gru0: dg.DeltaGRUParams, w_fc0, b_fc0,
-                                 coef, fex_state: FExState,
-                                 state: dg.DeltaState,
-                                 cas_state: CascadeState,
-                                 vad_state: VADState,
-                                 det_state: DetectorState, acc: _Accum,
-                                 audio, *, threshold: float, backend: str,
-                                 fex_backend: str, interpret: bool | None,
-                                 frame_shift: int, env_alpha: float,
-                                 log_eps: float, vad_cfg: VADConfig,
-                                 det_cfg: DetectorConfig,
-                                 cas_cfg: CascadeConfig):
-    """Fused TWO-STAGE cascade step (DESIGN.md §13): audio → FEx → VAD →
-    always-on stage-0 micro-ΔGRU → wake state machine → wake-gated
-    stage-1 ΔGRU → FC → detection head, one jitted graph.  Stage 0 runs
-    every frame on its reduced channel set; stage 1 executes only on
-    awake frames — asleep slots keep their entire delta state bit-frozen
-    and execute zero stage-1 MACs (``masked_delta_gru_scan``)."""
-    in_bad = jnp.any(~jnp.isfinite(audio), axis=1)   # pre-quantizer
-    audio = quantize_audio_12b(audio.astype(jnp.float32))
-    energy = frame_energy(audio, frame_shift)        # (F, B)
-    feats, fex_state = fex_scan(
-        audio, coef, fex_state, frame_shift=frame_shift,
-        env_alpha=env_alpha, log_eps=log_eps, compress=True,
-        backend=fex_backend, interpret=interpret)
-    xs = jnp.moveaxis(feats, 1, 0)                   # (F, B, C)
-    xs, gate, vad_state = vad_gate(xs, energy, vad_state, vad_cfg)
-    # Stage 0: always on, leading-channel subset, its own Δ_TH.
-    xs0 = xs[..., :cas_cfg.s0_channels]
-    hs0, s0_state, stats0 = dg.delta_gru_scan(
-        gru0, xs0, threshold=cas_cfg.s0_threshold, state=cas_state.s0,
-        backend=backend, interpret=interpret)
-    score = jax.nn.softmax(f32_dot(hs0, w_fc0) + b_fc0, axis=-1)[..., 1]
-    awake_t, awake, hang = cascade_wake_scan(cas_cfg, cas_state.awake,
-                                             cas_state.hang, score)
-    hs, state, stats = dg.masked_delta_gru_scan(gru, xs, threshold, state,
-                                                awake_t)
-    counts = (LayerCounts(stats.nz_dx, stats.nz_dh, xs.shape[-1],
-                          gru.w_h.shape[0]),)
-    det_state, out = _detect_tail(w_fc, b_fc, hs, _transmits(counts), gate,
-                                  det_cfg=det_cfg, det_state=det_state,
-                                  awake=awake_t)
-    decisions = xs.shape[0] * xs.shape[1]
-    acc = _bump(acc, counts, decisions, decisions * frame_shift,
-                vad_open=jnp.sum(gate), awake=jnp.sum(awake_t),
-                s0=(LayerCounts(stats0.nz_dx, stats0.nz_dh, xs0.shape[-1],
-                                gru0.w_h.shape[0]),))
-    health = slot_health(in_bad, fex_state, state, vad_state, det_state)
-    health |= slot_health(jnp.zeros_like(in_bad), None, s0_state,
-                          None, None)
-    cas_state = CascadeState(awake=awake, hang=hang, s0=s0_state)
-    return (fex_state, state, cas_state, vad_state, det_state, acc, out,
-            health)
-
-
-def _process_audio_chunk_cascade_int(gru: fp.IntGruWeights, w_fc, b_fc,
-                                     gru0: fp.IntGruWeights, w_fc0, b_fc0,
-                                     coef, fex_state: FExState,
-                                     state: dg.DeltaState,
-                                     cas_state: CascadeState,
-                                     vad_state: VADState,
-                                     det_state: DetectorState,
-                                     acc: _Accum, audio, *,
-                                     threshold: float, backend: str,
-                                     fex_backend: str,
-                                     interpret: bool | None,
-                                     frame_shift: int, gfmt: fp.GruFormats,
-                                     ffmt: fp.FexFormats,
-                                     gfmt0: fp.GruFormats,
-                                     vad_cfg: VADConfig,
-                                     det_cfg: DetectorConfig,
-                                     cas_cfg: CascadeConfig):
-    """Integer mirror of ``_process_audio_chunk_cascade``: both stages
-    run the deployed code-domain datapath (stage 0 through its OWN
-    promoted formats ``gfmt0`` — its own golden fixed-point path), the
-    wake machine scores dequantized — grid-exact — stage-0 logits, and
-    asleep slots freeze their integer stage-1 state bit-for-bit
-    (``masked_int_gru_scan``)."""
-    in_bad = jnp.any(~jnp.isfinite(audio), axis=1)   # pre-quantizer
-    audio = quantize_audio_12b(audio.astype(jnp.float32))
-    energy = frame_energy(audio, frame_shift)        # float — pre-codes
-    audio_codes = fp.to_code(audio, ffmt.feat_frac, 16, jnp.int16)
-    feats, fex_buf = fp.int_fex_scan(
-        audio_codes, coef, _pack_state(fex_state), ffmt,
-        frame_shift=frame_shift, backend=fex_backend, interpret=interpret)
-    xs = jnp.moveaxis(feats, 1, 0)                   # (F, B, C) codes
-    xs, gate, vad_state = vad_gate(xs, energy, vad_state, vad_cfg)
-    xs0 = xs[..., :cas_cfg.s0_channels]
-    hs0, s0_state, nzx0, nzh0 = fp.int_gru_scan(
-        gru0, gfmt0, xs0, cas_cfg.s0_threshold, state=cas_state.s0,
-        backend=backend, interpret=interpret)
-    logits0 = fp.from_code(fp.int_fc(hs0, w_fc0, b_fc0), gfmt0.logit_frac)
-    score = jax.nn.softmax(logits0, axis=-1)[..., 1]
-    awake_t, awake, hang = cascade_wake_scan(cas_cfg, cas_state.awake,
-                                             cas_state.hang, score)
-    hs, state, nz_dx, nz_dh = fp.masked_int_gru_scan(
-        gru, gfmt, xs, threshold, state, awake_t)
-    counts = (LayerCounts(nz_dx, nz_dh, xs.shape[-1], gru.w_h.shape[0]),)
-    det_state, out = _detect_tail(w_fc, b_fc, hs, _transmits(counts), gate,
-                                  logit_frac=gfmt.logit_frac,
-                                  det_cfg=det_cfg, det_state=det_state,
-                                  awake=awake_t)
-    decisions = xs.shape[0] * xs.shape[1]
-    acc = _bump(acc, counts, decisions, decisions * frame_shift,
-                vad_open=jnp.sum(gate), awake=jnp.sum(awake_t),
-                s0=(LayerCounts(nzx0, nzh0, xs0.shape[-1],
-                                gru0.w_h.shape[0]),))
-    fex_state = _unpack_state(fex_buf)
-    health = slot_health(in_bad, fex_state, state, vad_state, det_state)
-    health |= slot_health(jnp.zeros_like(in_bad), None, s0_state,
-                          None, None)
-    cas_state = CascadeState(awake=awake, hang=hang, s0=s0_state)
-    return (fex_state, state, cas_state, vad_state, det_state, acc, out,
-            health)
-
-
-def _reset_layer(state: dg.DeltaState, bias, mask) -> dg.DeltaState:
-    m = mask[:, None]
-
-    def zero(a):
-        return jnp.where(m, jnp.zeros((), a.dtype), a)
-
-    return dg.DeltaState(
-        h=zero(state.h), x_hat=zero(state.x_hat), h_hat=zero(state.h_hat),
-        m_x=jnp.where(m, bias.astype(state.m_x.dtype), state.m_x),
-        m_h=zero(state.m_h))
+                vad_open=None if gate is None else jnp.sum(gate),
+                awake=None if awake is None else jnp.sum(awake), s0=s0)
+    slots = SlotState(
+        fex=num.fex_state(fex_carried) if stages.audio else None,
+        gru=gru_state, cascade=cascade, vad=vad, det=det)
+    health = slot_health(in_bad, slots)
+    if cascade is not None:
+        health |= slot_health(jnp.zeros_like(in_bad),
+                              SlotState(gru=cascade.s0))
+    return (*(getattr(slots, f) for f in stages.fields), acc, out, health)
 
 
 @jax.jit
-def _reset_gru_slots(state, bias, mask):
+def _reset_slots(slots, fresh, mask):
     """Fresh-stream state for every slot where ``mask`` is True, in every
-    layer: ``state``/``bias`` are one layer's ``DeltaState`` and bias, or
-    a tuple of each per layer of a stack.
+    stage: ``fresh`` is one fresh slot's ``SlotState`` ((1, …) leaves),
+    selected into the masked rows.
 
-    Mask-select instead of per-slot dynamic updates: ONE compiled
+    One mask-select instead of per-slot dynamic updates: ONE compiled
     elementwise op resets an arbitrary admission wave (continuous
     batching can churn every slot of a shard in one serve step — a
     dispatch per slot would cost more than the chunk step itself).
@@ -922,54 +830,11 @@ def _reset_gru_slots(state, bias, mask):
     elementwise along the slot axis, so each shard rewrites only its own
     rows — no collectives, no reshard, no stall for other shards.
     """
-    if type(state) in (tuple, list):
-        return tuple(_reset_layer(st, b, mask)
-                     for st, b in zip(state, bias))
-    return _reset_layer(state, bias, mask)
+    def select(now, new):
+        return jnp.where(mask.reshape(mask.shape + (1,) * (now.ndim - 1)),
+                         new, now)
 
-
-@jax.jit
-def _reset_fex_slots(state: FExState, mask) -> FExState:
-    """Quiescent filters for every slot where ``mask`` is True (see above).
-    Dtype-preserving: serves both the float state and the int8 path's
-    int16 register codes."""
-    return FExState(
-        filt=jnp.where(mask[:, None, None],
-                       jnp.zeros((), state.filt.dtype), state.filt),
-        env=jnp.where(mask[:, None],
-                      jnp.zeros((), state.env.dtype), state.env))
-
-
-@jax.jit
-def _reset_vad_slots(state: VADState, mask) -> VADState:
-    """Fresh-stream VAD state for masked slots (see _reset_gru_slots):
-    zero hold (matches x̂ = 0), no hangover.  Dtype-preserving (int16
-    code hold in the int8 engine)."""
-    return VADState(
-        hold=jnp.where(mask[:, None], jnp.zeros((), state.hold.dtype),
-                       state.hold),
-        hang=jnp.where(mask, jnp.int32(0), state.hang))
-
-
-@jax.jit
-def _reset_det_slots(state: DetectorState, mask) -> DetectorState:
-    """Idle detector for masked slots: zero smoothed posteriors, no open
-    event, no refractory — bit-identical to a fresh stream's head."""
-    return DetectorState(
-        smooth=jnp.where(mask[:, None], 0.0, state.smooth),
-        active=jnp.where(mask, jnp.int32(-1), state.active),
-        refract=jnp.where(mask, jnp.int32(0), state.refract))
-
-
-@jax.jit
-def _reset_cascade_slots(state: CascadeState, bias0, mask) -> CascadeState:
-    """Fresh cascade state for masked slots (see _reset_gru_slots):
-    asleep, no hangover, stage-0 delta state zeroed with its M seeded at
-    the stage-0 bias — bit-identical to a fresh stream's cascade."""
-    return CascadeState(
-        awake=jnp.where(mask, False, state.awake),
-        hang=jnp.where(mask, jnp.int32(0), state.hang),
-        s0=_reset_gru_slots(state.s0, bias0, mask))
+    return jax.tree.map(select, slots, fresh)
 
 
 class StreamingKwsSession:
@@ -1074,7 +939,7 @@ class StreamingKwsSession:
                  stage0_params=None,
                  supervisor: SupervisorConfig | None = None,
                  input_policy: str = "reject"):
-        if numerics not in ("float32", "int8"):
+        if numerics not in _NUMERICS:
             raise ValueError(f"unknown numerics: {numerics!r}")
         if input_policy not in ("reject", "sanitize", "trust"):
             raise ValueError(f"unknown input_policy: {input_policy!r} "
@@ -1121,18 +986,14 @@ class StreamingKwsSession:
                           else threshold)
         self._fex = (FeatureExtractor(fex) if isinstance(fex, FExConfig)
                      else fex)
-        self._bundle = bundle
-        if numerics == "int8":
-            if bundle is None:
-                self._bundle = fp.promote_kws(params, self.threshold,
-                                              fex=self._fex)
+        self._num = _NUMERICS[numerics]
+        if backend not in self._num.backends:
+            raise ValueError(f"unknown ΔGRU backend: {backend!r}")
+        (self._gru, self._w_fc, self._b_fc), self._bundle = self._num.load(
+            params, self.threshold, bundle=bundle, fex=self._fex,
+            quantize_8b=quantize_8b, mesh=mesh)
+        if self._bundle is not None:
             self.threshold = self._bundle.threshold
-            self._gru = shp.put_replicated(self._bundle.gru, mesh)
-            self._w_fc, self._b_fc = shp.put_replicated(
-                (self._bundle.w_fc, self._bundle.b_fc), mesh)
-        else:
-            self._gru, self._w_fc, self._b_fc = kws.serving_weights(
-                params, quantize_8b, mesh)
         self.n_layers = len(_layers(self._gru))
         if cascade is not None and self.n_layers > 1:
             raise ValueError(
@@ -1143,27 +1004,23 @@ class StreamingKwsSession:
         # 2-class stage-0) head serves through the same session code.
         self.n_classes = int(self._b_fc.shape[-1])
         self._cascade = cascade
-        self._bundle0 = None
+        # The fused step's replicated arguments (the FEx bank joins them
+        # in the audio step) and the stages it runs.
+        self._weights = (self._gru, self._w_fc, self._b_fc)
+        self._gfmt0 = None
         if cascade is not None:
-            if numerics == "int8":
-                # Stage 0 gets its OWN promotion: per-tensor exponents
-                # from its own trained dynamic range (gfmt0 ≠ gfmt).
-                self._bundle0 = fp.promote_kws(stage0_params,
-                                               cascade.s0_threshold)
-                self._gru0 = shp.put_replicated(self._bundle0.gru, mesh)
-                self._w_fc0, self._b_fc0 = shp.put_replicated(
-                    (self._bundle0.w_fc, self._bundle0.b_fc), mesh)
-            else:
-                self._gru0, self._w_fc0, self._b_fc0 = \
-                    kws.serving_weights(stage0_params, quantize_8b, mesh)
-            self._s0_hidden = int(self._gru0.w_h.shape[0])
-            self._s0_classes = int(self._b_fc0.shape[-1])
-        self._state: dg.DeltaState | None = None
-        self._cas_state: CascadeState | None = None
+            # Stage 0 is loaded on its own: int8 promotes it from its own
+            # trained dynamic range (gfmt0 ≠ gfmt).
+            stage0, bundle0 = self._num.load(
+                stage0_params, cascade.s0_threshold, bundle=None, fex=None,
+                quantize_8b=quantize_8b, mesh=mesh)
+            self._weights += stage0
+            self._gfmt0 = bundle0.gfmt if bundle0 is not None else None
+        self._stages = _Stages(audio=True, vad=self._vad, det=detector,
+                               cascade=cascade)
+        self._slots: SlotState | None = None        # every stage's state
+        self._fresh_row: SlotState | None = None    # one fresh slot's
         self._coef = None                           # replicated FEx coeffs
-        self._fex_state: FExState | None = None
-        self._vad_state: VADState | None = None
-        self._det_state: DetectorState | None = None
         self._audio_rem: np.ndarray | None = None   # carried tail samples
         self.frame_joins = 0                # steps that joined a tail
         self._acc = shp.put_slot_sharded(
@@ -1197,82 +1054,25 @@ class StreamingKwsSession:
             self._init_state(input_dim)
 
     def _make_step_fns(self, threshold: float):
-        """Build (jitted feature step, audio-step partial) for one Δ_TH.
-
-        _process_chunk(gru, w_fc, b_fc, state, acc, feats): state/acc are
-        slot-major, feats is time-major with slots on axis 1.  The int8
-        step has the same argument geometry, so the shard wrapper is
-        numerics-agnostic.
-        """
-        det_kw = ({"vad_cfg": self._vad, "det_cfg": self._detector}
-                  if self._detector is not None else {})
-        if self._cascade is not None:
-            det_kw["cas_cfg"] = self._cascade
-        if self.numerics == "int8":
-            if self._backend not in ("pallas", "xla"):
-                raise ValueError(f"unknown ΔGRU backend: {self._backend!r}")
-            step_fn = functools.partial(
-                _process_chunk_int, threshold=threshold,
-                gfmt=self._bundle.gfmt, backend=self._backend,
-                interpret=self._interpret)
-            if self._cascade is not None:
-                audio_fn = _process_audio_chunk_cascade_int
-                det_kw["gfmt0"] = self._bundle0.gfmt
-            else:
-                audio_fn = (_process_audio_chunk_detect_int
-                            if self._detector is not None
-                            else _process_audio_chunk_int)
-            audio_step_fn = functools.partial(
-                audio_fn, threshold=threshold,
-                backend=self._backend, fex_backend=self._fex_backend,
-                interpret=self._interpret, gfmt=self._bundle.gfmt, **det_kw)
-        else:
-            step_fn = functools.partial(
-                _process_chunk, threshold=threshold,
-                backend=self._backend, interpret=self._interpret)
-            if self._cascade is not None:
-                audio_fn = _process_audio_chunk_cascade
-            else:
-                audio_fn = (_process_audio_chunk_detect
-                            if self._detector is not None
-                            else _process_audio_chunk)
-            audio_step_fn = functools.partial(
-                audio_fn, threshold=threshold,
-                backend=self._backend, fex_backend=self._fex_backend,
-                interpret=self._interpret, **det_kw)
+        """Build (jitted feature step, audio-step partial) for one Δ_TH:
+        the one composed step (``_kws_step``) over ``process_chunk``'s
+        stages and over the session's audio stages."""
+        kw = dict(num=self._num, threshold=threshold, backend=self._backend,
+                  interpret=self._interpret,
+                  gfmt=self._bundle.gfmt if self._bundle is not None else None)
         step = jax.jit(self._shard(
-            step_fn, n_args=6, slot_major=(3, 4), time_major=(5,),
-            n_state_out=2))
-        return step, audio_step_fn
+            functools.partial(_kws_step, stages=_FEATURES, **kw), _FEATURES))
+        return step, functools.partial(
+            _kws_step, stages=self._stages, fex_backend=self._fex_backend,
+            gfmt0=self._gfmt0, **kw)
 
     def _build_audio_step(self, audio_step_fn):
         """Jit + shard the fused audio step once the FEx kwargs are known.
 
-        Every variant is jitted under one stable name, so the device
-        trace shows the step as the module ``jit_kws_audio_step``."""
-        fn = functools.partial(audio_step_fn, **self._fex_kw)
-        if self._cascade is not None:
-            # _process_audio_chunk_cascade[_int](gru, w_fc, b_fc, gru0,
-            # w_fc0, b_fc0, coef, fex_state, state, cas_state, vad_state,
-            # det_state, acc, audio): five state trees + acc + audio are
-            # slot-major; both stages' weights are replicated.
-            sharded = self._shard(
-                fn, n_args=14, slot_major=(7, 8, 9, 10, 11, 12, 13),
-                time_major=(), n_state_out=6)
-        elif self._detector is not None:
-            # _process_audio_chunk_detect[_int](gru, w_fc, b_fc, coef,
-            # fex_state, state, vad_state, det_state, acc, audio):
-            # the four state trees + acc + audio are slot-major.
-            sharded = self._shard(
-                fn, n_args=10, slot_major=(4, 5, 6, 7, 8, 9),
-                time_major=(), n_state_out=5)
-        else:
-            # _process_audio_chunk[_int](gru, w_fc, b_fc, coef,
-            # fex_state, state, acc, audio): fex_state/state/acc/audio
-            # are slot-major.
-            sharded = self._shard(
-                fn, n_args=8, slot_major=(4, 5, 6, 7), time_major=(),
-                n_state_out=3)
+        Jitted under one stable name, so the device trace shows the step
+        as the module ``jit_kws_audio_step``."""
+        sharded = self._shard(functools.partial(audio_step_fn, **self._fex_kw),
+                              self._stages)
 
         def kws_audio_step(*args):
             return sharded(*args)
@@ -1306,22 +1106,18 @@ class StreamingKwsSession:
                                    self._interpret) if enabled else None)
             return {"shape": list(shape), "config": cfg or {}}
 
+        num = self._num
         H = int(_layers(self._gru)[0].w_h.shape[0])
-        gru_kernel = ("delta_gru_seq_int" if self.numerics == "int8"
-                      else "delta_gru_seq")
-        gru_dtype = "int8" if self.numerics == "int8" else "float32"
         if self._input_dim is not None:
-            report["kernels"][gru_kernel] = entry(
-                gru_kernel, (b_shard, int(self._input_dim), H), gru_dtype,
-                self.threshold)
+            report["kernels"][num.gru_kernel] = entry(
+                num.gru_kernel, (b_shard, int(self._input_dim), H),
+                num.gru_dtype, self.threshold)
         if self._fex is not None:
             fcfg = self._fex.cfg
-            is_int = self.numerics == "int8"
-            fex_kernel = "batched_iir_fex_int" if is_int else "batched_iir_fex"
-            report["kernels"][fex_kernel] = entry(
-                fex_kernel,
+            report["kernels"][num.fex_kernel] = entry(
+                num.fex_kernel,
                 (b_shard, int(fcfg.n_active), int(fcfg.frame_shift)),
-                "int16" if is_int else "float32", 0.0)
+                num.fex_dtype, 0.0)
         return report
 
     def _use_threshold(self, threshold: float):
@@ -1357,98 +1153,76 @@ class StreamingKwsSession:
             return
         self._use_threshold(threshold)
 
-    def _shard(self, fn, *, n_args: int, slot_major: tuple[int, ...],
-               time_major: tuple[int, ...], n_state_out: int):
-        """Wrap a pure chunk step in shard_map over the slot mesh.
-
-        ``slot_major``: positions of per-stream args with the slot axis
-        FIRST (state trees, telemetry, raw audio) → prefix P("data");
-        ``time_major``: frame-major inputs with slots on axis 1 →
-        P(None, "data"); every other arg (weights, coefficients) is
-        replicated.  Outputs follow the fixed (state…, acc, ChunkResult,
-        health) convention: ``n_state_out`` slot-major trees, the
-        time-major ChunkResult, then the slot-major (B,) health mask.
-        No-op without a mesh — the unsharded session is byte-for-byte
-        the pre-sharding code path.
+    def _shard(self, fn, stages: _Stages):
+        """Wrap a fused step over ``stages`` in shard_map over the slot
+        mesh: the weights are replicated, the slot state and telemetry
+        slot-major (prefix P("data")), raw audio slot-major and features
+        frame-major (P(None, "data")).  Outputs: the slot state and
+        telemetry, the frame-major result, the slot-major (B,) health
+        mask.  No-op without a mesh — the unsharded session is
+        byte-for-byte the pre-sharding code path.
         """
         if self.mesh is None:
             return fn
-        specs = [P()] * n_args
-        for i in slot_major:
-            specs[i] = P(shp.SLOT_AXIS)
-        for i in time_major:
-            specs[i] = P(None, shp.SLOT_AXIS)
-        out_specs = tuple([P(shp.SLOT_AXIS)] * n_state_out
-                          + [P(None, shp.SLOT_AXIS), P(shp.SLOT_AXIS)])
-        return shard_map_kernels(fn, self.mesh, in_specs=tuple(specs),
-                                 out_specs=out_specs)
+        slot, n = P(shp.SLOT_AXIS), len(stages.fields) + 1
+        frames = P(None, shp.SLOT_AXIS)
+        in_specs = ((P(),) * stages.n_weights + (slot,) * n
+                    + ((slot if stages.audio else frames),))
+        return shard_map_kernels(fn, self.mesh, in_specs=in_specs,
+                                 out_specs=(slot,) * n + (frames, slot))
+
+    def _fresh_slots(self, batch: int) -> SlotState:
+        """Fresh-stream state of every stage the session's step carries,
+        for ``batch`` slots: the ΔGRU (one ``DeltaState``, or one per
+        layer of a stack: layer 1 reads the input features, each layer
+        above the hidden state below) and, once an FEx is attached, the
+        FEx, the cascade (everyone asleep, stage 0 fresh), the VAD hold
+        and the detector head."""
+        num, C, H = self._num, self._input_dim, self.cfg.d_model
+
+        def gru():
+            layers = tuple(num.init_gru(batch, C if layer == 0 else H, H, g)
+                           for layer, g in enumerate(_layers(self._gru)))
+            return layers if self.n_layers > 1 else layers[0]
+
+        def cascade():
+            g0 = self._weights[3]
+            return CascadeState(
+                awake=jnp.zeros((batch,), bool),
+                hang=jnp.zeros((batch,), jnp.int32),
+                s0=num.init_gru(batch, g0.w_x.shape[0], g0.w_h.shape[0], g0))
+
+        fresh = dict(
+            fex=lambda: num.fresh_fex(batch, C), gru=gru, cascade=cascade,
+            vad=lambda: init_vad_state(batch, C, num.hold_dtype),
+            det=lambda: init_detector_state(batch, self.n_classes))
+        stages = self._stages if self._coef is not None else _FEATURES
+        return SlotState(**{f: fresh[f]() for f in stages.fields})
 
     def _init_state(self, input_dim: int):
-        """Fresh ΔGRU state: one ``DeltaState``, or a tuple of one per
-        layer of a stack (layer 1 reads ``input_dim`` features, each
-        layer above the ``d_model`` hidden state below)."""
+        """Fresh state of every stage, and the one fresh slot that
+        ``reset_streams`` selects into reset rows."""
         self._input_dim = input_dim
-        H = self.cfg.d_model
-        init = (fp.init_int_delta_state if self.numerics == "int8"
-                else dg.init_delta_state)
-        grus = _layers(self._bundle.gru if self.numerics == "int8"
-                       else self._gru)
-        states = tuple(init(self.batch, input_dim if layer == 0 else H, H,
-                            g) for layer, g in enumerate(grus))
-        state = states[0] if self.n_layers == 1 else states
-        self._state = shp.put_slot_sharded(state, self.mesh)
-
-    def _fresh_fex_state(self, n_channels: int) -> FExState:
-        if self.numerics == "int8":
-            return _unpack_state(
-                fp.init_int_fex_state(self.batch, n_channels))
-        return init_fex_state(self.batch, n_channels)
+        self._slots = shp.put_slot_sharded(self._fresh_slots(self.batch),
+                                           self.mesh)
+        self._fresh_row = shp.put_replicated(self._fresh_slots(1), self.mesh)
 
     def _require_fex(self) -> FeatureExtractor:
         if self._fex is None:
             self._fex = FeatureExtractor()
         fcfg = self._fex.cfg
-        if self._input_dim is None:
-            self._init_state(fcfg.n_active)
-        elif self._input_dim != fcfg.n_active:
+        if self._input_dim not in (None, fcfg.n_active):
             raise ValueError(f"FEx emits {fcfg.n_active} channels, session "
                              f"state is {self._input_dim}-wide")
-        if self._fex_state is None:
-            if self.numerics == "int8":
-                # Fold the FEx coefficient bank into the bundle if the
-                # promotion happened without one (feature-mode bundles).
-                # fold_fex copies — a caller-shared bundle is untouched.
-                self._bundle = fp.fold_fex(self._bundle, self._fex)
-                self._coef = shp.put_replicated(self._bundle.coef,
-                                                self.mesh)
-                self._fex_kw = {"frame_shift": fcfg.frame_shift,
-                                "ffmt": self._bundle.ffmt}
-            else:
-                self._coef = shp.put_replicated(self._fex.coef, self.mesh)
-                self._fex_kw = {"frame_shift": fcfg.frame_shift,
-                                "env_alpha": fcfg.env_alpha,
-                                "log_eps": fcfg.log_eps}
-            self._fex_state = shp.put_slot_sharded(
-                self._fresh_fex_state(fcfg.n_active), self.mesh)
+        if self._coef is None:
+            self._bundle, self._coef, fex_kw = self._num.attach_fex(
+                self._bundle, self._fex, self.mesh)
+            self._fex_kw = {"frame_shift": fcfg.frame_shift, **fex_kw}
             self._audio_rem = np.zeros((self.batch, 0), np.float32)
-            if self._detector is not None:
-                # VAD holds what the ΔGRU eats: float features on the
-                # float path, int16 feature CODES in the int8 engine.
-                hold_dtype = (jnp.int16 if self.numerics == "int8"
-                              else jnp.float32)
-                self._vad_state = shp.put_slot_sharded(
-                    init_vad_state(self.batch, fcfg.n_active, hold_dtype),
-                    self.mesh)
-                self._det_state = shp.put_slot_sharded(
-                    init_detector_state(self.batch, self.n_classes),
-                    self.mesh)
-            if self._cascade is not None:
-                s0_gru = (self._bundle0.gru if self.numerics == "int8"
-                          else self._gru0)
-                self._cas_state = shp.put_slot_sharded(
-                    init_cascade_state(self.batch, s0_gru,
-                                       int8=self.numerics == "int8"),
-                    self.mesh)
+            gru = self.state
+            self._init_state(fcfg.n_active)
+            if gru is not None:     # the ΔGRU keeps what it has carried
+                self._slots = self._slots._replace(gru=gru)
             # Re-enter the cache now that the FEx kwargs are known —
             # this builds (and caches) the fused audio step.
             self._use_threshold(self.threshold)
@@ -1544,47 +1318,37 @@ class StreamingKwsSession:
             block = audio[:, :cut]
         if n_frames == 0:
             z = jnp.zeros((0, self.batch), jnp.int32)
-            logits = jnp.zeros((0, self.batch, self.n_classes))
-            if self._cascade is not None:
-                return DetectResult(logits=logits, votes=z, nz=z, events=z,
-                                    gate=jnp.zeros((0, self.batch), bool),
-                                    awake=jnp.zeros((0, self.batch), bool))
-            if self._detector is not None:
-                return DetectResult(logits=logits, votes=z, nz=z, events=z,
-                                    gate=jnp.zeros((0, self.batch), bool))
-            return ChunkResult(logits=logits, votes=z, nz=z)
+            out = ChunkResult(jnp.zeros((0, self.batch, self.n_classes)), z, z)
+            if self._detector is None:
+                return out
+            no = jnp.zeros((0, self.batch), bool)
+            return DetectResult(*out, events=z, gate=no,
+                                awake=no if self._cascade else None)
         with spans.span(spans.H2D):
             block = jnp.asarray(block)
         with spans.span(spans.LAUNCH):
-            outs = self._audio_step(*self._audio_step_args(block))
-            if self._cascade is not None:
-                (self._fex_state, self._state, self._cas_state,
-                 self._vad_state, self._det_state, self._acc, out,
-                 health) = outs
-            elif self._detector is not None:
-                (self._fex_state, self._state, self._vad_state,
-                 self._det_state, self._acc, out, health) = outs
-            else:
-                self._fex_state, self._state, self._acc, out, health = outs
-            self._last_health = health
-            self._chunks += 1
+            out = self._carry(self._stages,
+                              self._audio_step(*self._audio_step_args(block)))
         self._maybe_heal()
         return out
 
     def _audio_step_args(self, block):
-        """The fused audio step's arguments for one (batch, S) block —
-        the positional layouts ``_build_audio_step`` documents."""
-        if self._cascade is not None:
-            return (self._gru, self._w_fc, self._b_fc,
-                    self._gru0, self._w_fc0, self._b_fc0, self._coef,
-                    self._fex_state, self._state, self._cas_state,
-                    self._vad_state, self._det_state, self._acc, block)
-        if self._detector is not None:
-            return (self._gru, self._w_fc, self._b_fc, self._coef,
-                    self._fex_state, self._state, self._vad_state,
-                    self._det_state, self._acc, block)
-        return (self._gru, self._w_fc, self._b_fc, self._coef,
-                self._fex_state, self._state, self._acc, block)
+        """The fused audio step's arguments for one (batch, S) block:
+        the replicated weights and FEx bank, the slot state's present
+        fields, the telemetry, the block."""
+        return (*self._weights, self._coef,
+                *(getattr(self._slots, f) for f in self._stages.fields),
+                self._acc, block)
+
+    def _carry(self, stages: _Stages, outs):
+        """Keep a fused step's slot state, telemetry and health mask;
+        return its result."""
+        n = len(stages.fields)
+        self._slots = self._slots._replace(**dict(zip(stages.fields,
+                                                      outs[:n])))
+        self._acc, out, self._last_health = outs[n:]
+        self._chunks += 1
+        return out
 
     def fused_audio_step(self, n_samples: int):
         """``(step, args)``: the jitted fused audio step and the argument
@@ -1623,15 +1387,13 @@ class StreamingKwsSession:
         if feats.shape[1] != self.batch:
             raise ValueError(f"chunk carries {feats.shape[1]} streams, "
                              f"session was created with batch={self.batch}")
-        if self._state is None:
+        if self._slots is None:
             self._init_state(feats.shape[-1])
         elif feats.shape[-1] != self._input_dim:
             raise ValueError(f"chunk has {feats.shape[-1]} feature channels,"
                              f" session state is {self._input_dim}-wide")
-        self._state, self._acc, out, health = self._step(
-            self._gru, self._w_fc, self._b_fc, self._state, self._acc, feats)
-        self._last_health = health
-        self._chunks += 1
+        out = self._carry(_FEATURES, self._step(
+            *self._weights, self._slots.gru, self._acc, feats))
         self._maybe_heal()
         return out
 
@@ -1639,33 +1401,18 @@ class StreamingKwsSession:
     def state(self):
         """The slots' ΔGRU state: one ``DeltaState``, or for a stack a
         tuple of one per layer, the first layer's first."""
-        return self._state
+        return self._slots.gru if self._slots is not None else None
 
     @property
     def fex_state(self) -> FExState | None:
-        return self._fex_state
+        return self._slots.fex if self._slots is not None else None
 
     def reset(self):
         """Forget stream state + telemetry (keeps weights/compiled step)."""
         if self._input_dim is not None:
             self._init_state(self._input_dim)
-        if self._fex_state is not None:
-            self._fex_state = shp.put_slot_sharded(
-                self._fresh_fex_state(self._input_dim), self.mesh)
+        if self._coef is not None:
             self._audio_rem = np.zeros((self.batch, 0), np.float32)
-        if self._vad_state is not None:
-            self._vad_state = shp.put_slot_sharded(
-                init_vad_state(self.batch, self._input_dim,
-                               self._vad_state.hold.dtype), self.mesh)
-        if self._det_state is not None:
-            self._det_state = shp.put_slot_sharded(
-                init_detector_state(self.batch, self.n_classes), self.mesh)
-        if self._cas_state is not None:
-            s0_gru = (self._bundle0.gru if self.numerics == "int8"
-                      else self._gru0)
-            self._cas_state = shp.put_slot_sharded(
-                init_cascade_state(self.batch, s0_gru,
-                                   int8=self.numerics == "int8"), self.mesh)
         self._acc = shp.put_slot_sharded(
             _zero_accum(self.n_shards, self.n_layers), self.mesh)
         self._chunks = 0
@@ -1693,7 +1440,7 @@ class StreamingKwsSession:
         rows and churn on one shard never stalls the streams on others.
         Batched on purpose: continuous batching can re-admit dozens of
         slots after one serve step, and a dispatch per slot would
-        dominate the step itself; a wave is two dispatches total.
+        dominate the step itself; a wave is one dispatch.
 
         Caveat: the carried sample remainder's LENGTH is shared across
         streams, so the reset zeroes a slot's buffered samples but
@@ -1709,20 +1456,9 @@ class StreamingKwsSession:
             return
         mask = np.zeros((self.batch,), bool)
         mask[slots] = True
-        mask = jnp.asarray(mask)
-        if self._state is not None:
-            bias = tuple(g.b for g in _layers(self._gru))
-            self._state = _reset_gru_slots(
-                self._state, bias if self.n_layers > 1 else bias[0], mask)
-        if self._fex_state is not None:
-            self._fex_state = _reset_fex_slots(self._fex_state, mask)
-        if self._vad_state is not None:
-            self._vad_state = _reset_vad_slots(self._vad_state, mask)
-        if self._det_state is not None:
-            self._det_state = _reset_det_slots(self._det_state, mask)
-        if self._cas_state is not None:
-            self._cas_state = _reset_cascade_slots(self._cas_state,
-                                                   self._gru0.b, mask)
+        if self._slots is not None:
+            self._slots = _reset_slots(self._slots, self._fresh_row,
+                                       jnp.asarray(mask))
         if self._audio_rem is not None and self._audio_rem.shape[1]:
             self._audio_rem[slots] = 0.0
         self._strikes[slots] = 0          # a reset slot restarts clean
@@ -1888,9 +1624,11 @@ class StreamingKwsSession:
             # counts only awake stage-1 frames (the masked scan zeroes
             # asleep stats), so macs_pf is the executed average.
             duty = totals["awake"] / frames
+            gru0, _, b_fc0 = self._weights[3:6]
             cc = cascade_frame_cost(
                 totals["s0_macs"] / frames, macs_pf, duty,
-                s0_hidden=self._s0_hidden, s0_classes=self._s0_classes,
+                s0_hidden=int(gru0.w_h.shape[0]),
+                s0_classes=int(b_fc0.shape[-1]),
                 s1_hidden=int(self._gru.w_h.shape[0]),
                 s1_classes=self.n_classes, n_channels=n_ch)
             energy_nj, latency_ms = cc.energy_nj_per_decision, cc.latency_ms

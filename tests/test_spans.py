@@ -9,7 +9,7 @@
   clock reads per step do not grow with the slot count.
 * The fused step lowers as the module ``jit_kws_audio_step`` with the
   ``kws.fex`` / ``kws.gru`` / ``kws.head`` scopes, with and without a
-  slot mesh.
+  slot mesh, with and without the detection head.
 """
 import os
 import pathlib
@@ -273,10 +273,11 @@ def test_no_span_or_clock_read_per_slot(monkeypatch, loop):
 # ---------------------------------------------------------------------------
 # The fused step's name and scopes on the device
 
-def _lowered_text(mesh_devices: int) -> str:
+def _lowered_text(mesh_devices: int, mode: str) -> str:
     """The fused int8 step's lowered module text, with debug locations,
     in a child process with two virtual CPUs and a slot mesh over
-    ``mesh_devices`` of them (0: no mesh)."""
+    ``mesh_devices`` of them (0: no mesh); ``mode`` "detect" adds the
+    VAD gate and detection head."""
     code = f"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
@@ -286,13 +287,15 @@ from repro.frontend import FeatureExtractor
 from repro.launch.mesh import make_slot_mesh
 from repro.launch.streaming import StreamingKwsSession
 from repro.models import kws
+from repro.models.detector import DetectorConfig
 cfg = get_config("deltakws")
 fex = FeatureExtractor()
 params, _ = kws.init_kws(jax.random.PRNGKey(0), cfg,
                          input_dim=fex.cfg.n_active)
 mesh = make_slot_mesh({mesh_devices}) if {mesh_devices} else None
+kw = dict(detector=DetectorConfig()) if {mode!r} == "detect" else {{}}
 sess = StreamingKwsSession(params, cfg, threshold=0.1, batch=4, fex=fex,
-                           numerics="int8", mesh=mesh)
+                           numerics="int8", mesh=mesh, **kw)
 assert sess.n_shards == max({mesh_devices}, 1)
 step, args = sess.fused_audio_step(256)
 print(step.lower(*args).as_text(debug_info=True))
@@ -305,9 +308,10 @@ print(step.lower(*args).as_text(debug_info=True))
     return r.stdout
 
 
+@pytest.mark.parametrize("mode", ["audio", "detect"])
 @pytest.mark.parametrize("mesh_devices", [0, 2])
-def test_fused_step_is_named_and_scoped(mesh_devices):
-    text = _lowered_text(mesh_devices)
+def test_fused_step_is_named_and_scoped(mesh_devices, mode):
+    text = _lowered_text(mesh_devices, mode)
     assert re.search(r"^module @jit_kws_audio_step ", text, re.M)
     scopes = set(re.findall(r"kws\.(?:fex|gru|head)", text))
     assert scopes == {"kws.fex", "kws.gru", "kws.head"}
