@@ -34,7 +34,8 @@ storms, fault plans and mesh>1.
 
 SLO telemetry: the engine tracks p50/p99/p99.9 step and end-to-end
 decision latency (assemble start → results host-visible), the
-scheduler's shard-occupancy imbalance, and where a step's host time
+scheduler's shard-occupancy imbalance and admission waves
+(``admission``: waves, slots, mean wave), and where a step's host time
 goes.  Every step is a ``kws.step`` span; the phases inside it are the
 program's host spans (``launch/spans.py`` lists them and where each is
 entered), timed into the engine's span recorder.  Everything runs on an
@@ -144,8 +145,10 @@ class PipelinedEngine:
     ``field`` names the result attribute fetched per piece ("votes" for
     the utterance loop, "events" for detect/cascade).  ``scheduler``
     (optional) is sampled at every ``end()`` for shard-occupancy
-    imbalance.  ``clock`` is injectable for fake-clock telemetry tests;
-    the span recorder (``spans``) times on it too.
+    imbalance, and its admission waves since the engine's start (or
+    ``reset_telemetry``) are reported.  ``clock`` is injectable for
+    fake-clock telemetry tests; the span recorder (``spans``) times on
+    it too.
     """
 
     def __init__(self, session, *, depth: int = 2, field: str = "votes",
@@ -168,6 +171,7 @@ class PipelinedEngine:
         self._step_s: list[float] = []
         self._e2e_s: list[float] = []
         self._imbalance: list[int] = []
+        self._admitted_before = self._admission_counts()
         self.decisions = 0
         self._ring: list[np.ndarray | None] = [None] * depth
         self.staging_allocated = 0          # ring blocks allocated
@@ -277,10 +281,17 @@ class PipelinedEngine:
         self._step_s = []
         self._e2e_s = []
         self._imbalance = []
+        self._admitted_before = self._admission_counts()
         self.decisions = 0
         self._t_begin = None
         self._t_first = None
         self._t_last_end = None
+
+    def _admission_counts(self) -> tuple[int, int]:
+        """The scheduler's (waves, slots admitted) so far."""
+        if self._sched is None:
+            return (0, 0)
+        return (self._sched.waves, self._sched.admitted)
 
     @property
     def last_step_s(self) -> float:
@@ -300,7 +311,7 @@ class PipelinedEngine:
         host[spans.UNTRACED] = (steady_s * 1e3 / n
                                 - sum(host[p] for p in spans.PHASES))
         dispatch = sum(host[p] for p in spans.DISPATCH)
-        return {
+        rep = {
             "depth": self.depth,
             "steps": steps,
             "decisions": self.decisions,
@@ -323,6 +334,14 @@ class PipelinedEngine:
             # the session's count since its last reset
             "frame_joins": getattr(self.session, "frame_joins", 0),
         }
+        if self._sched is not None:
+            (w1, s1), (w0, s0) = (self._admission_counts(),
+                                  self._admitted_before)
+            waves, slots = w1 - w0, s1 - s0
+            rep["admission"] = {"waves": waves, "slots": slots,
+                                "mean_wave": slots / waves if waves
+                                else 0.0}
+        return rep
 
 
 def warm_session(sess, chunk: int) -> float:

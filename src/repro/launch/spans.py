@@ -35,8 +35,9 @@ The spans, where they are entered, and the per-layer benchmark metric
                 (``evict_many``) at dispatch — and a drained step's
                 tally (one ``bincount``) or fire decode
                 (``fires_from_event_block``)              slots_ms_per_step
-  kws.admit     ``SlotScheduler.admit``: the admission loop and its
-                batched ``reset_streams`` wave            admit_ms_per_step
+  kws.admit     ``SlotScheduler.admit``: the whole admission wave
+                computed in one pass from per-shard free lists,
+                and its batched ``reset_streams``         admit_ms_per_step
 
 ``untraced`` in the report (``untraced_ms_per_step``) is the steady-state
 step less the phases: what no span covers.

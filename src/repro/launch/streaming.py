@@ -1448,11 +1448,12 @@ class StreamingKwsSession:
         starts up to ``frame_shift−1`` zero samples early relative to a
         fresh session.  Feed frame-aligned chunks (the serve launcher's
         default) to keep resets exactly fresh."""
-        slots = list(slots)
-        for i in slots:
-            if not (0 <= i < self.batch):
-                raise ValueError(f"stream {i} out of range [0, {self.batch})")
-        if not slots:
+        slots = np.asarray(slots, np.intp)
+        bad = (slots < 0) | (slots >= self.batch)
+        if bad.any():
+            raise ValueError(f"stream {slots[bad.argmax()]} out of range "
+                             f"[0, {self.batch})")
+        if not slots.size:
             return
         mask = np.zeros((self.batch,), bool)
         mask[slots] = True
@@ -1463,7 +1464,7 @@ class StreamingKwsSession:
             self._audio_rem[slots] = 0.0
         self._strikes[slots] = 0          # a reset slot restarts clean
         if self._flagged:
-            self._flagged = self._flagged - set(slots)
+            self._flagged = self._flagged - set(slots.tolist())
 
     # ------------------------------------------------ self-healing --
 
@@ -1687,6 +1688,9 @@ class SlotScheduler:
         for s in range(self.n_slots - 1, -1, -1):    # pop() yields low first
             self._free[session.shard_of_slot(s)].append(s)
         self.live: dict[int, Any] = {}               # slot -> payload
+        self._per_shard = self.n_slots // self.n_shards
+        self.waves = 0              # non-empty admission waves
+        self.admitted = 0           # slots those waves admitted
 
     def __len__(self) -> int:
         return len(self._queue)
@@ -1700,11 +1704,12 @@ class SlotScheduler:
         self._queue.append(payload)
 
     def occupancy(self) -> list[int]:
-        """Live streams per shard (the balance ``admit`` maintains)."""
-        counts = [0] * self.n_shards
-        for slot in self.live:
-            counts[self._sess.shard_of_slot(slot)] += 1
-        return counts
+        """Live streams per shard (the balance ``admit`` maintains).
+
+        Read off the free lists: every slot is either live or on its
+        shard's free list (flagged slots stay on it), which ``admit``,
+        ``evict`` and ``evict_many`` keep true."""
+        return [self._per_shard - len(f) for f in self._free]
 
     def admit(self) -> list[tuple[int, Any]]:
         """Map queued requests onto free slots, least-loaded shard first.
@@ -1712,35 +1717,57 @@ class SlotScheduler:
         Slots the supervisor currently flags as unhealthy
         (``session.flagged_slots()``) are SKIPPED — admitting a fresh
         stream into a quarantine-pending slot would hand it corrupted
-        state; the slot stays on the free list and becomes admittable
-        again once the supervisor heals or clears it.  The whole
-        admission wave is reset to fresh-stream state with ONE batched
-        slot-local reset (see ``reset_streams``).  Returns the
-        (slot, payload) admissions.
+        state; the slot stays on the free list (and counts as free in
+        the shard's load) and becomes admittable again once the
+        supervisor heals or clears it.  The whole admission wave is
+        reset to fresh-stream state with ONE batched slot-local reset
+        (see ``reset_streams``).  Returns the (slot, payload)
+        admissions, in admission order.
+
+        The wave is the one a request-at-a-time greedy loop makes —
+        each request to the least-loaded shard with a healthy free slot
+        (ties to the lowest shard), there to the healthy slot nearest
+        the end of the free list — computed in one pass: shard ``s``
+        starts at load ``per - len(free[s])``, so its j-th pick comes at
+        load ``per - len(free[s]) + j``, and the loop's order is the
+        merge of those runs by (load, shard).
         """
         with spans.span(spans.ADMIT):
             flagged = self._sess.flagged_slots()
-            admitted = []
-            while self._queue:
-                usable = [s for s in range(self.n_shards)
-                          if any(sl not in flagged for sl in self._free[s])]
-                if not usable:
-                    break                 # full, or only unhealthy slots
-                shard = min(usable, key=self._shard_load)
-                free = self._free[shard]  # pop highest-priority healthy
-                idx = next(i for i in range(len(free) - 1, -1, -1)
-                           if free[i] not in flagged)
-                slot = free.pop(idx)
-                payload = self._queue.popleft()
-                self.live[slot] = payload
-                admitted.append((slot, payload))
-            if admitted:
-                self._sess.reset_streams([slot for slot, _ in admitted])
-        return admitted
-
-    def _shard_load(self, shard: int) -> int:
-        per = self.n_slots // self.n_shards
-        return per - len(self._free[shard])
+            # Each shard's healthy free slots in pick order (from the end
+            # of its free list), with their positions in it.
+            free = [np.asarray(f, np.intp) for f in self._free]
+            if flagged:
+                bad = np.fromiter(flagged, np.intp, len(flagged))
+                pos = [np.flatnonzero(~np.isin(f, bad))[::-1] for f in free]
+            else:
+                pos = [np.arange(len(f) - 1, -1, -1) for f in free]
+            runs = [len(p) for p in pos]
+            take = min(len(self._queue), sum(runs))
+            if not take:
+                return []
+            slot = np.concatenate([f[p] for f, p in zip(free, pos)])
+            shard = np.repeat(np.arange(self.n_shards), runs)
+            if self.n_shards > 1:               # merge the runs
+                load = np.concatenate(
+                    [np.arange(k) + self._per_shard - len(f)
+                     for f, k in zip(free, runs)])
+                order = np.lexsort((shard, load))
+                slot, shard = slot[order], shard[order]
+            slot, shard = slot[:take], shard[:take]
+            picked = np.bincount(shard, minlength=self.n_shards).tolist()
+            for f, p, n in zip(self._free, pos, picked):
+                if n:       # its last n healthy go; flagged ones stay
+                    cut = int(p[n - 1])
+                    f[cut:] = [s for s in f[cut:] if s in flagged] \
+                        if flagged else []
+            slots = slot.tolist()
+            payloads = [self._queue.popleft() for _ in range(take)]
+            self.live.update(zip(slots, payloads))
+            self.waves += 1
+            self.admitted += take
+            self._sess.reset_streams(slot)
+        return list(zip(slots, payloads))
 
     def evict(self, slot: int) -> Any:
         """Free a finished stream's slot; returns its payload.

@@ -860,6 +860,144 @@ def test_admit_order_unchanged_when_nothing_flagged():
                                                   (2, 2), (3, 3)]
 
 
+def test_reset_streams_names_the_first_slot_out_of_range():
+    sess, _ = _sched()
+    with pytest.raises(ValueError, match=r"stream 7 out of range \[0, 4\)"):
+        sess.reset_streams(np.array([1, 7, -1]))
+    with pytest.raises(ValueError, match=r"stream -1 out of range"):
+        sess.reset_streams([-1, 9])
+    sess.reset_streams([])                  # an empty wave is no reset
+
+
+def _admit_one_request_at_a_time(sched):
+    """``SlotScheduler.admit`` as the per-request greedy loop it was: each
+    request to the least-loaded shard with a healthy free slot (ties to
+    the lowest shard), there to the healthy entry nearest the end of the
+    free list; then one reset of the wave."""
+    flagged = sched._sess.flagged_slots()
+    per = sched.n_slots // sched.n_shards
+    admitted = []
+    while sched._queue:
+        usable = [s for s in range(sched.n_shards)
+                  if any(sl not in flagged for sl in sched._free[s])]
+        if not usable:
+            break
+        shard = min(usable, key=lambda s: per - len(sched._free[s]))
+        free = sched._free[shard]
+        idx = next(i for i in range(len(free) - 1, -1, -1)
+                   if free[i] not in flagged)
+        slot = free.pop(idx)
+        payload = sched._queue.popleft()
+        sched.live[slot] = payload
+        admitted.append((slot, payload))
+    if admitted:
+        sched._sess.reset_streams([slot for slot, _ in admitted])
+    return admitted
+
+
+class FlaggingSession(ReplaySession):
+    """A ReplaySession whose supervisor-flagged set the test sets."""
+
+    flagged = frozenset()
+
+    def flagged_slots(self):
+        return self.flagged
+
+
+def _admission_rounds(shards, flagged, queue, seed, rounds=12):
+    """Seeded rounds of ``evict_many``, flags and ``submit`` against two
+    schedulers over 4 slots a shard; yields ``(sched, twin)`` after the
+    submits, for the test to admit through each.  ``flagged`` is
+    "none", "one" (one slot, live or free) or "shard" (every free slot of
+    one shard); ``queue`` sizes the queue against the healthy free slots:
+    "short", "equal" or "long"."""
+    from repro.launch.streaming import SlotScheduler
+    rng = np.random.default_rng(seed)
+    batch = 4 * shards
+    pair = [SlotScheduler(FlaggingSession(batch=batch, n_shards=shards))
+            for _ in range(2)]
+    request = 0
+    for rnd in range(rounds):
+        sched = pair[0]
+        if sched.live and rnd:
+            out = rng.permutation(list(sched.live))
+            out = out[:rng.integers(0, len(out) + 1)]
+            for s in pair:
+                s.evict_many(out)
+        if flagged == "one":
+            bad = frozenset({int(rng.integers(batch))})
+        elif flagged == "shard":
+            bad = frozenset(sched._free[rnd % shards])
+        else:
+            bad = frozenset()
+        healthy = sum(s not in bad for f in sched._free for s in f)
+        want = {"short": healthy // 2, "equal": healthy,
+                "long": healthy + 3}[queue]
+        for _ in range(max(0, want - len(sched))):
+            for s in pair:
+                s.submit(f"r{request}")
+            request += 1
+        for s in pair:
+            s._sess.flagged = bad
+        yield pair
+
+
+@pytest.mark.parametrize("queue", ["short", "equal", "long"])
+@pytest.mark.parametrize("flagged", ["none", "one", "shard"])
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_bulk_admit_equals_per_request_loop(shards, flagged, queue):
+    for sched, twin in _admission_rounds(shards, flagged, queue,
+                                         seed=[shards, len(flagged),
+                                               len(queue)]):
+        want = _admit_one_request_at_a_time(twin)
+        got = sched.admit()
+        assert got == want                  # slots, payloads, order
+        assert all(type(s) is int for s, _ in got)
+        assert list(sched.live.items()) == list(twin.live.items())
+        assert sched._free == twin._free    # per shard, in order
+        assert all(type(s) is int for f in sched._free for s in f)
+        assert list(sched._queue) == list(twin._queue)
+        assert sched._sess.log == twin._sess.log   # the reset waves
+        assert not set(s for s, _ in got) & sched._sess.flagged
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_occupancy_from_free_lists_and_admission_counts(shards):
+    from repro.launch.engine import run_audio_requests, \
+        run_continuous_detect
+    waves = slots = 0
+    for sched, twin in _admission_rounds(shards, "one", "long", seed=shards):
+        got = sched.admit()
+        _admit_one_request_at_a_time(twin)  # keeps the pair in step
+        waves, slots = waves + bool(got), slots + len(got)
+        walk = [0] * shards
+        for slot in sched.live:
+            walk[sched._sess.shard_of_slot(slot)] += 1
+        assert sched.occupancy() == walk
+        assert (sched.waves, sched.admitted) == (waves, slots)
+
+    # The utterance loop's engine has the scheduler: the report counts
+    # the waves the run admitted (without faults, every reset is one).
+    done, stats, log = _replay_run(run_audio_requests, 2, requests=14,
+                                   slots=4, shards=shards)
+    resets = [op[1] for op in log if op[0] == "reset"]
+    n = sum(map(len, resets))
+    assert n == len(done) == 14
+    assert stats["slo"]["admission"] == {
+        "waves": len(resets), "slots": n, "mean_wave": n / len(resets)}
+
+    # The detect loop's has none, and its report no admission key.
+    class EventSession(ReplaySession):
+        def process_audio(self, piece):
+            out = super().process_audio(piece)
+            return types.SimpleNamespace(events=out.votes)
+    audio = np.zeros((4, 2048), np.float32)
+    *_, stats = run_continuous_detect(
+        EventSession(batch=4, n_shards=shards), list(audio), chunk=1024,
+        n_samples=2048, depth=2, warm=False)
+    assert "admission" not in stats["slo"]
+
+
 # ---------------------------------------------------------------------------
 # serve.py CLI: --sync-loop escape hatch + the timing-split output
 
