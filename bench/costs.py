@@ -9,12 +9,18 @@ read the same work whatever implements it.
     envelope (|y|, two multiplies, an add): 16 operations; per frame and
     channel, the log2 compression onto the 12-bit grid: 10 operations.
     Bytes: audio in, filter state in and out, features out, coefficients.
-  ΔGRU, per frame and slot, dense as the kernel runs it today, summed
-    over the configuration's layers (I = input_dim for the first, H for
-    each layer above, which reads the hidden state below): 2·(I+H)·3H
-    for the two contractions, 6H to accumulate M, 11H for the gates.
+  Recurrent stack, per frame and slot, dense as the kernel runs it
+    today, summed over the configuration's layers (I = input_dim for the
+    first, H for each layer above, which reads the hidden state below),
+    with G the cell's gate count (``bench/model.py``'s ``CELLS``: ΔGRU 3,
+    ΔLSTM 4): 2·(I+H)·G·H for the two contractions, 2·G·H to accumulate
+    M, and the gates: ΔGRU 11H; ΔLSTM 13H (4H pre-activation sums, 3H
+    sigmoid, H tanh(g), H f·c, H i·g, H add, H tanh(c), H o·tanh(c)).
     Bytes, each layer: its input in (features, or the hidden state
-    below), hidden out, state in and out, weights.
+    below), hidden out, weights (I+H)·G·H, and the state in and out: h
+    and ĥ, x̂, M as 2·G·H accumulator words, and for the ΔLSTM c, H words
+    of ``dtypes["cell"]``.  The configuration's ``kernels.gru`` names
+    the stack's recurrent kernel, whatever its cell.
   FC, per frame and slot: 2·H·K + K.
   ADC quantizer, per sample: 7 (clip, scale, round, clip).
 
@@ -24,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from bench.model import layers
+from bench.model import CELLS, cell, layers
 
 # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8,
 # 16 GB HBM at 819 GB/s per chip.  No float32 or vector-unit rate is
@@ -87,31 +93,41 @@ def fex_call(config: dict, slots: int, samples: int) -> Work:
 
 
 def _layer_inputs(config: dict) -> list[tuple[int, int]]:
-    """(input width, bytes per input element) of each ΔGRU layer, the
-    first first: the features, then the hidden state of the layer below."""
+    """(input width, bytes per input element) of each recurrent layer,
+    the first first: the features, then the hidden state of the layer
+    below."""
     I, H = int(config["input_dim"]), int(config["hidden"])
     dt = config["dtypes"]
     return ([(I, BYTES[dt["features"]])]
             + [(H, BYTES[dt["hidden"]])] * (layers(config) - 1))
 
 
+GATE_OPS_PER_H = {"gru": 11, "lstm": 13}
+
+
 def gru_frame_ops(config: dict) -> int:
+    """Dense operations of the recurrent stack, one frame of one slot."""
     H = int(config["hidden"])
-    return sum(2 * (I + H) * 3 * H + 6 * H + 11 * H
+    G = CELLS[cell(config)].gates
+    gate_ops = GATE_OPS_PER_H[cell(config)] * H
+    return sum(2 * (I + H) * G * H + 2 * G * H + gate_ops
                for I, _ in _layer_inputs(config))
 
 
 def gru_call(config: dict, slots: int, frames: int) -> Work:
-    """The ΔGRU stack over ``slots`` × ``frames`` (dense): one call of
-    the kernel per layer."""
+    """The recurrent stack over ``slots`` × ``frames`` (dense): one call
+    of the kernel per layer."""
     H = int(config["hidden"])
+    spec = CELLS[cell(config)]
     dt = config["dtypes"]
     bh, ba = BYTES[dt["hidden"]], BYTES[dt["accumulator"]]
+    bc = BYTES[dt["cell"]] if spec.carries_c else 0
+    GH = spec.gates * H
     nbytes = 0
     for I, bi in _layer_inputs(config):
-        state = (2 * H) * bh + I * bi + 6 * H * ba      # h, ĥ, x̂, M
+        state = (2 * H) * bh + I * bi + 2 * GH * ba + H * bc  # h, ĥ, x̂, M, c
         nbytes += (slots * frames * (I * bi + H * bh) + 2 * slots * state
-                   + (I + H) * 3 * H * BYTES[dt["weights"]])
+                   + (I + H) * GH * BYTES[dt["weights"]])
     return Work(slots * frames * gru_frame_ops(config), nbytes)
 
 

@@ -62,6 +62,7 @@ def _check_program_config(cfg: dict, arch, fcfg):
     f = cfg["fex"]
     pairs = {
         "layers": (arch.num_layers, model.layers(cfg)),
+        "cell": (getattr(arch, "cell", "gru"), model.cell(cfg)),
         "hidden": (arch.d_model, cfg["hidden"]),
         "classes": (arch.vocab_size, cfg["classes"]),
         "sample_rate_hz": (fcfg.fs, cfg["sample_rate_hz"]),

@@ -35,6 +35,25 @@ layer below (``hid_frac`` fraction bits, its Δ threshold on that grid)
 as its input; its input weights take a power-of-two scale of their own,
 and its accumulator, saturation and gates are the first layer's.
 
+A configuration may state ``cell`` (``bench/model.py``'s ``CELLS``):
+``"gru"``, the default, or ``"lstm"``, a ΔLSTM (Spartus, arXiv:2108.02297)
+whose layers carry a cell vector c beside h.  Its Δ-encoding and memories
+are the ΔGRU's, G·H = 4H wide, gate-major i, f, g, o:
+
+  float32  M = m_x + m_h;  i, f, o = σ(M);  g = tanh(M);
+           c' = f⊙c + i⊙g;  h' = o⊙tanh(c').
+  int8     i, f, o, g codes at ``hid_frac``, rounded from σ / tanh of
+           M·2^−acc_frac as the ΔGRU's gates are; c an int16 code at
+           ``formats["cell_frac"]``:
+           c' = sat16(rshr(f·c, hid_frac)
+                      + rshr(i·g, 2·hid_frac − cell_frac)),
+           h' = sat16(rshr(o·round(tanh(c'·2^−cell_frac)·2^hid_frac),
+                           hid_frac)),
+           rshr the round-half-up arithmetic shift.
+
+The check compares every layer's h, not c: through a slot's silence tail
+an error in c keeps reaching h through o⊙tanh(c).
+
 ``precision`` selects the comparison's control: "high" computes every
 float contraction in three bfloat16 passes (what the TPU's ``high``
 precision does), and ``weight_bits=4`` rounds the int8 weights onto the
@@ -50,7 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.model import layer_keys, layers
+from bench.model import CELLS, cell, layer_keys, layers
 
 NO_EVENT = -1
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -138,10 +157,11 @@ def filter_bank(fex: dict):
 
 # ------------------------------------------------------------- the model
 class GruLayer(NamedTuple):
-    """One ΔGRU layer's weights: float32, or int8 codes and int32 bias."""
-    w_x: jax.Array           # (I, 3H): I = C for the first layer, H above
-    w_h: jax.Array           # (H, 3H)
-    b: jax.Array             # (3H,)
+    """One recurrent layer's weights, of either cell: float32, or int8
+    codes and int32 bias."""
+    w_x: jax.Array           # (I, G·H): I = C for the first layer, H above
+    w_h: jax.Array           # (H, G·H)
+    b: jax.Array             # (G·H,)
 
 
 class Model(NamedTuple):
@@ -162,6 +182,7 @@ class Static:
     log_eps: float
     threshold: float
     hidden: int
+    cell: str = "gru"
     # int8 formats
     b_frac: int = 0
     a_frac: int = 0
@@ -172,6 +193,7 @@ class Static:
     eps_code: int = 0
     feat_frac: int = 11
     hid_frac: int = 15
+    cell_frac: int = 0       # the ΔLSTM's c
     acc_frac: int = 18
     acc_bits: int = 24
     shift_x: tuple = ()      # per layer
@@ -219,6 +241,7 @@ def build(config: dict, params: dict, *, precision: str = "highest",
         precision=precision, frame_shift=int(fex["frame_shift"]),
         env_alpha=alpha, log_eps=float(fex["log_eps"]),
         threshold=float(config["delta_threshold"]), hidden=H,
+        cell=cell(config),
         vad_threshold=float(vad["energy_threshold"]),
         vad_hangover=int(vad["hangover_frames"]),
         smooth_alpha=float(det.get("smooth_alpha", 1.0)),
@@ -263,6 +286,8 @@ def build(config: dict, params: dict, *, precision: str = "highest",
         alpha_code=int(round(alpha * 2 ** f["alpha_frac"])),
         eps_code=int(round(fex["log_eps"] * 2 ** f["env_frac"])),
         feat_frac=int(f["feat_frac"]), hid_frac=int(f["hid_frac"]),
+        cell_frac=(int(f["cell_frac"]) if CELLS[cell(config)].carries_c
+                   else 0),
         acc_frac=acc_frac, acc_bits=acc_bits,
         shift_x=tuple(shift_x), shift_h=tuple(shift_h),
         logit_frac=logit_frac, **common)
@@ -271,13 +296,15 @@ def build(config: dict, params: dict, *, precision: str = "highest",
 
 # ------------------------------------------------------------- state
 class State(NamedTuple):
-    """The ΔGRU fields hold one array per layer, the first layer's first."""
+    """The recurrent fields hold one array per layer, the first layer's
+    first."""
     fex: jax.Array       # (B, 5, C) section-0, section-1 registers + env
     h: tuple             # (B, H)
+    c: tuple             # (B, H) the ΔLSTM's cell state; () for a ΔGRU
     x_hat: tuple         # (B, I): I = C for the first layer, H above
     h_hat: tuple         # (B, H)
-    m_x: tuple           # (B, 3H) input pre-activation memory (with bias)
-    m_h: tuple           # (B, 3H) hidden pre-activation memory
+    m_x: tuple           # (B, G·H) input pre-activation memory (with bias)
+    m_h: tuple           # (B, G·H) hidden pre-activation memory
     hold: jax.Array      # (B, C) VAD hold register
     hang: jax.Array      # (B,) VAD hangover
     smooth: jax.Array    # (B, K) smoothed posteriors
@@ -288,6 +315,8 @@ class State(NamedTuple):
 def init_state(model: Model, static: Static, batch: int) -> State:
     C = model.coef.shape[1]
     H, K = model.w_fc.shape
+    spec = CELLS[static.cell]
+    GH = spec.gates * H
     dt = jnp.float32 if static.numerics == "float32" else jnp.int32
 
     def zeros(*shape):
@@ -295,11 +324,12 @@ def init_state(model: Model, static: Static, batch: int) -> State:
 
     return State(
         fex=zeros(5, C), h=tuple(zeros(H) for _ in model.gru),
+        c=tuple(zeros(H) for _ in model.gru) if spec.carries_c else (),
         x_hat=tuple(zeros(g.w_x.shape[0]) for g in model.gru),
         h_hat=tuple(zeros(H) for _ in model.gru),
-        m_x=tuple(jnp.broadcast_to(g.b, (batch, 3 * H)).astype(dt)
+        m_x=tuple(jnp.broadcast_to(g.b, (batch, GH)).astype(dt)
                   for g in model.gru),
-        m_h=tuple(zeros(3 * H) for _ in model.gru), hold=zeros(C),
+        m_h=tuple(zeros(GH) for _ in model.gru), hold=zeros(C),
         hang=jnp.zeros((batch,), jnp.int32),
         smooth=jnp.zeros((batch, K), jnp.float32),
         active=jnp.full((batch,), NO_EVENT, jnp.int32),
@@ -393,12 +423,12 @@ def _compress(env, st: Static):
     return _sat(jnp.round(v * (1 << st.feat_frac)).astype(jnp.int32), 16)
 
 
-def _gru_frame(x, h, x_hat, h_hat, m_x, m_h, g: GruLayer, layer: int,
-               st: Static):
-    """One ΔGRU layer's frame: Δ-encode x and h, accumulate W·Δ into M,
-    gates.  In int8 the first layer's x is a feature code, a higher
-    layer's the hidden code of the layer below."""
-    H = st.hidden
+def _delta_accumulate(x, h, x_hat, h_hat, m_x, m_h, g: GruLayer,
+                      layer: int, st: Static):
+    """Δ-encode x and h against the values last transmitted and add W·Δ
+    into the memories M: the part every cell shares.  In int8 the first
+    layer's x is a feature code, a higher layer's the hidden code of the
+    layer below."""
     if st.numerics == "float32":
         th = st.threshold
         mx = jnp.abs(x - x_hat) > th
@@ -409,10 +439,6 @@ def _gru_frame(x, h, x_hat, h_hat, m_x, m_h, g: GruLayer, layer: int,
         h_hat = jnp.where(mh, h, h_hat)
         m_x = m_x + fdot(dx, g.w_x, st.precision)
         m_h = m_h + fdot(dh, g.w_h, st.precision)
-        r = jax.nn.sigmoid(m_x[:, :H] + m_h[:, :H])
-        u = jax.nn.sigmoid(m_x[:, H:2 * H] + m_h[:, H:2 * H])
-        c = jnp.tanh(m_x[:, 2 * H:] + r * m_h[:, 2 * H:])
-        h = u * h + (1.0 - u) * c
     else:
         in_frac = st.feat_frac if layer == 0 else st.hid_frac
         th_x = int(np.floor(st.threshold * (1 << in_frac)))
@@ -428,6 +454,23 @@ def _gru_frame(x, h, x_hat, h_hat, m_x, m_h, g: GruLayer, layer: int,
                    st.acc_bits)
         m_h = _sat(m_h + _to_grid(idot(dh, g.w_h), st.shift_h[layer]),
                    st.acc_bits)
+    nz = jnp.sum(mx, -1) + jnp.sum(mh, -1)
+    return x_hat, h_hat, m_x, m_h, nz
+
+
+def _gru_frame(x, h, x_hat, h_hat, m_x, m_h, g: GruLayer, layer: int,
+               st: Static):
+    """One ΔGRU layer's frame: Δ-encode x and h, accumulate W·Δ into M,
+    gates r, u, c."""
+    H = st.hidden
+    x_hat, h_hat, m_x, m_h, nz = _delta_accumulate(
+        x, h, x_hat, h_hat, m_x, m_h, g, layer, st)
+    if st.numerics == "float32":
+        r = jax.nn.sigmoid(m_x[:, :H] + m_h[:, :H])
+        u = jax.nn.sigmoid(m_x[:, H:2 * H] + m_h[:, H:2 * H])
+        c = jnp.tanh(m_x[:, 2 * H:] + r * m_h[:, 2 * H:])
+        h = u * h + (1.0 - u) * c
+    else:
         one = 1 << st.hid_frac
         acc = float(2.0 ** -st.acc_frac)
         ru = jnp.round(jax.nn.sigmoid(
@@ -439,8 +482,42 @@ def _gru_frame(x, h, x_hat, h_hat, m_x, m_h, g: GruLayer, layer: int,
                * (m_h[:, 2 * H:].astype(jnp.float32) * acc))
         c = jnp.round(jnp.tanh(pre) * one).astype(jnp.int32)
         h = _sat(_rshr(u * h + (one - u) * c, st.hid_frac), 16)
-    nz = jnp.sum(mx, -1) + jnp.sum(mh, -1)
     return h, x_hat, h_hat, m_x, m_h, nz
+
+
+def _lstm_frame(x, h, x_hat, h_hat, m_x, m_h, c, g: GruLayer, layer: int,
+                st: Static):
+    """One ΔLSTM layer's frame: the ΔGRU's Δ-encoding and accumulation,
+    then gates i, f, g, o and the cell c (module docstring).  Returns the
+    ΔGRU frame's six values and c'."""
+    H = st.hidden
+    x_hat, h_hat, m_x, m_h, nz = _delta_accumulate(
+        x, h, x_hat, h_hat, m_x, m_h, g, layer, st)
+    if st.numerics == "float32":
+        m = m_x + m_h
+        i = jax.nn.sigmoid(m[:, :H])
+        f = jax.nn.sigmoid(m[:, H:2 * H])
+        gg = jnp.tanh(m[:, 2 * H:3 * H])
+        o = jax.nn.sigmoid(m[:, 3 * H:])
+        c = f * c + i * gg
+        h = o * jnp.tanh(c)
+    else:
+        one = 1 << st.hid_frac
+        m = (m_x + m_h).astype(jnp.float32) * float(2.0 ** -st.acc_frac)
+
+        def code(v):
+            return jnp.round(v * one).astype(jnp.int32)
+
+        i = code(jax.nn.sigmoid(m[:, :H]))
+        f = code(jax.nn.sigmoid(m[:, H:2 * H]))
+        gg = code(jnp.tanh(m[:, 2 * H:3 * H]))
+        o = code(jax.nn.sigmoid(m[:, 3 * H:]))
+        c = _sat(_rshr(f * c, st.hid_frac)
+                 + _rshr(i * gg, 2 * st.hid_frac - st.cell_frac), 16)
+        tc = code(jnp.tanh(c.astype(jnp.float32)
+                           * float(2.0 ** -st.cell_frac)))
+        h = _sat(_rshr(o * tc, st.hid_frac), 16)
+    return h, x_hat, h_hat, m_x, m_h, nz, c
 
 
 def _logits(h, model: Model, st: Static):
@@ -510,10 +587,15 @@ def block(model: Model, state: State, audio, *, st: Static, detect: bool):
                              jnp.maximum(hang - 1, 0)).astype(jnp.int32)
             x = jnp.where(gate[:, None], x, hold)
             hold = x
-        gru = []
+        gru, cells = [], []
         for layer, g in enumerate(model.gru):
-            out = _gru_frame(x, s.h[layer], s.x_hat[layer], s.h_hat[layer],
-                             s.m_x[layer], s.m_h[layer], g, layer, st)
+            carry = (s.h[layer], s.x_hat[layer], s.h_hat[layer],
+                     s.m_x[layer], s.m_h[layer])
+            if st.cell == "lstm":
+                *out, c = _lstm_frame(x, *carry, s.c[layer], g, layer, st)
+                cells.append(c)
+            else:
+                out = _gru_frame(x, *carry, g, layer, st)
             gru.append(out)
             x = out[0]                    # the next layer's input
         h, x_hat, h_hat, m_x, m_h, nz = (tuple(f) for f in zip(*gru))
@@ -523,9 +605,9 @@ def block(model: Model, state: State, audio, *, st: Static, detect: bool):
         if detect:
             smooth, active, refract, events = _detector(
                 smooth, active, refract, logits, st)
-        s = State(fex=jnp.stack(regs, axis=1), h=h, x_hat=x_hat,
-                  h_hat=h_hat, m_x=m_x, m_h=m_h, hold=hold, hang=hang,
-                  smooth=smooth, active=active, refract=refract)
+        s = State(fex=jnp.stack(regs, axis=1), h=h, c=tuple(cells),
+                  x_hat=x_hat, h_hat=h_hat, m_x=m_x, m_h=m_h, hold=hold,
+                  hang=hang, smooth=smooth, active=active, refract=refract)
         return s, Frames(votes=votes, events=events, gate=gate,
                          nz=sum(nz).astype(jnp.int32))
 

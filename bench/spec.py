@@ -12,12 +12,15 @@ a cell by adding files and entries and never edits a file that exists:
   limits          ``bench/limits/<cell>.json``
   per-layer       ``bench/metrics/<metric>.py``, one reader each
 
-A configuration may state ``layers``, a stack of ΔGRU layers of width
-``hidden`` (default one): the weights (``bench/model.py``, which names
-the keys of the layers above the first), the work counts
-(``bench/costs.py``), the program check (``bench/harness.py``), the
-reference and the state the check compares (every layer's, side by side)
-follow it, so a stacked configuration is a file of its own too.
+A configuration may state ``layers``, a stack of recurrent layers of
+width ``hidden`` (default one), and ``cell``, the recurrent cell of every
+layer (``"gru"``, the default ΔGRU, or ``"lstm"``, a ΔLSTM with four
+gates and a cell vector c; ``bench/model.py``'s ``CELLS``): the weights
+(``bench/model.py``, which names the keys of the layers above the first),
+the work counts (``bench/costs.py``), the program check
+(``bench/harness.py``), the reference and the state the check compares
+(every layer's h, side by side) follow both, so a stacked or a ΔLSTM
+configuration is a file of its own too.
 """
 from __future__ import annotations
 

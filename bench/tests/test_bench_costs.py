@@ -70,9 +70,49 @@ def test_two_layer_counts_by_hand():
     assert costs.model_ops_per_frame(F32_2) == 332 + 300 + 28
 
 
+# The same widths as a ΔLSTM: four gates and a cell vector c.
+F32_LSTM = dict(F32, cell="lstm",
+                dtypes=dict(F32["dtypes"], cell="float32"))
+I8_LSTM = dict(I8, cell="lstm", dtypes=dict(I8["dtypes"], cell="int16"))
+
+
+def test_lstm_counts_by_hand():
+    # per frame: 2*(2+3)*12 = 120 contraction, 2*4*3 = 24 accumulate,
+    # 13*3 = 39 gates -> 183.  5 slots x 2 frames -> 1830.
+    assert costs.gru_frame_ops(F32_LSTM) == 183
+    w = costs.gru_call(F32_LSTM, slots=5, frames=2)
+    assert w.ops == 1830
+    # features in 80, hidden out 120; state per slot (h, h_hat 6*4) +
+    # (x_hat 2*4) + (M 24*4) + (c 3*4) = 140, in and out for 5 slots =
+    # 1400; weights 5*12*4 = 240.
+    assert w.bytes == 80 + 120 + 1400 + 240
+    # int8, c in int16: 40 + 60 + 2*5*(12 + 4 + 96 + 6) + 60.
+    assert costs.gru_call(I8_LSTM, slots=5, frames=2).bytes == \
+        40 + 60 + 1180 + 60
+    # FEx 332 and FC 28 as for the ΔGRU.
+    assert costs.model_ops_per_frame(F32_LSTM) == 332 + 183 + 28
+
+
+def test_two_layer_lstm_counts_by_hand():
+    f32, i8 = dict(F32_LSTM, layers=2), dict(I8_LSTM, layers=2)
+    # layer 2 (I = H = 3): 2*(3+3)*12 = 144 contraction, 24 accumulate,
+    # 39 gates -> 207.
+    assert costs.gru_frame_ops(f32) == 183 + 207
+    w = costs.gru_call(f32, slots=5, frames=2)
+    assert w.ops == 10 * 390
+    # layer 2: hidden in 120, out 120; state per slot 24 + 12 + 96 + 12
+    # = 144, in and out for 5 slots = 1440; weights 6*12*4 = 288.
+    assert w.bytes == (80 + 120 + 1400 + 240) + (120 + 120 + 1440 + 288)
+    # int8: 60 + 60 + 2*5*(12 + 6 + 96 + 6) + 72.
+    assert costs.gru_call(i8, slots=5, frames=2).bytes == \
+        (40 + 60 + 1180 + 60) + (60 + 60 + 1200 + 72)
+    assert costs.model_ops_per_frame(f32) == 332 + 390 + 28
+
+
 # (fex ops, fex bytes, gru ops, gru bytes, model ops) of 1000 steps and
 # 123457 served frames, recorded from the one-layer code before a
-# configuration could state ``layers``.
+# configuration could state ``layers``; the stacked cell's from the code
+# before a configuration could state ``cell``.
 PINNED_WORK = {
     "int8-utt-c4096": (674365440000, 9249008000, 966787072000, 8574848000,
                        6484949296.0),
@@ -82,6 +122,8 @@ PINNED_WORK = {
                        6484949296.0),
     "int8-utt-c4096-x4": (2697461760000, 36995312000, 3867148288000,
                           34256768000, 6484949296.0),
+    "edgedrnn-int8-utt-c4096": (674365440000, 9249008000, 350257938432000,
+                                248248832000, 1324563239408.0),
 }
 
 

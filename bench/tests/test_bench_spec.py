@@ -1,4 +1,5 @@
 """BENCHMARK.json and the files its names resolve to."""
+import dataclasses
 import json
 import pathlib
 import re
@@ -188,3 +189,72 @@ def test_a_two_layer_config_added_as_a_file_alone(tmp_path):
                                       FExConfig())
     for p, data in before.items():
         assert p.read_bytes() == data
+
+
+def test_an_lstm_config_added_as_a_file_alone(tmp_path):
+    """A ΔLSTM configuration is a config file with ``cell``: weights,
+    counts and reference follow it, today's program (a ΔGRU) is refused
+    by name, and no file under bench/ changes."""
+    from bench import costs, model
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
+              if p.is_file()}
+    cfg = json.loads((ROOT / "bench/configs/deltakws-int8.json").read_text())
+    cfg.update(name="test-lstm", cell="lstm", layers=2,
+               formats=dict(cfg["formats"], cell_frac=12),
+               dtypes=dict(cfg["dtypes"], cell="int16"))
+    (tmp_path / "bench/configs/test-lstm.json").write_text(json.dumps(cfg))
+    (tmp_path / "bench/limits/test-lstm-utt.json").write_text(
+        (ROOT / "bench/limits/int8-utt-c4096.json").read_text())
+    b = json.loads((ROOT / "BENCHMARK.json").read_text())
+    b["configs"].append({"name": "test-lstm", "source": "test",
+                         "file": "bench/configs/test-lstm.json",
+                         "reduced": [], "why": "test"})
+    b["workloads"].append({"name": "test-lstm-utt", "config": "test-lstm",
+                           "traffic": "utt-c4096", "chips": 1,
+                           "why": "test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    cell = spec.find_cell(spec.load_benchmark(tmp_path), "test-lstm-utt",
+                          tmp_path)
+    assert model.cell(cell.config) == "lstm"
+    params = model.make_params(cell.config, 2 ** 37 + 1)
+    H, I = cfg["hidden"], cfg["input_dim"]
+    assert params["w_x"].shape == (I, 4 * H)
+    assert params["w_h"].shape == params["w_x_1"].shape == (H, 4 * H)
+    assert params["b"].shape == params["b_1"].shape == (4 * H,)
+    gru = dataclasses.replace(cell, config=dict(cell.config, cell="gru"))
+    served = {"steps": 10, "slots": 8, "chunk_samples": 4096,
+              "served_frames": 100}
+    lstm_work = costs.StepWork.of(cell, served)
+    gru_work = costs.StepWork.of(gru, served)
+    assert lstm_work.fex == gru_work.fex
+    assert lstm_work.gru.ops > gru_work.gru.ops
+    assert lstm_work.gru.bytes > gru_work.gru.bytes
+    assert lstm_work.model_ops > gru_work.model_ops
+    ref = spec.reference(cell)
+    ref_model, static = ref.build(cell.config, params)
+    assert len(ref_model.gru) == 2 and static.cell == "lstm"
+    assert static.cell_frac == 12
+    state = ref.init_state(ref_model, static, 3)
+    assert [c.shape for c in state.c] == [(3, H), (3, H)]
+    assert state.m_x[1].shape == (3, 4 * H)
+    from repro.configs import get_config
+    from repro.frontend import FExConfig
+    arch = dataclasses.replace(get_config("deltakws"), num_layers=2)
+    with pytest.raises(harness.RunError, match="'cell'"):
+        harness._check_program_config(cell.config, arch, FExConfig())
+    for p, data in before.items():
+        assert p.read_bytes() == data
+
+
+@pytest.mark.parametrize("name, want", [(None, "gru"), ("gru", "gru"),
+                                        ("lstm", "lstm"), ("rnn", None)])
+def test_the_cell_a_config_states(name, want):
+    from bench import model
+    cfg = {} if name is None else {"cell": name}
+    if want is None:
+        with pytest.raises(ValueError, match="rnn"):
+            model.cell(cfg)
+    else:
+        assert model.cell(cfg) == want
