@@ -55,6 +55,7 @@ class ArchConfig:
     use_delta: bool = False
     delta_threshold: float = 0.0
     gru_backend: str = "xla"         # xla | pallas (DESIGN.md §3)
+    cell: str = "gru"                # recurrent cell, a key of core.delta_gru.CELLS
     # --- performance knobs (§Perf) ---
     remat_policy: str = "full"       # full | save_mlp (selective remat)
     # --- numerics ---
@@ -125,6 +126,7 @@ _MODULES = {
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
     "deltakws": "deltakws",
     "edgedrnn": "edgedrnn",
+    "spartus": "spartus",
 }
 
 

@@ -38,6 +38,13 @@ persistent pre-activation memory):
     h_t = u_t ⊙ h_{t-1} + (1 - u_t) ⊙ c_t
 
 All delta state (x̂, ĥ, M_r, M_u, M_xc, M_hc) is carried in the scan.
+
+``CELLS`` is the one table of the recurrent cells a layer may be: the
+ΔGRU above, and the ΔLSTM (Spartus, arXiv:2108.02297), whose four gates
+i, f, g, o share the ΔGRU's Δ-encoding and memories (M is G·H wide) and
+which carries a cell vector c beside h (``DeltaLstmState``).  Every
+module that builds, carries or runs a layer reads its gate count and
+whether it carries c from here.
 """
 from __future__ import annotations
 
@@ -61,14 +68,37 @@ class DeltaGRUParams(NamedTuple):
     b: Array    # (3H,)
 
 
+class Cell(NamedTuple):
+    """What a recurrent cell is to the code that carries it."""
+
+    gates: int          # G: blocks of H in a layer's pre-activations
+    carries_c: bool     # a cell vector c carried beside h
+
+
+CELLS = {"gru": Cell(gates=3, carries_c=False),
+         "lstm": Cell(gates=4, carries_c=True)}
+
+
 class DeltaState(NamedTuple):
-    """Carried state of the delta recurrence."""
+    """Carried state of the delta recurrence (G = the cell's gates:
+    3 for the ΔGRU, 4 for the ΔLSTM)."""
 
     h: Array       # (B, H)  hidden state
     x_hat: Array   # (B, I)  last transmitted input
     h_hat: Array   # (B, H)  last transmitted hidden
-    m_x: Array     # (B, 3H) accumulated input pre-activations (incl. bias)
-    m_h: Array     # (B, 3H) accumulated hidden pre-activations
+    m_x: Array     # (B, G·H) accumulated input pre-activations (incl. bias)
+    m_h: Array     # (B, G·H) accumulated hidden pre-activations
+
+
+class DeltaLstmState(NamedTuple):
+    """``DeltaState`` of a cell that carries c: the same fields, then c."""
+
+    h: Array       # (B, H)
+    x_hat: Array   # (B, I)
+    h_hat: Array   # (B, H)
+    m_x: Array     # (B, 4H)
+    m_h: Array     # (B, 4H)
+    c: Array       # (B, H)  cell state
 
 
 class DeltaStats(NamedTuple):
@@ -82,14 +112,16 @@ class DeltaStats(NamedTuple):
 
 
 def init_delta_gru(key: Array, input_dim: int, hidden_dim: int,
-                   dtype=jnp.float32) -> DeltaGRUParams:
+                   dtype=jnp.float32, gates: int = 3) -> DeltaGRUParams:
+    """One layer's weights, ``gates`` blocks of H wide (``CELLS``)."""
     k1, k2 = jax.random.split(key)
+    GH = gates * hidden_dim
     # Orthogonal-ish recurrent init, scaled glorot for input kernels.
-    w_x = jax.random.normal(k1, (input_dim, 3 * hidden_dim), dtype) * (
+    w_x = jax.random.normal(k1, (input_dim, GH), dtype) * (
         1.0 / np.sqrt(input_dim))
-    w_h = jax.random.normal(k2, (hidden_dim, 3 * hidden_dim), dtype) * (
+    w_h = jax.random.normal(k2, (hidden_dim, GH), dtype) * (
         1.0 / np.sqrt(hidden_dim))
-    b = jnp.zeros((3 * hidden_dim,), dtype)
+    b = jnp.zeros((GH,), dtype)
     return DeltaGRUParams(w_x, w_h, b)
 
 

@@ -40,6 +40,7 @@ Formats (the per-tensor QFormat table — DESIGN.md §9):
   FEx coeff b / a       Q*.{12,8}b   int32     from dynamic range
   feature / x̂           Q0.11        int16     2^-11
   hidden h / ĥ / gates  Q0.15        int16     2^-15
+  ΔLSTM cell state c    Q3.12        int16     2^-12
   ΔGRU weight           Q0.7 × 2^e   int8      per-tensor pow-2 e
   accumulator M, bias   Q5.18        int32     24-bit saturating
   FC logits             Q*.{22-e}    int32     —
@@ -54,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.delta_gru import CELLS
 from repro.core.quantize import WEIGHT_Q
 from repro.kernels.gru_math import delta_branch, f32_dot, gru_gates
 
@@ -124,13 +126,15 @@ def _weight_exp(w) -> int:
 # ------------------------------------------------------------- ΔGRU formats
 @dataclasses.dataclass(frozen=True)
 class GruFormats:
-    """Static format metadata of a promoted ΔGRU (+FC).  Frozen/hashable:
-    passed as a jit static argument next to the code arrays."""
+    """Static format metadata of a promoted ΔGRU or ΔLSTM layer (+FC).
+    Frozen/hashable: passed as a jit static argument next to the code
+    arrays."""
 
     feat_frac: int = 11      # x / x̂ grid (the 12-bit feature grid)
     hid_frac: int = 15       # h / ĥ / gate grid
     acc_frac: int = 18       # M accumulator grid
     acc_bits: int = 24       # accumulator word width (saturating)
+    cell_frac: int = 12      # the ΔLSTM's c grid (int16 codes)
     e_x: int = 0             # w_x scale exponent (w = code · 2^(e-7))
     e_h: int = 0             # w_h scale exponent
     e_fc: int = 0            # FC weight scale exponent
@@ -161,11 +165,12 @@ class GruFormats:
 
 
 class IntGruWeights(NamedTuple):
-    """Promoted ΔGRU weights: int8 codes + bias on the accumulator grid."""
+    """Promoted recurrent-layer weights (either cell, G = its gates):
+    int8 codes + bias on the accumulator grid."""
 
-    w_x: Array   # (I, 3H) int8, value = code · 2^(e_x - 7)
-    w_h: Array   # (H, 3H) int8, value = code · 2^(e_h - 7)
-    b: Array     # (3H,)  int32 on the accumulator grid
+    w_x: Array   # (I, G·H) int8, value = code · 2^(e_x - 7)
+    w_h: Array   # (H, G·H) int8, value = code · 2^(e_h - 7)
+    b: Array     # (G·H,)  int32 on the accumulator grid
 
 
 def quantize_gru(params, fmt: GruFormats | None = None
@@ -188,17 +193,22 @@ def quantize_gru(params, fmt: GruFormats | None = None
 
 
 def init_int_delta_state(batch: int, input_dim: int, hidden_dim: int,
-                         w: IntGruWeights):
+                         w: IntGruWeights, cell: str = "gru"):
     """Fresh-stream state in code domain.  Reuses ``DeltaState`` (it is
-    a dtype-agnostic NamedTuple); m_x seeds at the bias codes so
+    a dtype-agnostic NamedTuple), ``DeltaLstmState`` with a zero int16 c
+    for a cell that carries c; m_x seeds at the bias codes so
     M == W x̂ + W ĥ + b holds on the accumulator grid."""
-    from repro.core.delta_gru import DeltaState
-    return DeltaState(
+    from repro.core.delta_gru import DeltaLstmState, DeltaState
+    GH = CELLS[cell].gates * hidden_dim
+    state = DeltaState(
         h=jnp.zeros((batch, hidden_dim), jnp.int16),
         x_hat=jnp.zeros((batch, input_dim), jnp.int16),
         h_hat=jnp.zeros((batch, hidden_dim), jnp.int16),
-        m_x=jnp.broadcast_to(w.b, (batch, 3 * hidden_dim)).astype(jnp.int32),
-        m_h=jnp.zeros((batch, 3 * hidden_dim), jnp.int32))
+        m_x=jnp.broadcast_to(w.b, (batch, GH)).astype(jnp.int32),
+        m_h=jnp.zeros((batch, GH), jnp.int32))
+    if not CELLS[cell].carries_c:
+        return state
+    return DeltaLstmState(*state, c=jnp.zeros((batch, hidden_dim), jnp.int16))
 
 
 # ------------------------------------------------------------ ΔGRU datapath
@@ -247,6 +257,36 @@ def int_gru_gates(m, h, fmt: GruFormats):
     return sat(h_new, 16)
 
 
+def int_lstm_gates(m, c, fmt: GruFormats):
+    """LSTM nonlinearity in code domain (ideal-LUT σ/tanh): ``m`` the
+    FUSED ``[m_x | m_h]`` block, (B, 8H) int32, gate-major i, f, g, o;
+    ``c`` the int32 cell codes at ``cell_frac``.  Returns (h', c'):
+
+      i, f, g, o = round(σ / tanh((m_x + m_h)·2^−acc_frac)·2^hid_frac)
+      c' = sat16(rshr(f·c, hid) + rshr(i·g, 2·hid − cell_frac))
+      h' = sat16(rshr(o·round(tanh(c'·2^−cell_frac)·2^hid), hid))
+
+    Every product is under 2^31 (|gate| ≤ 2^15, |c| < 2^15), and the
+    float σ/tanh see exact inputs, as in ``int_gru_gates``."""
+    H = c.shape[-1]
+    one = 1 << fmt.hid_frac
+    pre = ((m[:, :4 * H] + m[:, 4 * H:]).astype(jnp.float32)
+           * float(2.0 ** -fmt.acc_frac))
+
+    def code(v):
+        return jnp.round(v * one).astype(jnp.int32)
+
+    # i and f share σ as one pass over their [i|f] block; o its own.
+    i_f = code(jax.nn.sigmoid(pre[:, :2 * H]))
+    g = code(jnp.tanh(pre[:, 2 * H:3 * H]))
+    o = code(jax.nn.sigmoid(pre[:, 3 * H:]))
+    c = sat(rshift_round(i_f[:, H:] * c, fmt.hid_frac)
+            + rshift_round(i_f[:, :H] * g, 2 * fmt.hid_frac - fmt.cell_frac),
+            16)
+    tc = code(jnp.tanh(c.astype(jnp.float32) * float(2.0 ** -fmt.cell_frac)))
+    return sat(rshift_round(o * tc, fmt.hid_frac), 16), c
+
+
 # Exact Δ·W on the MXU.  A Δ code is a difference of saturated int16
 # codes, so |d| ≤ 2^16: 17 signed bits.  Split it into byte planes,
 # lo = d & 0xFF ∈ [0, 2^8) and hi = d >> 8 ∈ [−2^8, 2^8], and contract
@@ -291,6 +331,34 @@ def packed_int8_dot_pair(dx, dh, wx_f32, wh_f32):
     return (prod[rows:] << 8) + prod[:rows]
 
 
+# The same exact Δ·W with int8 weights held as they are (no float32
+# image): a Δ code (|d| < 2^17) is three SIGNED byte digits, d = p0 +
+# p1·2^8 + p2·2^16 with p0, p1 ∈ [−128, 127] and p2 ∈ [−1, 1], each an
+# int8 plane contracted on the MXU with int32 accumulation: every
+# product is ≤ 2^14, so any K < 2^17 rows sums exactly, and the int32
+# recombination wraps mod 2^32 as the int32 dot does.
+def int8_plane_dot(d, w_i8):
+    """Exact (B, K) int32 ``d`` × (K, N) int8 codes as the (3B, N) int32
+    plane products [p0; p1; p2]."""
+    p0 = ((d + 128) & 0xFF) - 128
+    d1 = (d - p0) >> 8
+    p1 = ((d1 + 128) & 0xFF) - 128
+    planes = jnp.concatenate([p0, p1, (d1 - p1) >> 8], axis=0)
+    return jnp.dot(planes.astype(jnp.int8), w_i8,
+                   preferred_element_type=jnp.int32)
+
+
+def int8_plane_dot_pair(dx, dh, wx_i8, wh_i8):
+    """Both contractions through ``int8_plane_dot`` with one shared
+    recombination into the fused (B, 2·G·H) [Δx·W_x | Δh·W_h] block —
+    bit-identical to ``packed_int8_dot_pair`` and to the int32 dot."""
+    rows = dx.shape[0]
+    prod = jnp.concatenate([int8_plane_dot(dx, wx_i8),
+                            int8_plane_dot(dh, wh_i8)], axis=-1)
+    return (prod[:rows] + (prod[rows:2 * rows] << 8)
+            + (prod[2 * rows:] << 16))
+
+
 def gru_frame_step(fmt: GruFormats | None, x, h, x_hat, h_hat, m,
                    w_x, w_h, th_x, th_h, dot=None):
     """ONE ΔGRU frame — the single source for golden scan AND kernel body.
@@ -308,10 +376,11 @@ def gru_frame_step(fmt: GruFormats | None, x, h, x_hat, h_hat, m,
     With a ``GruFormats``, everything is integer-code arithmetic.
 
     ``dot`` swaps the Δ·W contraction implementation (int mode only):
-    ``None`` is the plain int32 ``jnp.dot`` pair; the kernel passes the
-    exact byte-plane dot (``packed_int8_dot_pair``) on its float32
-    weight image, so the frame step stays the single source of the math
-    either way.
+    ``None`` is the plain int32 ``jnp.dot`` pair; the kernel passes an
+    exact byte-plane dot (``packed_int8_dot_pair`` on its float32 weight
+    image, or ``int8_plane_dot_pair`` on int8 weights held as they
+    are), so the frame step stays the single source of the math either
+    way.
     Signature: ``dot(dx, dh, w_x, w_h) -> (B, 6H)``.
 
     Returns ``(h', x̂', ĥ', m', mask_x, mask_h)``.
@@ -325,6 +394,18 @@ def gru_frame_step(fmt: GruFormats | None, x, h, x_hat, h_hat, m,
         h = gru_gates(m[:, :n], m[:, n:], h, h.shape[-1])
         return h, x_hat, h_hat, m, mask_x, mask_h
 
+    h32, x_hat, h_hat, m, mask_x, mask_h = _int_delta_accumulate(
+        fmt, x, h, x_hat, h_hat, m, w_x, w_h, th_x, th_h, dot)
+    h_new = int_gru_gates(m, h32, fmt)
+    return h_new, x_hat, h_hat, m, mask_x, mask_h
+
+
+def _int_delta_accumulate(fmt: GruFormats, x, h, x_hat, h_hat, m,
+                          w_x, w_h, th_x, th_h, dot):
+    """The part of an integer frame every cell shares: Δ-encode x and h
+    against the values last transmitted and add the aligned W·Δ into the
+    fused 24-bit memory.  Returns (h as int32, x̂', ĥ', m', mask_x,
+    mask_h)."""
     x = x.astype(jnp.int32)
     h32 = h.astype(jnp.int32)
     dx, x_hat, mask_x = int_delta_branch(x, x_hat.astype(jnp.int32), th_x)
@@ -338,20 +419,37 @@ def gru_frame_step(fmt: GruFormats | None, x, h, x_hat, h_hat, m,
     else:
         p = dot(dx, dh, w_x, w_h)
     m = sat(m + align_pair(p, fmt.shift_x, fmt.shift_h), fmt.acc_bits)
-    h_new = int_gru_gates(m, h32, fmt)
-    return h_new, x_hat, h_hat, m, mask_x, mask_h
+    return h32, x_hat, h_hat, m, mask_x, mask_h
+
+
+def lstm_frame_step(fmt: GruFormats, x, h, c, x_hat, h_hat, m, w_x, w_h,
+                    th_x, th_h, dot=None):
+    """ONE integer ΔLSTM frame — golden scan and kernel body alike: the
+    ΔGRU's Δ-encoding and fused (B, 8H) memory, then ``int_lstm_gates``.
+    ``dot`` as in ``gru_frame_step``.  Returns ``(h', c', x̂', ĥ', m',
+    mask_x, mask_h)``."""
+    _, x_hat, h_hat, m, mask_x, mask_h = _int_delta_accumulate(
+        fmt, x, h, x_hat, h_hat, m, w_x, w_h, th_x, th_h, dot)
+    h_new, c_new = int_lstm_gates(m, c.astype(jnp.int32), fmt)
+    return h_new, c_new, x_hat, h_hat, m, mask_x, mask_h
 
 
 @jax.named_scope("kws.gru")
 def int_gru_scan(w: IntGruWeights, fmt: GruFormats, xs_codes,
                  threshold: float, state=None, *, backend: str = "xla",
                  block_b: int | None = None, block_t: int | None = None,
-                 interpret: bool | None = None, event_driven: bool = False):
+                 interpret: bool | None = None, event_driven: bool = False,
+                 cell: str = "gru"):
     """Run the integer ΔGRU over codes ``xs_codes`` (T, B, I) int16.
 
     ``backend="xla"`` is the golden ``lax.scan``; ``"pallas"`` the fused
     sequence-resident kernel — bit-identical by single-source math.
     Returns ``(hs_codes (T,B,H) int16, final state, nz_dx, nz_dh)``.
+
+    ``cell`` (a key of ``core.delta_gru.CELLS``) runs a layer of another
+    cell through the same scan and kernel: ``"lstm"`` steps
+    ``lstm_frame_step`` over G·H = 4H memories and carries c in a
+    ``DeltaLstmState`` (``int_lstm_scan``).
 
     ``block_b``/``block_t`` forward to the kernel's tiling knobs
     (numerics-invariant); left ``None``, the dispatch consults the
@@ -372,69 +470,78 @@ def int_gru_scan(w: IntGruWeights, fmt: GruFormats, xs_codes,
     Unlike the float ``delta_gru_scan``, there is no block-sparse
     fallback for weights that cannot stay resident in VMEM (no int image
     of ``delta_matvec`` yet) — the kernel's VMEM plan
-    (``kernels.delta_gru_seq.int_vmem_plan``) REFUSES loudly instead of
-    compiling a kernel that Mosaic would reject.
+    (``kernels.delta_gru_seq.int_vmem_plan``) holds them as int8 planes
+    where their float32 image does not fit, and REFUSES loudly where
+    neither fits instead of compiling a kernel that Mosaic would reject.
     """
     T, B, I = xs_codes.shape
     H = w.w_h.shape[0]
+    GH = CELLS[cell].gates * H
     if state is None:
-        state = init_int_delta_state(B, I, H, w)
+        state = init_int_delta_state(B, I, H, w, cell)
     th_x, th_h = fmt.th_codes(threshold)
 
     if event_driven:
-        from repro.core.delta_gru import DeltaState
         from repro.kernels import compaction
+        kind = type(state)
 
         def run(xs_c, st):
             return int_gru_scan(
                 w, fmt, jnp.asarray(xs_c), threshold,
-                DeltaState(*[jnp.asarray(s) for s in st]), backend=backend,
-                interpret=interpret)
+                kind(*[jnp.asarray(s) for s in st]), backend=backend,
+                interpret=interpret, cell=cell)
 
         held = compaction.held_slots(xs_codes, state.x_hat, th_x)
         hs, st, nz_dx, nz_dh, _ = compaction.event_driven_seq(
             run, xs_codes, tuple(state), held)
-        return (jnp.asarray(hs),
-                DeltaState(*[jnp.asarray(s) for s in st]),
+        return (jnp.asarray(hs), kind(*[jnp.asarray(s) for s in st]),
                 jnp.asarray(nz_dx), jnp.asarray(nz_dh))
 
     if backend == "pallas":
-        from repro.kernels import autotune
-        from repro.kernels.delta_gru_seq import delta_gru_seq_int
-        if block_b is None or block_t is None:
-            tuned = autotune.resolve("delta_gru_seq_int", (B, I, H), "int8",
-                                     threshold, interpret=interpret,
-                                     B=B, T=T)
-            block_b = block_b if block_b is not None else tuned.get("block_b")
-            block_t = block_t if block_t is not None else tuned.get("block_t")
+        from repro.kernels.delta_gru_seq import (delta_gru_seq_int,
+                                                 int_kernel_tiles)
+        block_b, block_t = int_kernel_tiles(cell, B, I, H, T, threshold,
+                                            block_b, block_t, interpret)
         th = jnp.asarray([[th_x, th_h]], jnp.int32)
-        return delta_gru_seq_int(xs_codes, state.h, state.x_hat,
-                                 state.h_hat, state.m_x, state.m_h,
-                                 w.w_x, w.w_h, th, fmt=fmt,
+        return delta_gru_seq_int(xs_codes, *state[:5], w.w_x, w.w_h, th,
+                                 *state[5:], fmt=fmt, cell=cell,
                                  block_b=block_b, block_t=block_t,
                                  interpret=interpret)
     if backend != "xla":
         raise ValueError(f"unknown int ΔGRU backend: {backend!r}")
 
-    from repro.core.delta_gru import DeltaState
-
-    # The frame step carries the fused [m_x | m_h] block; the DeltaState
+    # The frame step carries the fused [m_x | m_h] block; the state's
     # halves concatenate once before the scan and split once after.
     def body(carry, x):
-        h, xh, hh, m = carry
-        h, xh, hh, m, mask_x, mask_h = gru_frame_step(
-            fmt, x, h, xh, hh, m, w.w_x, w.w_h, th_x, th_h)
+        if CELLS[cell].carries_c:
+            h, xh, hh, m, c = carry
+            h, c, xh, hh, m, mask_x, mask_h = lstm_frame_step(
+                fmt, x, h, c, xh, hh, m, w.w_x, w.w_h, th_x, th_h)
+            tail = (c.astype(jnp.int16),)
+        else:
+            h, xh, hh, m = carry
+            h, xh, hh, m, mask_x, mask_h = gru_frame_step(
+                fmt, x, h, xh, hh, m, w.w_x, w.w_h, th_x, th_h)
+            tail = ()
         h16 = h.astype(jnp.int16)
-        return ((h16, xh.astype(jnp.int16), hh.astype(jnp.int16), m),
+        return ((h16, xh.astype(jnp.int16), hh.astype(jnp.int16), m) + tail,
                 (h16, jnp.sum(mask_x, -1).astype(jnp.int32),
                  jnp.sum(mask_h, -1).astype(jnp.int32)))
 
     m0 = jnp.concatenate([state.m_x, state.m_h], axis=-1)
-    (h, xh, hh, m), (hs, nz_dx, nz_dh) = jax.lax.scan(
-        body, (state.h, state.x_hat, state.h_hat, m0), xs_codes)
-    final = DeltaState(h=h, x_hat=xh, h_hat=hh,
-                       m_x=m[:, :3 * H], m_h=m[:, 3 * H:])
+    (h, xh, hh, m, *c), (hs, nz_dx, nz_dh) = jax.lax.scan(
+        body, (state.h, state.x_hat, state.h_hat, m0, *state[5:]), xs_codes)
+    final = type(state)(h, xh, hh, m[:, :GH], m[:, GH:], *c)
     return hs, final, nz_dx, nz_dh
+
+
+def int_lstm_scan(w: IntGruWeights, fmt: GruFormats, xs_codes,
+                  threshold: float, state=None, **kw):
+    """The integer ΔLSTM over codes (T, B, I): ``int_gru_scan`` with
+    ``cell="lstm"`` (``lstm_frame_step``, c carried in the state).
+    Returns ``(hs (T,B,H) int16, DeltaLstmState, nz_dx, nz_dh)``."""
+    return int_gru_scan(w, fmt, xs_codes, threshold, state, cell="lstm",
+                        **kw)
 
 
 def masked_int_gru_scan(w: IntGruWeights, fmt: GruFormats, xs_codes,
@@ -669,9 +776,9 @@ class IntKwsBundle:
     ``ffmt`` are None until a FEx is folded in (feature-chunk serving
     needs only the GRU+FC half).
 
-    A ΔGRU stack holds ``gru`` and ``gfmt`` as tuples, one entry per
-    layer, the first layer's first; each layer's formats carry the FC
-    head's exponent, so any of them gives ``logit_frac``."""
+    A stack (of either cell) holds ``gru`` and ``gfmt`` as tuples, one
+    entry per layer, the first layer's first; each layer's formats carry
+    the FC head's exponent, so any of them gives ``logit_frac``."""
 
     gru: IntGruWeights | tuple
     w_fc: Array                     # (H, 12) int8
@@ -688,7 +795,7 @@ def promote_kws(params, threshold: float, fex=None) -> IntKwsBundle:
     Args:
       params: the ``models.kws.init_kws`` tree (w_x/w_h/b/w_fc/b_fc, and
         ``w_x_<l>, w_h_<l>, b_<l>`` for each layer ``l`` of a stack above
-        the first).
+        the first), of either cell: the weights' G·H columns fold alike.
       threshold: the float Δ_TH to serve at; stored on the bundle and
         FLOOR-quantized to a code at serving time so the integer gate
         transmits exactly the deltas the float gate transmits on grid

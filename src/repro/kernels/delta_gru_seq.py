@@ -33,24 +33,38 @@ accumulated on-device.  Weights that do NOT fit VMEM take the
 block-sparse path instead (``core.delta_gru`` composes ``delta_matvec``'s
 scalar-prefetch block mask per step — see DESIGN.md §2/§3).
 
-The int variant runs every Δ·W contraction exactly on the float MXU
-path (DESIGN.md §12): the int8 weight image is converted ONCE (at grid
-step 0) into an f32-valued copy held in persistent VMEM scratch, and
-each contraction runs as ``fixed_point.packed_int8_dot_pair`` — f32
-matmuls over byte-plane-split deltas in blocks of ≤ 512 contraction
-rows, exact at any width.  Its VMEM plan (``int_vmem_plan``) counts the
-weights, their image and the tile, raises the scoped-VMEM limit where a
-768-wide layer needs it, and refuses weights that cannot stay resident.
+The int variant runs every Δ·W contraction exactly on the MXU
+(DESIGN.md §12), for a layer of either cell (``core.delta_gru.CELLS``:
+the gate count G is a static parameter, and a ΔLSTM's cell state c rides
+the state blocks and the carry, under the name ``delta_lstm_seq_int``).
+Its VMEM plan (``int_vmem_plan``) chooses per layer how the weights are
+held, counts them and the tile, and raises the scoped-VMEM limit where a
+wide layer needs it:
+
+  * ``f32_image`` wherever it fits: the int8 block (double-buffered by
+    the pipeline) is converted ONCE (at grid step 0) into an f32-valued
+    copy in persistent VMEM scratch, and each contraction runs as
+    ``fixed_point.packed_int8_dot_pair`` — f32 matmuls over byte-plane-
+    split deltas in blocks of ≤ 512 contraction rows;
+  * ``int8_planes`` where that image does not fit (a ΔLSTM-1024's second
+    layer): the int8 codes stay in HBM (``memory_space=ANY``), one DMA
+    at grid step 0 holds them once in VMEM scratch, and each contraction
+    runs as ``fixed_point.int8_plane_dot_pair`` — native int8 matmuls of
+    three signed byte planes of the deltas, int32 accumulation.
+
+Both are exact at any width; weights that fit neither way are refused.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.delta_gru import CELLS
 from repro.kernels.autotune import LANES, validate_block_b, validate_divisor
 from repro.kernels.gru_math import delta_branch, f32_dot, gru_gates
 from repro.kernels.platform import resolve_interpret
@@ -60,12 +74,16 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 
 
-def _slot_vmem_bytes(I: int, H: int, T: int, block_t: int) -> int:
+def _slot_vmem_bytes(I: int, H: int, T: int, block_t: int, gates: int = 3,
+                     carries_c: bool = False) -> int:
     """VMEM bytes one slot adds to a tile: every block double-buffered,
-    4-byte words, lane dims padded to 128 — the default-tile budget."""
+    4-byte words, lane dims padded to 128 — the default-tile budget.
+    The state is h, x̂, ĥ, two G·H memories and, for a cell that
+    carries it, c."""
     lanes = lambda d: -(-d // LANES) * LANES
     seq = block_t * (lanes(I) + lanes(H))                 # xs in, hs out
-    state = 2 * (2 * lanes(H) + lanes(I) + 2 * lanes(3 * H))  # in + out
+    state = 2 * (2 * lanes(H) + lanes(I) + 2 * lanes(gates * H)
+                 + carries_c * lanes(H))                  # in + out
     return 2 * 4 * (seq + state + 2 * T)                  # + nz_dx/nz_dh
 
 
@@ -205,65 +223,132 @@ def delta_gru_seq(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, threshold,
 SCOPED_VMEM_DEFAULT_BYTES = 16 * 2 ** 20
 VMEM_CAP_BYTES = 100 * 2 ** 20
 
+# How the int kernel holds a layer's weights in VMEM, in order of
+# preference: the int8 block (double-buffered by the pipeline) beside a
+# float32 image converted once, which the byte-plane f32 dot reads; or,
+# where that does not fit, the int8 codes alone, DMA'd once from HBM
+# into single-buffered scratch and read by the native int8 plane dot.
+F32_IMAGE, INT8_PLANES = "f32_image", "int8_planes"
+
+
+class VmemPlan(NamedTuple):
+    """The int kernel's tiling and weight holding for one layer."""
+
+    block_b: int          # slots per batch tile
+    vmem_bytes: int       # what the kernel asks of VMEM
+    weights: str          # F32_IMAGE or INT8_PLANES
+
+
+def kernel_name(cell: str) -> str:
+    """The int kernel's ``pallas_call`` name for a layer of ``cell``:
+    ``delta_gru_seq_int``, ``delta_lstm_seq_int`` — one kernel body, a
+    name per cell, so a device trace tells the cells apart."""
+    return f"delta_{cell}_seq_int"
+
 
 def int_vmem_plan(I: int, H: int, T: int, B: int, block_t: int,
-                  block_b: int | None,
-                  interpret: bool | None = None) -> tuple[int, int]:
-    """(batch tile, VMEM bytes) of the int kernel, from what it holds:
-    the int8 weights (double-buffered by the pipeline) and their float32
-    image, and per slot the tile's blocks (``_slot_vmem_bytes``) plus
-    the frame step's live temporaries.
+                  block_b: int | None, interpret: bool | None = None, *,
+                  cell: str = "gru") -> VmemPlan:
+    """The int kernel's plan for one layer of ``cell``, from what it
+    holds: the weights, and per slot the tile's blocks
+    (``_slot_vmem_bytes``) plus the frame step's live temporaries.
 
-    The tile is ``validate_block_b``'s rule with the resident bytes
-    counted against its budget.  Raises ``NotImplementedError`` when even
-    the smallest legal tile cannot hold its weights resident under
-    ``VMEM_CAP_BYTES``: no kernel is built that Mosaic would refuse."""
-    resident = (2 + 4) * (I + H) * 3 * H
-    tile = _slot_vmem_bytes(I, H, T, block_t)
-    bb = validate_block_b("delta_gru_seq_int", B, block_b,
-                          interpret=interpret, slot_bytes=tile,
-                          fixed_bytes=resident)
-    # Fused M and its increment, both dots' two plane products, the
-    # float32 planes and the gates' float32 intermediates.
-    temps = 4 * (12 * H + 12 * H + 2 * (I + H) + 12 * H)
-    need = resident + bb * (tile + temps)
-    if need > VMEM_CAP_BYTES:
-        raise NotImplementedError(
-            f"delta_gru_seq_int: I={I}, H={H} needs {need} B of VMEM at "
-            f"block_b={bb} ({resident} B of resident weights), above the "
-            f"{VMEM_CAP_BYTES} B a kernel may use; the weights cannot stay "
-            f"resident and no int8 kernel streams them")
-    return bb, need
+    The weights are held as their float32 image (``F32_IMAGE``: 6 B a
+    weight, the int8 block double-buffered and the image) wherever that
+    whole plan fits ``VMEM_CAP_BYTES``, else as int8 codes held once
+    (``INT8_PLANES``: 1 B a weight).  The tile is ``validate_block_b``'s
+    rule with the held bytes counted against its budget.  Raises
+    ``NotImplementedError`` when neither holding fits at the smallest
+    legal tile: no kernel is built that Mosaic would refuse."""
+    spec = CELLS[cell]
+    GH = spec.gates * H
+    tile = _slot_vmem_bytes(I, H, T, block_t, spec.gates, spec.carries_c)
+    holdings = (
+        # Fused M and its increment, both dots' two plane products, the
+        # float32 planes and the gates' float32 intermediates.
+        (F32_IMAGE, (2 + 4) * (I + H) * GH,
+         4 * (4 * GH + 4 * GH + 2 * (I + H) + 4 * GH)),
+        # Fused M and its increment, both dots' three plane products,
+        # the int32 planes and the gates' float32 intermediates.
+        (INT8_PLANES, (I + H) * GH,
+         4 * (4 * GH + 6 * GH + 3 * (I + H) + 4 * GH)),
+    )
+    for weights, resident, temps in holdings:
+        bb = validate_block_b(kernel_name(cell), B, block_b,
+                              interpret=interpret, slot_bytes=tile,
+                              fixed_bytes=resident)
+        need = resident + bb * (tile + temps)
+        if need <= VMEM_CAP_BYTES:
+            return VmemPlan(bb, need, weights)
+    raise NotImplementedError(
+        f"{kernel_name(cell)}: the layer of I={I}, H={H} needs {need} B of "
+        f"VMEM at block_b={bb} even with its weights held as int8 planes "
+        f"({resident} B), above the {VMEM_CAP_BYTES} B a kernel may use; "
+        f"the weights cannot stay resident and no int8 kernel streams them")
 
 
-def _int_kernel(x_ref, h0_ref, xh0_ref, hh0_ref, mx0_ref, mh0_ref,
-                wx_ref, wh_ref, th_ref,
-                hs_ref, nzx_ref, nzh_ref,
-                h_ref, xh_ref, hh_ref, mx_ref, mh_ref,
-                wxf_ref=None, whf_ref=None, *, fmt, block_t: int):
+def int_kernel_tiles(cell: str, B: int, I: int, H: int, T: int, threshold,
+                     block_b: int | None, block_t: int | None,
+                     interpret: bool | None) -> tuple:
+    """(block_b, block_t) the int kernel runs a layer with: the caller's
+    knobs, else the ``kernels.autotune`` cache's for this shape, else
+    ``None`` (the kernel's static defaults)."""
+    if block_b is None or block_t is None:
+        from repro.kernels import autotune
+        tuned = autotune.resolve(kernel_name(cell), (B, I, H), "int8",
+                                 threshold, interpret=interpret, B=B, T=T)
+        block_b = block_b if block_b is not None else tuned.get("block_b")
+        block_t = block_t if block_t is not None else tuned.get("block_t")
+    return block_b, block_t
+
+
+def _int_kernel(*refs, fmt, block_t: int, carries_c: bool, weights: str):
+    """The int kernel body.  ``refs``: xs; the state blocks in (h, x̂, ĥ,
+    m_x, m_h, and c for a cell that carries it); w_x, w_h, th; hs and
+    the two transmit counts; the state blocks out, in the same order;
+    then the scratch the weight holding needs."""
     from repro.core import fixed_point as fp
+
+    n = 6 if carries_c else 5
+    x_ref, state0 = refs[0], refs[1:1 + n]
+    wx_ref, wh_ref, th_ref = refs[1 + n:4 + n]
+    hs_ref, nzx_ref, nzh_ref = refs[4 + n:7 + n]
+    state, scratch = refs[7 + n:7 + 2 * n], refs[7 + 2 * n:]
+    h_ref, xh_ref, hh_ref, mx_ref, mh_ref = state[:5]
 
     t = pl.program_id(1)
     row0 = t * block_t
 
     @pl.when(t == 0)
     def _load_state():
-        h_ref[...] = h0_ref[...]
-        xh_ref[...] = xh0_ref[...]
-        hh_ref[...] = hh0_ref[...]
-        mx_ref[...] = mx0_ref[...]
-        mh_ref[...] = mh0_ref[...]
-        if fmt is not None:
+        for dst, src in zip(state, state0):
+            dst[...] = src[...]
+        if fmt is None:
+            return
+        if weights == F32_IMAGE:
             # One-time weight conversion: the int8 image becomes an
             # f32-valued copy in persistent VMEM scratch, so every
             # frame's dot reads float operands (no per-frame cast).
-            wxf_ref[...] = wx_ref[...].astype(jnp.float32)
-            whf_ref[...] = wh_ref[...].astype(jnp.float32)
+            scratch[0][...] = wx_ref[...].astype(jnp.float32)
+            scratch[1][...] = wh_ref[...].astype(jnp.float32)
+        else:
+            # One DMA of each int8 weight matrix from HBM, held once.
+            copies = [pltpu.make_async_copy(src, dst, scratch[2].at[k])
+                      for k, (src, dst) in enumerate(
+                          ((wx_ref, scratch[0]), (wh_ref, scratch[1])))]
+            for c in copies:
+                c.start()
+            for c in copies:
+                c.wait()
 
     if fmt is None:
         dot, w_x, w_h = None, wx_ref[...], wh_ref[...]
+    elif weights == F32_IMAGE:
+        dot, w_x, w_h = fp.packed_int8_dot_pair, scratch[0][...], \
+            scratch[1][...]
     else:
-        dot, w_x, w_h = fp.packed_int8_dot_pair, wxf_ref[...], whf_ref[...]
+        dot, w_x, w_h = fp.int8_plane_dot_pair, scratch[0][...], \
+            scratch[1][...]
     th_x, th_h = th_ref[0, 0], th_ref[0, 1]
 
     # State rides the fori_loop CARRY, not the refs: the refs are read
@@ -271,7 +356,7 @@ def _int_kernel(x_ref, h0_ref, xh0_ref, hh0_ref, mx0_ref, mh0_ref,
     # Interpret mode charges every ref read/write as a real op, so at
     # block_t=4 this removes ~12 ops per frame versus the read-compute-
     # write-per-frame form — numerics untouched (same values, same
-    # order; the int-mode casts in gru_frame_step become no-ops because
+    # order; the int-mode casts in the frame step become no-ops because
     # the carry already holds int32).  The two accumulator halves ride
     # the carry FUSED as [m_x | m_h] — concatenated once here, split
     # once at writeback — matching the frame step's fused block.
@@ -279,31 +364,44 @@ def _int_kernel(x_ref, h0_ref, xh0_ref, hh0_ref, mx0_ref, mh0_ref,
     half = mx_ref.shape[-1]
 
     def step(k, carry):
-        h, xh, hh, m = carry
-        h, xh, hh, m, mask_x, mask_h = fp.gru_frame_step(
-            fmt, x_ref[k], h, xh, hh, m, w_x, w_h,
-            th_x, th_h, dot=dot)
+        if carries_c:
+            h, xh, hh, m, c = carry
+            h, c, xh, hh, m, mask_x, mask_h = fp.lstm_frame_step(
+                fmt, x_ref[k], h, c, xh, hh, m, w_x, w_h, th_x, th_h,
+                dot=dot)
+            new = (h, xh, hh, m, c)
+        else:
+            h, xh, hh, m = carry
+            h, xh, hh, m, mask_x, mask_h = fp.gru_frame_step(
+                fmt, x_ref[k], h, xh, hh, m, w_x, w_h,
+                th_x, th_h, dot=dot)
+            new = (h, xh, hh, m)
         hs_ref[k] = h.astype(hs_ref.dtype)
         nzx_ref[row0 + k, :] = jnp.sum(mask_x, axis=-1).astype(jnp.int32)
         nzh_ref[row0 + k, :] = jnp.sum(mask_h, axis=-1).astype(jnp.int32)
-        return h, xh, hh, m
+        return new
 
-    h, xh, hh, m = jax.lax.fori_loop(
+    carry = jax.lax.fori_loop(
         0, block_t, step,
         (h_ref[...].astype(wide), xh_ref[...].astype(wide),
          hh_ref[...].astype(wide),
-         jnp.concatenate([mx_ref[...], mh_ref[...]], axis=-1)))
+         jnp.concatenate([mx_ref[...], mh_ref[...]], axis=-1))
+        + ((state[5][...].astype(wide),) if carries_c else ()))
+    h, xh, hh, m = carry[:4]
     h_ref[...] = h.astype(h_ref.dtype)
     xh_ref[...] = xh.astype(xh_ref.dtype)
     hh_ref[...] = hh.astype(hh_ref.dtype)
     mx_ref[...] = m[:, :half].astype(mx_ref.dtype)
     mh_ref[...] = m[:, half:].astype(mh_ref.dtype)
+    if carries_c:
+        state[5][...] = carry[4].astype(state[5].dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("fmt", "block_b", "block_t",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("fmt", "cell", "block_b",
+                                             "block_t", "interpret"))
 def delta_gru_seq_int(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th,
-                      *, fmt=None, block_b: int | None = None,
+                      c0=None, *, fmt=None, cell: str = "gru",
+                      block_b: int | None = None,
                       block_t: int | None = None,
                       interpret: bool | None = None):
     """The int8-weight/int16-state variant of the fused sequence kernel.
@@ -315,10 +413,13 @@ def delta_gru_seq_int(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th,
       * ``fmt`` a ``GruFormats`` — integer-code operands: xs/h/x̂/ĥ are
         int16 codes, m_x/m_h int32 on the 24-bit saturating accumulator
         grid, weights int8, ``th`` a (1, 2) int32 [th_x, th_h].  Every
-        Δ·W runs as exact byte-plane f32 dots against a one-time f32
-        weight image in VMEM scratch (``fixed_point.
-        packed_int8_dot_pair``), at any contraction length: bit-identical
-        to the golden ``fixed_point.int_gru_scan`` scan.
+        Δ·W runs exactly on the MXU, at any contraction length, against
+        the weights as ``int_vmem_plan`` holds them: byte-plane f32 dots
+        against a one-time f32 image in VMEM scratch (``fixed_point.
+        packed_int8_dot_pair``), or, where that image does not fit,
+        int8 plane dots against the int8 codes DMA'd once into scratch
+        (``fixed_point.int8_plane_dot_pair``).  Bit-identical to the
+        golden ``fixed_point.int_gru_scan`` scan either way.
       * ``fmt=None`` — identity-quant conformance mode: float operands
         (``th`` (1, 2) float32, both entries Δ_TH) through the SAME
         kernel skeleton, executing the float math in the float kernel's
@@ -326,22 +427,40 @@ def delta_gru_seq_int(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th,
         This isolates the int kernel's plumbing (dispatch, block specs,
         state carry) from quantization in the differential fuzz suite.
 
-    ``block_b``/``block_t`` tile the batch/time grid axes (numerics-
-    invariant, autotunable); the tile and the kernel's VMEM limit follow
-    ``int_vmem_plan``, which refuses weights that cannot stay resident.
+    ``cell`` (``core.delta_gru.CELLS``) sets the gate count G (weights
+    and memories G·H wide) and whether the cell state ``c0`` (int16
+    codes, B × H) rides the state blocks and the carry: ``"lstm"`` runs
+    ``fixed_point.lstm_frame_step`` under the name ``delta_lstm_seq_int``
+    (``kernel_name``); integer codes only.
 
-    Returns ``(hs, (h, x̂, ĥ, m_x, m_h), nz_dx, nz_dh)``.
+    ``block_b``/``block_t`` tile the batch/time grid axes (numerics-
+    invariant, autotunable); the tile, the weight holding and the
+    kernel's VMEM limit follow ``int_vmem_plan``.
+
+    Returns ``(hs, DeltaState(h, x̂, ĥ, m_x, m_h), nz_dx, nz_dh)``, the
+    state a ``DeltaLstmState`` with c for a cell that carries it.
     """
+    from repro.core.delta_gru import DeltaLstmState, DeltaState
+    spec = CELLS[cell]
     T, B, I = xs.shape
     H = h0.shape[1]
+    GH = spec.gates * H
     assert h0.shape == h_hat0.shape == (B, H), (h0.shape, h_hat0.shape)
     assert x_hat0.shape == (B, I), (x_hat0.shape, (B, I))
-    assert m_x0.shape == m_h0.shape == (B, 3 * H), (m_x0.shape, m_h0.shape)
-    assert w_x.shape == (I, 3 * H), (w_x.shape, (I, 3 * H))
-    assert w_h.shape == (H, 3 * H), (w_h.shape, (H, 3 * H))
+    assert m_x0.shape == m_h0.shape == (B, GH), (m_x0.shape, m_h0.shape)
+    assert w_x.shape == (I, GH), (w_x.shape, (I, GH))
+    assert w_h.shape == (H, GH), (w_h.shape, (H, GH))
     assert th.shape == (1, 2), th.shape
-    bt = validate_divisor("delta_gru_seq_int", "block_t", block_t, "T", T)
-    bb, vmem = int_vmem_plan(I, H, T, B, bt, block_b, interpret)
+    assert (c0 is not None) == spec.carries_c, (cell, c0 is None)
+    assert c0 is None or (c0.shape == (B, H) and fmt is not None)
+    name = kernel_name(cell)
+    bt = validate_divisor(name, "block_t", block_t, "T", T)
+    bb, vmem, weights = int_vmem_plan(I, H, T, B, bt, block_b, interpret,
+                                      cell=cell)
+    if fmt is None and weights != F32_IMAGE:
+        raise NotImplementedError(
+            f"{name}: the float conformance mode holds float weights; "
+            f"I={I}, H={H} needs them held as int8 planes")
     compiler_params = _COMPILER_PARAMS
     if vmem > SCOPED_VMEM_DEFAULT_BYTES:
         compiler_params = pltpu.CompilerParams(
@@ -353,48 +472,49 @@ def delta_gru_seq_int(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th,
         # the frame step computes on int32, so feeding int32 blocks
         # makes its per-frame x cast a no-op (values unchanged).
         xs = xs.astype(jnp.int32)
-    kernel = functools.partial(_int_kernel, fmt=fmt, block_t=bt)
+    kernel = functools.partial(_int_kernel, fmt=fmt, block_t=bt,
+                               carries_c=spec.carries_c, weights=weights)
     state_spec = lambda d: pl.BlockSpec((bb, d), lambda b, t: (b, 0))
     fixed_spec = lambda s: pl.BlockSpec(s, lambda b, t: tuple(
         0 for _ in s))
     seq_spec = lambda d: pl.BlockSpec((bt, bb, d), lambda b, t: (t, b, 0))
+    state0 = (h0, x_hat0, h_hat0, m_x0, m_h0) + ((c0,) if spec.carries_c
+                                                 else ())
+    widths = (H, I, H, GH, GH, H)[:len(state0)]
+    if weights == F32_IMAGE:
+        weight_specs = [fixed_spec((I, GH)), fixed_spec((H, GH))]
+        scratch = ([pltpu.VMEM((I, GH), jnp.float32),
+                    pltpu.VMEM((H, GH), jnp.float32)]
+                   if fmt is not None else [])
+    else:
+        weight_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        scratch = [pltpu.VMEM((I, GH), jnp.int8),
+                   pltpu.VMEM((H, GH), jnp.int8),
+                   pltpu.SemaphoreType.DMA((2,))]
 
     out_shapes = (
         jax.ShapeDtypeStruct((T, B, H), h0.dtype),      # hs
         jax.ShapeDtypeStruct((T, B), jnp.int32),        # nz_dx
         jax.ShapeDtypeStruct((T, B), jnp.int32),        # nz_dh
-        jax.ShapeDtypeStruct((B, H), h0.dtype),         # h
-        jax.ShapeDtypeStruct((B, I), x_hat0.dtype),     # x_hat
-        jax.ShapeDtypeStruct((B, H), h_hat0.dtype),     # h_hat
-        jax.ShapeDtypeStruct((B, 3 * H), m_x0.dtype),   # m_x
-        jax.ShapeDtypeStruct((B, 3 * H), m_h0.dtype),   # m_h
-    )
+    ) + tuple(jax.ShapeDtypeStruct((B, d), a.dtype)    # the state
+              for d, a in zip(widths, state0))
     out_specs = (
         seq_spec(H),
         # Whole-sequence counts per tile, revisited along t.
         pl.BlockSpec((T, bb), lambda b, t: (0, b)),
         pl.BlockSpec((T, bb), lambda b, t: (0, b)),
-        state_spec(H), state_spec(I), state_spec(H),
-        state_spec(3 * H), state_spec(3 * H),
-    )
-    hs, nz_dx, nz_dh, h, x_hat, h_hat, m_x, m_h = pl.pallas_call(
+    ) + tuple(state_spec(d) for d in widths)
+    hs, nz_dx, nz_dh, *final = pl.pallas_call(
         kernel,
         grid=(B // bb, T // bt),
-        in_specs=[
-            seq_spec(I),
-            state_spec(H), state_spec(I), state_spec(H),
-            state_spec(3 * H), state_spec(3 * H),
-            fixed_spec((I, 3 * H)), fixed_spec((H, 3 * H)),
-            fixed_spec((1, 2)),
-        ],
+        in_specs=[seq_spec(I), *(state_spec(d) for d in widths),
+                  *weight_specs, fixed_spec((1, 2))],
         out_specs=out_specs,
         out_shape=out_shapes,
-        scratch_shapes=([pltpu.VMEM((I, 3 * H), jnp.float32),
-                         pltpu.VMEM((H, 3 * H), jnp.float32)]
-                        if fmt is not None else []),
+        scratch_shapes=scratch,
         compiler_params=compiler_params,
-        name="delta_gru_seq_int",
+        name=name,
         interpret=resolve_interpret(interpret),
-    )(xs, h0, x_hat0, h_hat0, m_x0, m_h0, w_x, w_h, th)
-    from repro.core.delta_gru import DeltaState
-    return hs, DeltaState(h, x_hat, h_hat, m_x, m_h), nz_dx, nz_dh
+    )(xs, *state0, w_x, w_h, th)
+    kind = DeltaLstmState if spec.carries_c else DeltaState
+    return hs, kind(*final), nz_dx, nz_dh

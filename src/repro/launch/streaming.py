@@ -17,8 +17,9 @@ One fused step per chunk (``_kws_step``, jitted as ``kws_audio_step``)
 is composed from the stages the session runs, in this order:
 
     non-finite input flag → 12-bit ADC → [frame energy] → FEx (kws.fex)
-    → [VAD gate] → [stage-0 ΔGRU → wake machine] → ΔGRU stack (kws.gru,
-    one kws.gru.<l> per layer; wake-masked under the cascade) → FC head
+    → [VAD gate] → [stage-0 ΔGRU → wake machine] → recurrent stack
+    (kws.gru, one kws.gru.<l> per layer: ΔGRU, or the int8 ΔLSTM of
+    ``cfg.cell``; wake-masked under the cascade) → FC head
     (kws.head) → [posterior smoothing + hysteresis events] → telemetry
     → per-slot health mask
 
@@ -94,6 +95,8 @@ from repro.frontend.fex import (FeatureExtractor, FExConfig, FExState,
                                 init_fex_state)
 from repro.frontend.vad import (VADConfig, VADState, VAD_OFF, frame_energy,
                                 init_vad_state, vad_gate, vad_state_flags)
+from repro.kernels.delta_gru_seq import (int_kernel_tiles, int_vmem_plan,
+                                         kernel_name)
 from repro.kernels.gru_math import f32_dot
 from repro.kernels.platform import resolve_interpret, shard_map_kernels
 from repro.launch import spans
@@ -187,8 +190,9 @@ class SlotState(NamedTuple):
     order."""
 
     fex: FExState | None = None           # biquad registers, envelope
-    gru: Any = None                       # ΔGRU x̂/ĥ/M: one DeltaState,
-                                          #   or a tuple per layer
+    gru: Any = None                       # x̂/ĥ/M (and a ΔLSTM's c): one
+                                          #   layer's state, or a tuple
+                                          #   per layer
     cascade: CascadeState | None = None   # wake latch, stage-0 ΔGRU
     vad: VADState | None = None           # feature hold, hangover
     det: DetectorState | None = None      # smoothed posteriors, latch
@@ -455,6 +459,11 @@ class StreamSummary:
     # and temporal sparsity, 1 − transmitted / (frames · (I + H)).
     layer_transmits: tuple = ()
     layer_sparsity: tuple = ()
+    # Per layer, the int kernel's plan at the last step's shapes:
+    # {block_b, vmem_bytes, weights ("f32_image" / "int8_planes")}; ()
+    # off the int8 Pallas path.  How a chip tiles the work, not what the
+    # streams decided, so it takes no part in comparing two summaries.
+    layer_plans: tuple = dataclasses.field(default=(), compare=False)
     # Chunks joined to a carried sample remainder: how the host framed
     # the audio, not what the streams decided, so — unlike ``chunks`` —
     # it takes no part in comparing two summaries.
@@ -481,12 +490,14 @@ def _layers(x) -> tuple:
 
 
 class LayerCounts(NamedTuple):
-    """One ΔGRU layer's transmitted Δs over a chunk, and its widths."""
+    """One recurrent layer's transmitted Δs over a chunk, its widths and
+    its cell's gate count."""
 
     nz_dx: Array    # (F, B) int32
     nz_dh: Array    # (F, B) int32
     in_dim: int
     hidden: int
+    gates: int = 3
 
 
 # ------------------------------------------------------------- numerics --
@@ -500,9 +511,20 @@ class _Float32:
 
     backends = ("pallas", "pallas-int", "xla")    # ΔGRU dispatch
     hold_dtype = jnp.float32                # the VAD holds float features
-    gru_kernel, gru_dtype = "delta_gru_seq", "float32"
+    gru_dtype = "float32"
     fex_kernel, fex_dtype = "batched_iir_fex", "float32"
-    init_gru = staticmethod(dg.init_delta_state)
+
+    def gru_kernel(self, cell: str) -> str:
+        return "delta_gru_seq"
+
+    def init_gru(self, batch, input_dim, hidden, weights, cell):
+        """A fresh layer's state (the float path serves ΔGRUs only)."""
+        return dg.init_delta_state(batch, input_dim, hidden, weights)
+
+    def kernel_plan(self, cell, batch, input_dim, hidden, frames, *,
+                    threshold, backend, interpret):
+        """The int kernel's plan of a layer; none on the float path."""
+        return None
 
     def load(self, params, threshold, *, bundle, fex, quantize_8b, mesh):
         """(replicated (gru, w_fc, b_fc), the promoted bundle or None)."""
@@ -530,7 +552,8 @@ class _Float32:
         """``process_chunk``'s float features as the ΔGRU's input."""
         return feats
 
-    def scan(self, gru, fmt, xs, threshold, state, *, backend, interpret):
+    def scan(self, gru, fmt, xs, threshold, state, *, backend, interpret,
+             cell="gru"):
         """One ΔGRU layer over the chunk: (hs, state, nz_dx, nz_dh)."""
         hs, state, stats = dg.delta_gru_scan(
             gru, xs, threshold=threshold, state=state, backend=backend,
@@ -564,9 +587,27 @@ class _Int8:
 
     backends = ("pallas", "xla")
     hold_dtype = jnp.int16                  # the VAD holds feature codes
-    gru_kernel, gru_dtype = "delta_gru_seq_int", "int8"
+    gru_dtype = "int8"
     fex_kernel, fex_dtype = "batched_iir_fex_int", "int16"
-    init_gru = staticmethod(fp.init_int_delta_state)
+
+    def gru_kernel(self, cell: str) -> str:
+        return kernel_name(cell)
+
+    def init_gru(self, batch, input_dim, hidden, weights, cell):
+        return fp.init_int_delta_state(batch, input_dim, hidden, weights,
+                                       cell)
+
+    def kernel_plan(self, cell, batch, input_dim, hidden, frames, *,
+                    threshold, backend, interpret):
+        """{block_b, vmem_bytes, weights} of the int kernel for a layer
+        of ``cell`` at these shapes and the tiles it would resolve (None
+        off the Pallas backend)."""
+        if backend != "pallas":
+            return None
+        bb, bt = int_kernel_tiles(cell, batch, input_dim, hidden, frames,
+                                  threshold, None, None, interpret)
+        return int_vmem_plan(input_dim, hidden, frames, batch, bt or 1, bb,
+                             interpret, cell=cell)._asdict()
 
     def load(self, params, threshold, *, bundle, fex, quantize_8b, mesh):
         # Without a bundle, ``params`` is promoted here — the train→deploy
@@ -601,9 +642,11 @@ class _Int8:
         """Features on the 12-bit grid → int16 codes."""
         return fp.to_code(feats, _layers(gfmt)[0].feat_frac, 16, jnp.int16)
 
-    def scan(self, gru, fmt, xs, threshold, state, *, backend, interpret):
+    def scan(self, gru, fmt, xs, threshold, state, *, backend, interpret,
+             cell="gru"):
         return fp.int_gru_scan(gru, fmt, xs, threshold, state=state,
-                               backend=backend, interpret=interpret)
+                               backend=backend, interpret=interpret,
+                               cell=cell)
 
     def masked_scan(self, gru, fmt, xs, threshold, state, awake):
         return fp.masked_int_gru_scan(gru, fmt, xs, threshold, state, awake)
@@ -660,14 +703,17 @@ _FEATURES = _Stages(audio=False)             # ``process_chunk``'s step
 
 @jax.named_scope("kws.gru")
 def _gru_stack(num, gru, state, xs, *, threshold: float, backend: str,
-               interpret: bool | None, gfmt=None):
-    """The ΔGRU stage of the fused step: the stack's layers in turn over
-    the chunk, layer 1 on ``xs`` (T, B, I), each layer above on the
-    hidden states of the layer below.  Layer l at frame t reads only
-    layer l−1 at frame t, so running each layer over the whole chunk in
-    turn is the frame-interleaved order.  ``gru``/``state`` (and, int8,
-    ``gfmt``) are one layer's entry or a tuple per layer.  Each layer's
-    kernel runs under the named scope ``kws.gru.<l>`` (l = 0 first).
+               interpret: bool | None, gfmt=None, cell: str = "gru"):
+    """The recurrent stage of the fused step: the stack's layers in turn
+    over the chunk, layer 1 on ``xs`` (T, B, I), each layer above on the
+    hidden states of the layer below.  Every layer is of ``cell``
+    (``core.delta_gru.CELLS``): ``num.scan`` runs it with that cell's
+    kernel — the ΔGRU's, or the ΔLSTM's, which carries c in the state.
+    Layer l at frame t reads only layer l−1 at frame t, so running each
+    layer over the whole chunk in turn is the frame-interleaved order.
+    ``gru``/``state`` (and, int8, ``gfmt``) are one layer's entry or a
+    tuple per layer.  Each layer's kernel runs under the named scope
+    ``kws.gru.<l>`` (l = 0 first), whatever the cell.
 
     Returns (the top layer's hidden states, the new state in the form
     of ``state``, one ``LayerCounts`` per layer)."""
@@ -677,11 +723,16 @@ def _gru_stack(num, gru, state, xs, *, threshold: float, backend: str,
     for layer, (g, st, fmt) in enumerate(zip(grus, states, fmts)):
         in_dim = xs.shape[-1]
         with jax.named_scope(f"kws.gru.{layer}"):
-            xs, st, nz_dx, nz_dh = num.scan(g, fmt, xs, threshold, st,
-                                            backend=backend,
-                                            interpret=interpret)
+            try:
+                xs, st, nz_dx, nz_dh = num.scan(g, fmt, xs, threshold, st,
+                                                backend=backend,
+                                                interpret=interpret,
+                                                cell=cell)
+            except NotImplementedError as e:
+                raise NotImplementedError(f"layer {layer}: {e}") from None
         new.append(st)
-        counts.append(LayerCounts(nz_dx, nz_dh, in_dim, g.w_h.shape[0]))
+        counts.append(LayerCounts(nz_dx, nz_dh, in_dim, g.w_h.shape[0],
+                                  dg.CELLS[cell].gates))
     state = tuple(new) if type(state) in (tuple, list) else new[0]
     return xs, state, tuple(counts)
 
@@ -702,18 +753,20 @@ def _bump(acc: _Accum, counts, n_frames: int, n_samples: int,
     default to every frame awake (duty 1.0).
 
     Exact at any chunk size the transmit counts fit: each layer's chunk
-    sum of transmitted Δs is an int32 (under 2^31), scaled by its 3H
-    MACs per Δ inside the split counter (``_count_add``), and the dense
-    count is a Python int from the static shapes.
+    sum of transmitted Δs is an int32 (under 2^31), scaled by its G·H
+    MACs per Δ (G its cell's gates: 3H a ΔGRU's, 4H a ΔLSTM's) inside
+    the split counter (``_count_add``), and the dense count, (I+H)·G·H a
+    frame, is a Python int from the static shapes.
     """
     def macs(c, layer_counts):
         for lc in layer_counts:
-            c = _count_add(c, jnp.sum(lc.nz_dx + lc.nz_dh), 3 * lc.hidden)
+            c = _count_add(c, jnp.sum(lc.nz_dx + lc.nz_dh),
+                           lc.gates * lc.hidden)
         return c
 
     per_layer = lambda f: jnp.stack([jnp.sum(getattr(lc, f))
                                      for lc in counts])
-    dense = n_frames * sum((lc.in_dim + lc.hidden) * 3 * lc.hidden
+    dense = n_frames * sum((lc.in_dim + lc.hidden) * lc.gates * lc.hidden
                            for lc in counts)
     return _Accum(
         macs=macs(acc.macs, counts),
@@ -731,8 +784,8 @@ def _bump(acc: _Accum, counts, n_frames: int, n_samples: int,
 
 def _kws_step(*args, num, stages: _Stages, threshold: float, backend: str,
               interpret: bool | None, gfmt=None, gfmt0=None,
-              fex_backend: str | None = None, frame_shift: int = 0,
-              **fex_kw):
+              cell: str = "gru", fex_backend: str | None = None,
+              frame_shift: int = 0, **fex_kw):
     """The fused serving step, composed from ``stages`` in one jitted
     graph over ``num``'s datapath:
 
@@ -746,10 +799,11 @@ def _kws_step(*args, num, stages: _Stages, threshold: float, backend: str,
     non-finite flag; the 12-bit ADC; [frame energy]; the FEx
     (``kws.fex``), or the feature input; [the VAD gate, which holds the
     features during silence so that Δx = 0]; [stage 0 → the wake
-    machine]; the ΔGRU stack (``kws.gru``), or, under the cascade, the
-    wake-masked stage-1 scan; the head (``kws.head``) → [posterior
-    smoothing and hysteresis events]; telemetry; the per-slot health
-    mask.  Nothing in here leaves the device.
+    machine]; the recurrent stack of ``cell`` (``kws.gru``), or, under
+    the cascade, the wake-masked stage-1 ΔGRU scan; the head
+    (``kws.head``) → [posterior smoothing and hysteresis events];
+    telemetry; the per-slot health mask.  Nothing in here leaves the
+    device.
     """
     n_w = stages.n_weights
     weights, acc, block = args[:n_w], args[-2], args[-1]
@@ -774,7 +828,7 @@ def _kws_step(*args, num, stages: _Stages, threshold: float, backend: str,
     if stages.cascade is None:
         hs, gru_state, counts = _gru_stack(num, gru, st.gru, xs,
                                            threshold=threshold, gfmt=gfmt,
-                                           **scan)
+                                           cell=cell, **scan)
         s0 = ()
     else:
         # Stage 0: always on, leading-channel subset, its own Δ_TH and
@@ -842,7 +896,9 @@ class StreamingKwsSession:
 
     Args:
       params: the trained KWS parameter tree (``models.kws.init_kws``).
-      cfg: an ArchConfig (``d_model`` = GRU width).
+      cfg: an ArchConfig (``d_model`` = GRU width, ``num_layers`` the
+        stack's depth, ``cell`` its recurrent cell: ``"gru"``, or
+        ``"lstm"``, a ΔLSTM stack that the int8 datapath serves).
       threshold: Δ_TH override (default ``cfg.delta_threshold``).
       batch: number of parallel streams sharing the session.
       input_dim: feature channels per frame (default: inferred lazily
@@ -941,6 +997,20 @@ class StreamingKwsSession:
                  input_policy: str = "reject"):
         if numerics not in _NUMERICS:
             raise ValueError(f"unknown numerics: {numerics!r}")
+        self.cell = getattr(cfg, "cell", "gru")
+        if self.cell not in dg.CELLS:
+            raise ValueError(f"unknown recurrent cell {self.cell!r}; "
+                             f"known: {sorted(dg.CELLS)}")
+        # Only the int8 datapath's scan and kernel carry a cell state c.
+        carries_c = dg.CELLS[self.cell].carries_c
+        if carries_c and numerics != "int8":
+            raise ValueError(f"the {self.cell!r} cell is served by the int8 "
+                             f"datapath only: there is no {numerics} "
+                             f"kernel for it")
+        if carries_c and cascade is not None:
+            raise ValueError(f"the wake cascade gates a ΔGRU stage 1; a "
+                             f"{self.cell!r} cell is served in audio and "
+                             f"detect mode only")
         if input_policy not in ("reject", "sanitize", "trust"):
             raise ValueError(f"unknown input_policy: {input_policy!r} "
                              f"(choose reject / sanitize / trust)")
@@ -1023,6 +1093,7 @@ class StreamingKwsSession:
         self._coef = None                           # replicated FEx coeffs
         self._audio_rem: np.ndarray | None = None   # carried tail samples
         self.frame_joins = 0                # steps that joined a tail
+        self._frames: int | None = None     # frames of the last step
         self._acc = shp.put_slot_sharded(
             _zero_accum(self.n_shards, self.n_layers), mesh)
         self._chunks = 0
@@ -1058,7 +1129,7 @@ class StreamingKwsSession:
         the one composed step (``_kws_step``) over ``process_chunk``'s
         stages and over the session's audio stages."""
         kw = dict(num=self._num, threshold=threshold, backend=self._backend,
-                  interpret=self._interpret,
+                  interpret=self._interpret, cell=self.cell,
                   gfmt=self._bundle.gfmt if self._bundle is not None else None)
         step = jax.jit(self._shard(
             functools.partial(_kws_step, stages=_FEATURES, **kw), _FEATURES))
@@ -1109,8 +1180,9 @@ class StreamingKwsSession:
         num = self._num
         H = int(_layers(self._gru)[0].w_h.shape[0])
         if self._input_dim is not None:
-            report["kernels"][num.gru_kernel] = entry(
-                num.gru_kernel, (b_shard, int(self._input_dim), H),
+            kernel = num.gru_kernel(self.cell)
+            report["kernels"][kernel] = entry(
+                kernel, (b_shard, int(self._input_dim), H),
                 num.gru_dtype, self.threshold)
         if self._fex is not None:
             fcfg = self._fex.cfg
@@ -1173,15 +1245,17 @@ class StreamingKwsSession:
 
     def _fresh_slots(self, batch: int) -> SlotState:
         """Fresh-stream state of every stage the session's step carries,
-        for ``batch`` slots: the ΔGRU (one ``DeltaState``, or one per
-        layer of a stack: layer 1 reads the input features, each layer
-        above the hidden state below) and, once an FEx is attached, the
-        FEx, the cascade (everyone asleep, stage 0 fresh), the VAD hold
-        and the detector head."""
+        for ``batch`` slots: the recurrent stack (one layer's state — a
+        ``DeltaState``, or with the ΔLSTM's zero c a ``DeltaLstmState`` —
+        or one per layer of a stack: layer 1 reads the input features,
+        each layer above the hidden state below) and, once an FEx is
+        attached, the FEx, the cascade (everyone asleep, stage 0 fresh),
+        the VAD hold and the detector head."""
         num, C, H = self._num, self._input_dim, self.cfg.d_model
 
         def gru():
-            layers = tuple(num.init_gru(batch, C if layer == 0 else H, H, g)
+            layers = tuple(num.init_gru(batch, C if layer == 0 else H, H, g,
+                                        self.cell)
                            for layer, g in enumerate(_layers(self._gru)))
             return layers if self.n_layers > 1 else layers[0]
 
@@ -1190,7 +1264,8 @@ class StreamingKwsSession:
             return CascadeState(
                 awake=jnp.zeros((batch,), bool),
                 hang=jnp.zeros((batch,), jnp.int32),
-                s0=num.init_gru(batch, g0.w_x.shape[0], g0.w_h.shape[0], g0))
+                s0=num.init_gru(batch, g0.w_x.shape[0], g0.w_h.shape[0], g0,
+                                "gru"))
 
         fresh = dict(
             fex=lambda: num.fresh_fex(batch, C), gru=gru, cascade=cascade,
@@ -1329,6 +1404,7 @@ class StreamingKwsSession:
         with spans.span(spans.LAUNCH):
             out = self._carry(self._stages,
                               self._audio_step(*self._audio_step_args(block)))
+        self._frames = n_frames
         self._maybe_heal()
         return out
 
@@ -1394,12 +1470,14 @@ class StreamingKwsSession:
                              f" session state is {self._input_dim}-wide")
         out = self._carry(_FEATURES, self._step(
             *self._weights, self._slots.gru, self._acc, feats))
+        self._frames = int(feats.shape[0])
         self._maybe_heal()
         return out
 
     @property
     def state(self):
-        """The slots' ΔGRU state: one ``DeltaState``, or for a stack a
+        """The slots' recurrent state: one layer's (a ``DeltaState``, or
+        for the ΔLSTM a ``DeltaLstmState`` with c), or for a stack a
         tuple of one per layer, the first layer's first."""
         return self._slots.gru if self._slots is not None else None
 
@@ -1576,6 +1654,20 @@ class StreamingKwsSession:
                          for t, w in zip(tx, width))
         return (None if sat_x or sat_h else tx), sparsity
 
+    def _layer_plans(self) -> tuple:
+        """Each layer's int kernel plan at the shapes of the last step
+        (``_Int8.kernel_plan``); () before a step or off the int8
+        Pallas path.  From static shapes: no device work."""
+        if self._frames is None:
+            return ()
+        H = int(_layers(self._gru)[0].w_h.shape[0])
+        plans = tuple(self._num.kernel_plan(
+            self.cell, self.batch // self.n_shards, I, H, self._frames,
+            threshold=self.threshold, backend=self._backend,
+            interpret=self._interpret)
+            for I in [self._input_dim] + [H] * (self.n_layers - 1))
+        return plans if None not in plans else ()
+
     def summary(self) -> StreamSummary:
         """Fetch device telemetry ONCE and price it with the IC model.
 
@@ -1596,7 +1688,8 @@ class StreamingKwsSession:
                       sat_events=self._sat_events,
                       frame_joins=self.frame_joins, slo=dict(self._slo),
                       layer_transmits=layer_tx or (),
-                      layer_sparsity=layer_sp)
+                      layer_sparsity=layer_sp,
+                      layer_plans=self._layer_plans())
         if totals["frames"] == 0:
             # Nothing processed yet: report an identifiable empty state,
             # not a spurious 100%-sparsity / 0-energy datapoint.

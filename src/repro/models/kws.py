@@ -51,17 +51,21 @@ def init_kws(key, cfg, input_dim: int = 10):
     2-class stage-0 wake gate all train/promote/serve through the same
     code, the class count riding the weight shapes end to end).
 
-    ``cfg.num_layers`` ΔGRU layers of width ``d_model``: the first reads
-    the features and draws from ``key`` as a one-layer model always has;
-    layer ``l`` above it reads the hidden state below, under
-    ``layer_keys(l)``, from ``fold_in(key, l)``."""
+    ``cfg.num_layers`` recurrent layers of width ``d_model``, each of
+    ``cfg.cell`` (``core.delta_gru.CELLS``: G·H-wide weights, G = 3 for
+    the ΔGRU, 4 for the ΔLSTM): the first reads the features and draws
+    from ``key`` as a one-layer model always has; layer ``l`` above it
+    reads the hidden state below, under ``layer_keys(l)``, from
+    ``fold_in(key, l)``."""
     k1, k2 = jax.random.split(key)
     n_classes = getattr(cfg, "vocab_size", N_CLASSES)
     H = cfg.d_model
+    G = dg.CELLS[getattr(cfg, "cell", "gru")].gates
     t = AxTree()
     for layer in range(getattr(cfg, "num_layers", 1)):
-        gru = (dg.init_delta_gru(k1, input_dim, H) if layer == 0 else
-               dg.init_delta_gru(jax.random.fold_in(key, layer), H, H))
+        gru = (dg.init_delta_gru(k1, input_dim, H, gates=G) if layer == 0
+               else dg.init_delta_gru(jax.random.fold_in(key, layer), H, H,
+                                      gates=G))
         for name, w in zip(layer_keys(layer), gru):
             t.add(name, w, (None,) * w.ndim)
     t.add("w_fc", jax.random.normal(k2, (H, n_classes)) /
@@ -83,8 +87,9 @@ def _gru_params(params, quantize_8b: bool, layer: int = 0):
 
 
 def gru_stack(params, quantize_8b: bool = False):
-    """The ΔGRU weights a session serves: one ``DeltaGRUParams``, or for
-    a stack a tuple of them, the first layer's first."""
+    """The recurrent weights a session serves: one ``DeltaGRUParams``,
+    or for a stack a tuple of them, the first layer's first.  Each holds
+    a layer of any cell: its (·, G·H) weights as the tree has them."""
     layers = tuple(_gru_params(params, quantize_8b, layer)
                    for layer in range(n_gru_layers(params)))
     return layers[0] if len(layers) == 1 else layers

@@ -255,3 +255,77 @@ class TestStreamingSession:
         np.testing.assert_array_equal(np.asarray(first.logits),
                                       np.asarray(again.logits))
         assert sess.summary().frames == 8
+
+
+# ------------------------------------------------ the int kernel's plan
+# (batch tile, VMEM bytes) of every served configuration's int layers at
+# the utterance cells' 1024 slots × 32 frames (and one frame), as the
+# plan gave them before it could hold weights as int8 planes: each keeps
+# its float32 image and compiles exactly as before.
+SERVED_INT_PLANS = {
+    (10, 64, 32): (512, 13757696),           # deltakws-int8
+    (10, 64, 1): (512, 13503744),            # deltakws-int8, live
+    (10, 768, 32): (128, 39535616),          # edgedrnn layer 1
+    (768, 768, 32): (128, 52756480),         # edgedrnn layer 2
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SERVED_INT_PLANS))
+def test_int_vmem_plan_keeps_every_served_gru_layer(shape):
+    from repro.kernels import delta_gru_seq as k
+    I, H, T = shape
+    plan = k.int_vmem_plan(I, H, T, 1024, 1, None, interpret=False)
+    assert (plan.block_b, plan.vmem_bytes) == SERVED_INT_PLANS[shape]
+    assert plan.weights == k.F32_IMAGE
+
+
+def test_int_vmem_plan_holds_spartus_layer_2_as_int8_planes():
+    """Spartus's ΔLSTM-1024: layer 1 (10 in) keeps the float32 image
+    (76.2 MB at 128 slots); layer 2 (1024 in) would need 104.92 MB with
+    it, above the cap, so it holds its int8 weights once (8.4 MB) and
+    fits; weights neither holding fits are refused, naming the kernel."""
+    from repro.kernels import delta_gru_seq as k
+    one = k.int_vmem_plan(10, 1024, 32, 1024, 1, None, interpret=False,
+                          cell="lstm")
+    two = k.int_vmem_plan(1024, 1024, 32, 1024, 1, None, interpret=False,
+                          cell="lstm")
+    assert one == (128, 76212224, k.F32_IMAGE)
+    assert two.block_b == 128 and two.weights == k.INT8_PLANES
+    assert k.SCOPED_VMEM_DEFAULT_BYTES < two.vmem_bytes <= k.VMEM_CAP_BYTES
+    # The float32 image's plan at 128 slots: weights, tile, temporaries.
+    tile = k._slot_vmem_bytes(1024, 1024, 32, 1, 4, True)
+    f32_need = 6 * 2048 * 4096 + 128 * (tile + 4 * (12 * 4096 + 2 * 2048))
+    assert f32_need == 104_923_136 > k.VMEM_CAP_BYTES
+    with pytest.raises(NotImplementedError,
+                       match="delta_lstm_seq_int.*int8 planes"):
+        k.int_vmem_plan(8192, 8192, 32, 1024, 1, None, interpret=False,
+                        cell="lstm")
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_int8_plane_dot_equals_f32_planes_and_int32_dot(seed):
+    """The int8-plane contraction is exact: equal to the float32 byte
+    planes and to the int32 dot on Δs of two int16 codes (rails
+    included) against int8 codes, at 2048 contraction rows."""
+    from repro.core import fixed_point as fp
+    rng = np.random.default_rng(seed)
+    K, N, Bk = 2048, 256, 16
+    dx = rng.integers(-32768, 32768, (Bk, K)) - rng.integers(-32768, 32768,
+                                                             (Bk, K))
+    dx[0, :] = 65535
+    dx[1, :] = -65535
+    dh = rng.integers(-65535, 65536, (Bk, K))
+    wx = rng.integers(-128, 128, (K, N)).astype(np.int8)
+    wh = rng.integers(-128, 128, (K, N)).astype(np.int8)
+    dx, dh = jnp.asarray(dx, jnp.int32), jnp.asarray(dh, jnp.int32)
+    planes = fp.int8_plane_dot_pair(dx, dh, jnp.asarray(wx),
+                                    jnp.asarray(wh))
+    f32 = fp.packed_int8_dot_pair(dx, dh, jnp.asarray(wx, jnp.float32),
+                                  jnp.asarray(wh, jnp.float32))
+    i32 = jnp.concatenate(
+        [jnp.dot(dx, jnp.asarray(wx, jnp.int32),
+                 preferred_element_type=jnp.int32),
+         jnp.dot(dh, jnp.asarray(wh, jnp.int32),
+                 preferred_element_type=jnp.int32)], axis=-1)
+    np.testing.assert_array_equal(np.asarray(planes), np.asarray(f32))
+    np.testing.assert_array_equal(np.asarray(planes), np.asarray(i32))

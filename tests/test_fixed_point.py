@@ -191,14 +191,16 @@ def test_int_vmem_plan_counts_weights_and_tile_at_768():
     weights that cannot stay resident are refused."""
     from repro.kernels import delta_gru_seq as k
     for I in (10, 768):
-        bb, need = k.int_vmem_plan(I, 768, 32, 1024, 1, None,
-                                   interpret=False)
+        bb, need, weights = k.int_vmem_plan(I, 768, 32, 1024, 1, None,
+                                            interpret=False)
         resident = 2 * (I + 768) * 3 * 768
-        assert bb == 128
+        assert bb == 128 and weights == k.F32_IMAGE
         assert need >= resident + bb * k._slot_vmem_bytes(I, 768, 32, 1)
         assert k.SCOPED_VMEM_DEFAULT_BYTES < need <= k.VMEM_CAP_BYTES
-    bb, need = k.int_vmem_plan(10, 64, 32, 1024, 1, None, interpret=False)
+    bb, need, weights = k.int_vmem_plan(10, 64, 32, 1024, 1, None,
+                                        interpret=False)
     assert bb == 512 and need <= k.SCOPED_VMEM_DEFAULT_BYTES
+    assert weights == k.F32_IMAGE
     with pytest.raises(NotImplementedError, match="cannot stay resident"):
         k.int_vmem_plan(4096, 4096, 32, 1024, 1, None, interpret=False)
     with pytest.raises(NotImplementedError, match="cannot stay resident"):

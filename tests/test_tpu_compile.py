@@ -9,7 +9,9 @@ mode accepts all of those, so these compiles guard the compiled path.
 Every kernel compiles at its DEFAULT tiling (``interpret=False``) for
 B ∈ {16, 1024} slots and T ∈ {32, 12, 1} frames — an aligned 4096-sample
 chunk, a non-aligned chunk and a one-frame chunk — and the session's
-fused audio step compiles at 1024 slots in both numerics.
+fused audio step compiles at 1024 slots in both numerics, for the
+ΔGRU-768 stack and for the ΔLSTM-1024 stack, whose second layer holds
+its weights as int8 planes.
 
 The topology is described inside a module fixture (never at import or
 collection time): only one process may load the TPU library at a time,
@@ -99,6 +101,29 @@ def test_wide_int_kernel_compiles_for_v5e(one_chip, I_, H_):
     assert "delta_gru_seq_int" in _custom_calls(text)
 
 
+@pytest.mark.parametrize("I_, weights", [(10, "f32_image"),
+                                          (1024, "int8_planes")])
+def test_lstm_kernel_compiles_for_v5e(one_chip, I_, weights):
+    """Spartus's two ΔLSTM-1024 layers at 1024 slots × 32 frames: layer
+    1 with its float32 weight image, layer 2 with its int8 weights DMA'd
+    once and contracted as int8 planes, each under the raised VMEM
+    limit, as ``delta_lstm_seq_int``."""
+    from repro.kernels.delta_gru_seq import int_vmem_plan
+    B, T, H4 = 1024, 32, 4 * 1024
+    assert int_vmem_plan(I_, 1024, T, B, 1, None, interpret=False,
+                         cell="lstm").weights == weights
+    i16, i32 = jnp.int16, jnp.int32
+    fn = functools.partial(delta_gru_seq_int, fmt=fp.GruFormats(),
+                           cell="lstm", interpret=False)
+    shapes = [((T, B, I_), i16), ((B, 1024), i16), ((B, I_), i16),
+              ((B, 1024), i16), ((B, H4), i32), ((B, H4), i32),
+              ((I_, H4), jnp.int8), ((1024, H4), jnp.int8), ((1, 2), i32),
+              ((B, 1024), i16)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "delta_lstm_seq_int" in _custom_calls(text)
+
+
 @pytest.mark.parametrize("numerics,kernels", [
     ("float32", {"batched_iir_fex", "delta_gru_seq"}),
     ("int8", {"batched_iir_fex_int", "delta_gru_seq_int"}),
@@ -141,5 +166,30 @@ def test_stacked_fused_audio_step_compiles_for_v5e(one_chip):
         args)
     text = step.lower(*shapes).compile().as_text()
     assert {"batched_iir_fex_int", "delta_gru_seq_int"} <= _custom_calls(text)
+    for layer in (0, 1):
+        assert f"/kws.gru/kws.gru.{layer}/" in text
+
+
+def test_spartus_fused_audio_step_compiles_for_v5e(one_chip):
+    """The ``spartus`` configuration's int8 step at 1024 slots: both
+    ΔLSTM layers' kernels, named ``delta_lstm_seq_int``, each under its
+    ``kws.gru.<l>`` scope."""
+    from repro.configs import get_config
+    from repro.frontend import FeatureExtractor
+    from repro.launch.streaming import StreamingKwsSession
+    from repro.models import kws
+    cfg = get_config("spartus")
+    params, _ = kws.init_kws(jax.random.PRNGKey(0), cfg, input_dim=C)
+    sess = StreamingKwsSession(params, cfg, batch=1024,
+                               fex=FeatureExtractor(), numerics="int8",
+                               interpret=False)
+    step, args = sess.fused_audio_step(4096)
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    text = step.lower(*shapes).compile().as_text()
+    assert {"batched_iir_fex_int", "delta_lstm_seq_int"} <= \
+        _custom_calls(text)
+    assert "delta_gru_seq_int" not in _custom_calls(text)
     for layer in (0, 1):
         assert f"/kws.gru/kws.gru.{layer}/" in text
